@@ -549,9 +549,9 @@ import types as _types_mod  # noqa: E402
 nn.initializer.LazyGuard = LazyGuard
 nn.initializer.lazy_init = _types_mod.SimpleNamespace(LazyGuard=LazyGuard)
 
-# persistent XLA compile cache: armed here iff FLAGS_compile_cache_dir /
-# PADDLE_COMPILE_CACHE names a directory, so a fleet deploys warm-restart
-# compile caching with an env var and no code change
+# persistent XLA compile cache: armed here iff JAX_COMPILATION_CACHE_DIR
+# names a directory, so a fleet deploys warm-restart compile caching with an
+# env var and no code change (core/compile_cache.py)
 from .core import compile_cache as _compile_cache  # noqa: E402
 
 _compile_cache.maybe_autoinstall()

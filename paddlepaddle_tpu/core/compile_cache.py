@@ -5,13 +5,22 @@ Reference analogue: the reference framework's program/kernel caches that
 ``save_inference_model`` deployments rely on to avoid rebuilding per
 process. JAX-native: XLA's persistent compilation cache
 (``jax_compilation_cache_dir``) keyed by the optimized HLO, shared across
-processes through a directory. This module wires it through the
-``FLAGS_compile_cache_dir`` / ``PADDLE_COMPILE_CACHE`` flag family
+processes through a directory. This module arms it
 (:func:`maybe_autoinstall` runs at package import, so arming a fleet is an
 env var, no code change), counts hits/misses/seconds from the
 ``jax.monitoring`` cache events, and surfaces them as
 ``paddle_compile_cache_*`` metrics plus the ``cache`` block inside
 ``health()``/``/healthz``'s compile section.
+
+Where the directory comes from, in one rule: ``JAX_COMPILATION_CACHE_DIR``
+in the environment IS the cache — jax reads it itself, and nothing here
+writes ``jax_compilation_cache_dir`` over it, not even to detach. Only
+when it is unset does this module place the cache: at the directory
+:func:`install` is given, and for the programs that run on the chip
+(:func:`arm`: ``chip_smoke.py``, ``bench.py``,
+``inference/replica_main.py``) at ``<checkout>/.jax_cache`` — a fixed
+path, never one built from ``tempfile``, a pid or the clock: a directory
+that moves never hits.
 
 What the cache does and does not buy: a warm-disk restart still pays
 python tracing and cache retrieval (tens of milliseconds per program)
@@ -29,7 +38,6 @@ from __future__ import annotations
 
 import os
 import threading
-from contextlib import contextmanager
 from typing import Dict, Optional
 
 from . import flags as _flags
@@ -104,60 +112,53 @@ def _reset_jax_cache_latch() -> None:
     compile is a no-op — and uninstall() leaves the old directory live:
     jax caches the "is the cache used" decision and the cache handle the
     first time any compile asks, and never re-reads the config."""
-    try:
-        from jax.experimental.compilation_cache import compilation_cache \
-            as _jcc
+    from jax._src import compilation_cache as _jcc
 
-        _jcc.reset_cache()
-    except Exception:
-        try:
-            from jax._src import compilation_cache as _jcc
-
-            _jcc.reset_cache()
-        except Exception:
-            pass
+    _jcc.reset_cache()
 
 
-@contextmanager
-def cache_bypassed():
-    """Compiles inside this context skip the persistent cache entirely
-    (read AND write) and produce REAL backend executables.
+def _outside_dir() -> Optional[str]:
+    """The cache directory the environment chose, if it chose one."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
 
-    Exists for AOT bundle saves: on this jaxlib's CPU backend,
-    re-serializing an executable that was itself DESERIALIZED (a
-    persistent-cache hit) yields a payload with no kernel object code —
-    it fails at load time with "Symbols not found". A bundle save that
-    finds such an executable recompiles it in here. Concurrent compiles
-    on other threads harmlessly miss the cache for the duration."""
-    import jax
 
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    _reset_jax_cache_latch()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        _reset_jax_cache_latch()
+# <checkout>/.jax_cache: beside the package, listed in .gitignore
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def arm() -> str:
+    """Arm the persistent cache for a program that runs on the chip and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when the
+    environment sets it, else ``<checkout>/.jax_cache``. The one call
+    ``chip_smoke.py``, ``bench.py`` and ``inference/replica_main.py``
+    make, so the three cannot disagree about where compiled programs
+    live."""
+    install(CHECKOUT_CACHE_DIR)
+    return str(_state["dir"])
 
 
 def install(cache_dir: Optional[str] = None,
             min_compile_secs: Optional[float] = None) -> bool:
     """Point jax at a persistent compilation cache directory and start
-    counting its events. ``cache_dir`` defaults to
-    ``FLAGS_compile_cache_dir`` (env ``PADDLE_COMPILE_CACHE``); empty
-    means leave the cache off. Returns True when armed."""
+    counting its events. ``JAX_COMPILATION_CACHE_DIR`` wins when set: jax
+    already reads it, and no other directory is written over it.
+    Otherwise the cache goes to ``cache_dir``; with neither, it stays off.
+    Returns True when armed."""
     global _active, _listener_installed
-    if cache_dir is None:
-        cache_dir = _flags.flag_value("compile_cache_dir")
-    if not cache_dir:
-        return False
-    if min_compile_secs is None:
-        min_compile_secs = _flags.flag_value("compile_cache_min_compile_secs")
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    outside = _outside_dir()
+    if outside:
+        cache_dir = outside
+    else:
+        if not cache_dir:
+            return False
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if min_compile_secs is None:
+        min_compile_secs = _flags.flag_value("compile_cache_min_compile_secs")
     # default jax policy only persists compiles > 1s / large entries —
     # serving programs at small test scales would never cache, so the
     # flag default (0.0) persists everything and the flag raises the bar
@@ -168,7 +169,7 @@ def install(cache_dir: Optional[str] = None,
     # jax initializes its cache AT MOST ONCE, on the first compile — and
     # framework import itself compiles a few host ops before any user
     # code runs, latching "no cache" forever. Reset the latch so the
-    # directory set above actually takes effect
+    # directory actually takes effect
     _reset_jax_cache_latch()
     with _lock:
         if not _listener_installed:
@@ -184,13 +185,15 @@ def install(cache_dir: Optional[str] = None,
 
 
 def uninstall() -> None:
-    """Disarm: stop counting and detach the cache directory (existing
-    entries stay on disk for the next install)."""
+    """Disarm: stop counting and detach the cache directory this module
+    placed (existing entries stay on disk for the next install). A
+    directory the environment chose stays attached — it is not ours to
+    null."""
     global _active
     _active = False
     with _lock:
         _state["enabled"] = False
-    try:
+    if _outside_dir() is None:
         import jax
 
         jax.config.update("jax_compilation_cache_dir", None)
@@ -198,18 +201,16 @@ def uninstall() -> None:
         # directory keeps serving hits and absorbing writes for the rest
         # of the process — "detached" must mean detached
         _reset_jax_cache_latch()
-    except Exception:
-        pass
     _safe_metric("safe_set", "paddle_compile_cache_enabled",
                  "persistent XLA compile cache armed (1 = on)", 0)
 
 
 def maybe_autoinstall() -> bool:
-    """Arm the cache iff the flag/env names a directory — called at
-    package import so ``PADDLE_COMPILE_CACHE=/path python serve.py`` is
-    the whole deployment story."""
+    """Arm the cache iff the environment names a directory — called at
+    package import so ``JAX_COMPILATION_CACHE_DIR=/path python serve.py``
+    is the whole deployment story."""
     try:
-        if _flags.flag_value("compile_cache_dir"):
+        if _outside_dir():
             return install()
     except Exception as e:
         # never fatal at import — but an armed-by-env cache that silently
@@ -217,8 +218,9 @@ def maybe_autoinstall() -> bool:
         import sys
 
         sys.stderr.write(
-            "[compile-cache] PADDLE_COMPILE_CACHE set but the persistent "
-            f"compile cache could not be armed ({type(e).__name__}: {e}); "
+            "[compile-cache] JAX_COMPILATION_CACHE_DIR is set but the "
+            "persistent compile cache could not be armed "
+            f"({type(e).__name__}: {e}); "
             "restarts will pay full backend compiles\n")
         _safe_metric("safe_set", "paddle_compile_cache_enabled",
                      "persistent XLA compile cache armed (1 = on)", 0)

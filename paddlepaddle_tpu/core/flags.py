@@ -242,14 +242,10 @@ define_flag("obs_memledger_interval_s", 5.0,
 
 # Compile-cache family (core/compile_cache.py + inference/compile_plan.py):
 # persistent XLA compilation cache so warm-disk restarts skip backend
-# compile. Armed at package import when the dir is set (env alone deploys
-# it fleet-wide); hit/miss/seconds surface as paddle_compile_cache_*.
-define_flag("compile_cache_dir", "",
-            "directory for JAX's persistent XLA compilation cache "
-            "(jax_compilation_cache_dir); empty = cache off. Restarting a "
-            "serving process against a warm directory skips backend "
-            "compiles — seconds instead of minutes to first token",
-            env="PADDLE_COMPILE_CACHE")
+# compile. The directory is jax's own JAX_COMPILATION_CACHE_DIR (armed at
+# package import when set — env alone deploys it fleet-wide) or, for the
+# programs that run on the chip, <checkout>/.jax_cache; hit/miss/seconds
+# surface as paddle_compile_cache_*.
 define_flag("compile_cache_min_compile_secs", 0.0,
             "only compiles at least this long are persisted to the compile "
             "cache (0 = persist everything; raise it where cache I/O costs "

@@ -19,16 +19,29 @@ import numpy as np
 
 
 class Generator:
+    """The key is made on first use, not at construction: the default
+    generator is a module global, and a key is a device array — importing
+    the package must not take the chip (a launcher or supervisor that only
+    spawns workers has to leave it free for them)."""
+
     def __init__(self, seed: int = 0):
-        self._seed = int(seed)
-        self._key = jax.random.PRNGKey(self._seed)
-        self._offset = 0
+        self.manual_seed(seed)
 
     def manual_seed(self, seed: int):
         self._seed = int(seed)
-        self._key = jax.random.PRNGKey(self._seed)
+        self._lazy_key = None
         self._offset = 0
         return self
+
+    @property
+    def _key(self):
+        if self._lazy_key is None:
+            self._lazy_key = jax.random.PRNGKey(self._seed)
+        return self._lazy_key
+
+    @_key.setter
+    def _key(self, value):
+        self._lazy_key = value
 
     def initial_seed(self):
         return self._seed

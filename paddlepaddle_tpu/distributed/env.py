@@ -17,6 +17,28 @@ import jax
 _initialized = False
 
 
+def refuse_chip_contention(children: int, what: str) -> None:
+    """One process per chip-holding host: exit rather than start
+    ``children`` > 1 processes that would each try to take the host's TPU
+    chips — all but the first would fail or hang at jax start-up. Chips are
+    counted by the PCI scan jax's own start-up uses, so the caller (a
+    launcher, a fleet bench) initialises no backend and leaves the chips to
+    its children. ``JAX_PLATFORMS=cpu`` — children that never take a chip —
+    passes."""
+    if children <= 1 or os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return
+    from jax._src import hardware_utils
+
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if chips:
+        raise SystemExit(
+            f"{what} {children} on a host with {chips} TPU chip(s): one "
+            "process drives all of a host's chips and a chip belongs to one "
+            "process at a time, so every child after the first would fail "
+            f"or hang at jax start-up. Use {what} 1, or JAX_PLATFORMS=cpu "
+            "for a CPU run.")
+
+
 def init_parallel_env():
     """Multi-host rendezvous. Single-host (or driver-managed) setups no-op."""
     global _initialized

@@ -4,11 +4,14 @@ Reference surface: python/paddle/distributed/launch/main.py:23 (node/device
 discovery, per-rank env injection, log management, watch loop with
 restart-on-failure; controllers/collective.py + controllers/master.py).
 
-TPU-native notes: one process normally drives the whole chip mesh
-(single-controller), so the default is nproc_per_node=1 with multi-host
-rendezvous over the native TCPStore (distributed/store.py). Multi-process
-per node is supported for CPU-mesh testing and for per-host multi-slice
-jobs. The watch loop restarts failed workers up to --max_restarts times —
+TPU-native notes: one process drives all of a host's chips
+(single-controller, distributed/env.py), so the default is
+nproc_per_node=1 with multi-host rendezvous over the native TCPStore
+(distributed/store.py). A chip belongs to one process at a time: the
+launcher itself never touches a jax backend, and on a host with TPU chips
+it refuses nproc_per_node > 1 — the extra children would fail or hang
+waiting for chips the first one holds. Multi-process per node is for
+CPU-mesh testing (``JAX_PLATFORMS=cpu``). The watch loop restarts failed workers up to --max_restarts times —
 the launcher half of the reference's elastic story (checkpoint-resume
 provides the state half).
 """
@@ -88,7 +91,9 @@ def _worker_env(args, local_rank: int, world_size: int, master_addr,
         if args.obs_port:
             env["PADDLE_OBS_PORT"] = str(args.obs_port)
     if args.devices:
-        env["CUDA_VISIBLE_DEVICES"] = args.devices  # env parity; unused on TPU
+        # reference env parity only: nothing on a TPU host reads it, and it
+        # partitions no chips (see env.refuse_chip_contention)
+        env["CUDA_VISIBLE_DEVICES"] = args.devices
     # make the framework importable in workers even when not pip-installed
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))))
@@ -111,6 +116,9 @@ def _count_restart(local_rank: int, rc: int) -> None:
 
 def launch(argv=None) -> int:
     args = _parse_args(argv)
+    from ..env import refuse_chip_contention
+
+    refuse_chip_contention(args.nproc_per_node, "--nproc_per_node")
     # PADDLE_OBS_EXPORT in the shell autostarts an exporter in THIS process
     # at import time — on the launcher that squats rank 0's deterministic
     # port (obs_port + 0) and would force the real rank 0 onto an ephemeral

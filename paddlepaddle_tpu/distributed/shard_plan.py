@@ -352,10 +352,8 @@ class ShardingPlan:
             raise ValueError(
                 "shard_map compile path requires explicit in_specs and "
                 "out_specs (map-style execution cannot infer placements)")
-        from ..core.jax_compat import shard_map
-
-        mapped = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+        mapped = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
         return jax.jit(mapped, donate_argnums=donate_argnums,
                        static_argnums=static_argnums)
 

@@ -354,24 +354,18 @@ def save_bundle(engine, path: str,
                 engine._programs[key] = fn
                 engine._warmed.add(key)
             payload, in_tree, out_tree = _se.serialize(fn)
-            try:
-                _se.deserialize_and_load(payload, in_tree, out_tree)
-            except Exception:
-                # a payload that cannot load back is worse than no bundle
-                # (it fails at RESTART, the moment the bundle exists for).
-                # Known cause on this jaxlib's CPU backend: ``fn`` was
-                # itself deserialized (a persistent-cache hit), and
-                # re-serializing such an executable drops the kernel
-                # object code. Recompile for real with the cache detached
-                # and serialize THAT; a second probe failure is fatal.
-                from ..core.compile_cache import cache_bypassed
-
-                with cache_bypassed():
-                    fn = engine._build_program(key).lower(
-                        *engine._example_args(key)).compile()
-                engine._programs[key] = fn
-                payload, in_tree, out_tree = _se.serialize(fn)
-                _se.deserialize_and_load(payload, in_tree, out_tree)
+            # a payload that cannot load back is worse than no bundle (it
+            # fails at RESTART, the moment the bundle exists for): prove
+            # the round trip now and let a failure raise. On the TPU client
+            # it holds for executables that themselves came out of the
+            # persistent cache too (PR 21, chip run). This jaxlib's CPU
+            # client refuses to serialize an executable that has already
+            # RUN a sort-by-comparator (UNIMPLEMENTED: `LessThan` is not
+            # serializable) — every engine program samples through
+            # lax.top_k — so a served CPU engine cannot save a bundle.
+            _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=_execution_devices(engine))
             fname = f"{key}.xc"
             with open(os.path.join(staging, fname), "wb") as f:
                 f.write(payload)
@@ -409,6 +403,17 @@ def save_bundle(engine, path: str,
         raise
     manifest["save_wall_s"] = round(time.perf_counter() - t0, 3)
     return manifest
+
+
+def _execution_devices(engine) -> list:
+    """The devices the engine's programs run on, in assignment order: its
+    plan's mesh, or the one device its state lives on. Left to its default,
+    ``deserialize_and_load`` loads for EVERY device of the backend, and a
+    one-chip program on a four-chip host (or on the tests' eight virtual
+    CPU devices) then refuses its arguments: "expected 8 shards, got 1"."""
+    if engine.plan is not None:
+        return list(engine.plan.mesh.devices.flat)
+    return list(engine.lens.devices())
 
 
 def load_bundle(engine, path: str) -> Dict[str, object]:
@@ -469,8 +474,9 @@ def load_bundle(engine, path: str) -> Dict[str, object]:
         in_tree = tree_structure((engine._example_args(key), {}))
         out_tree = tree_structure(engine._out_template(key))
         try:
-            loaded[key] = _se.deserialize_and_load(payload, in_tree,
-                                                   out_tree)
+            loaded[key] = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=_execution_devices(engine))
         except Exception as e:
             raise BundleMismatchError(
                 f"bundle entry {key}: executable failed to deserialize "

@@ -667,8 +667,12 @@ class ReplicaSupervisor:
         self._ready.clear()
         self.ready_info = {}
         self.state = "starting"
+        # the replica runs on the platform this process was given, unless
+        # ``env=`` names another; on a TPU host a chip belongs to one
+        # process, so the supervisor's own process must stay off the jax
+        # backend (or each replica be given its own chip) for the child
+        # to find one free
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         if self.env:
             env.update(self.env)
         self._proc = subprocess.Popen(

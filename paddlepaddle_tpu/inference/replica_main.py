@@ -97,9 +97,13 @@ def main(argv=None) -> int:
         ap.error("exactly one of --socket / --port is required")
 
     t0 = time.perf_counter()
+    from paddlepaddle_tpu.core import compile_cache
     from paddlepaddle_tpu.inference.c_api_server import CApiServer
     from paddlepaddle_tpu.inference.serving import ServingEngine
 
+    # a restarted replica finds its compiled programs where the last one
+    # left them: JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
+    compile_cache.arm()
     model = _build_model(args.preset, args.model_json)
     t_model = time.perf_counter()
     eng_kw = json.loads(args.engine_json) if args.engine_json else {}

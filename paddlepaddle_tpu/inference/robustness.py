@@ -180,7 +180,7 @@ class DeployError(ServingError):
 
 
 # ---------------------------------------------------------------------------
-# wire (de)serialization — the process boundary's half of the taxonomy.
+# wire (de)serialization — the process boundary's half of the error classes.
 #
 # A remote replica (inference/replica_main.py) reports failures as a typed
 # error frame: {"type": <class name>, "msg": str(exc), "fields": {...}}.

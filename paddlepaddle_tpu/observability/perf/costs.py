@@ -159,13 +159,19 @@ class CostRegistry:
 
     def table(self, specs: Optional[dict] = None) -> List[dict]:
         """Derived rows (roofline numbers included), MFU-descending."""
+        programs = self.programs()
+        if not programs:
+            # nothing to derive: do not ask jax for a device — a /metrics
+            # scrape in a process that only supervises chip-holding
+            # children must not take the chip from them
+            return []
         if specs is None:
             try:
                 specs = _device.specs()
-            except Exception:   # no jax backend: raw costs, no roofline
+            except RuntimeError:   # no jax backend: raw costs, no roofline
                 specs = {"peak_flops": 0.0, "peak_hbm_bytes_per_s": 0.0,
                          "ridge_flops_per_byte": float("inf")}
-        rows = [pc.derived(specs) for pc in self.programs()]
+        rows = [pc.derived(specs) for pc in programs]
         rows.sort(key=lambda r: -(r.get("mfu") or 0.0))
         return rows
 

@@ -15,7 +15,7 @@ query segment with no keys) produce zero output and zero gradients: the
 online-softmax probabilities are multiplied by the mask so a row whose
 running max never leaves -inf cannot fabricate exp(0)=1 weights.
 
-The XLA fallback builds the same mask densely ([total_q, total_k]) and is
+The XLA path builds the same mask densely ([total_q, total_k]) and is
 used on CPU and for odd shapes; jax.grad differentiates it directly. The
 Pallas path wires a custom vjp (dQ and dK/dV kernels, same recompute
 structure as the dense ones in flash_attention.py).
@@ -28,17 +28,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.dispatch import apply_op
-from .flash_attention import NEG_INF, _blocks, _use_pallas
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from .flash_attention import NEG_INF, _blocks, _use_pallas, _vmem_limit
 
 
 # ---------------------------------------------------------------------------
@@ -218,13 +212,11 @@ def _smem_spec(n):
 
 
 def _varlen_pallas_fwd(q, k, v, cu_q, cu_k, causal, scale):
-    """q,k,v: [h, t, d]. Returns (out, lse) or None if unsupported."""
+    """q,k,v: [h, t, d] on a shape ``_use_pallas`` accepted.
+    Returns (out, lse)."""
     h, tq, d = q.shape
     tk = k.shape[1]
-    blocks = _blocks(tq, tk)
-    if blocks is None:
-        return None
-    block_q, block_kv = blocks
+    block_q, block_kv = _blocks(tq, tk)
     n_seq = cu_q.shape[0] - 1
     kernel = functools.partial(
         _vfwd_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -233,6 +225,7 @@ def _varlen_pallas_fwd(q, k, v, cu_q, cu_k, causal, scale):
         return pl.pallas_call(
             kernel,
             grid=(h, tq // block_q),
+            compiler_params=_vmem_limit(tk, q.dtype.itemsize),
             in_specs=[
                 _smem_spec(n_seq + 1), _smem_spec(n_seq + 1),
                 pl.BlockSpec((1, block_q, d), lambda hh, i: (hh, i, 0)),
@@ -253,10 +246,7 @@ def _varlen_pallas_fwd(q, k, v, cu_q, cu_k, causal, scale):
 def _varlen_pallas_bwd(q, k, v, cu_q, cu_k, out, lse, do, causal, scale):
     h, tq, d = q.shape
     tk = k.shape[1]
-    blocks = _blocks(tq, tk)
-    if blocks is None:
-        return None
-    block_q, block_kv = blocks
+    block_q, block_kv = _blocks(tq, tk)
     n_seq = cu_q.shape[0] - 1
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -279,6 +269,7 @@ def _varlen_pallas_bwd(q, k, v, cu_q, cu_k, out, lse, do, causal, scale):
                       vec_q_block, vec_q_block],
             out_specs=row_q,
             out_shape=jax.ShapeDtypeStruct((h, tq, d), q.dtype),
+            compiler_params=_vmem_limit(tk, q.dtype.itemsize),
         )(cu_q, cu_k, q, k, v, do, lse, delta)
 
         dk, dv = pl.pallas_call(
@@ -293,6 +284,7 @@ def _varlen_pallas_bwd(q, k, v, cu_q, cu_k, out, lse, do, causal, scale):
                 jax.ShapeDtypeStruct((h, tk, d), k.dtype),
                 jax.ShapeDtypeStruct((h, tk, d), v.dtype),
             ],
+            compiler_params=_vmem_limit(tq, q.dtype.itemsize),
         )(cu_q, cu_k, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -365,8 +357,7 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
         s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
         cu_q32 = cu_q.astype(jnp.int32)
         cu_k32 = cu_k.astype(jnp.int32)
-        if (not drop and _HAS_PALLAS and _use_pallas(q)
-                and _blocks(q.shape[0], k.shape[0]) is not None):
+        if not drop and _use_pallas(q.shape[0], k.shape[0], q.shape[-1]):
             qt = jnp.transpose(q, (1, 0, 2))
             kt = jnp.transpose(k, (1, 0, 2))
             vt = jnp.transpose(v, (1, 0, 2))

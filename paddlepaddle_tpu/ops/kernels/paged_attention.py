@@ -41,9 +41,15 @@ One online-softmax pass per slot (f32 running max / denominator /
 accumulator in VMEM scratch), pages visited in logical order, K and V
 pages each read exactly once per step: HBM traffic drops from
 ``gather(view) + attention-read`` to ``attention-read`` alone. The
-kernel runs compiled on TPU backends and in Pallas INTERPRET mode
-elsewhere (CPU tier-1: same program, emulated grid), which is how parity
-is test-pinned without an accelerator (tests/test_fused_kernels.py).
+kernel runs compiled on TPU backends (Mosaic accepts it, bf16 and int8
+pages, W=1 and W=4, and it matches the reference view math: chip_smoke.py,
+PR 21) and in Pallas INTERPRET mode elsewhere (CPU tier-1: same program,
+emulated grid), which is how parity is test-pinned without an accelerator
+(tests/test_fused_kernels.py). One thing the compiler showed that the
+interpreter cannot: XLA's default TPU layout for a ``[pages, 64, 8, 64]``
+pool is not row-major, so a standalone call copies the pool into the
+kernel's operand layout first — whether the engine's scan hoists that
+copy decides ROADMAP 1.1b (PERF.md, open questions).
 """
 
 from __future__ import annotations
@@ -52,17 +58,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.flags import flag_value
 from . import interpret_mode
 
-try:  # pallas import is cheap; kernels only compile when called on TPU
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
 
 NEG_INF = -1e30
 
@@ -74,8 +75,6 @@ def paged_attention_supported(*, page_size: int, head_dim: int,
     engine calls this ONCE at construction; a False here is a loud
     fallback to the reference ``pool[page_table]`` formulation, never a
     silent behavior change (docs/kernels.md has the full matrix)."""
-    if not _HAS_PALLAS:
-        return False, "pallas unavailable"
     if not flag_value("fused_paged_attention"):
         return False, "FLAGS_fused_paged_attention off"
     if kv_quant not in (None, "off", "int8"):
@@ -102,11 +101,19 @@ def paged_attention_supported(*, page_size: int, head_dim: int,
 
 
 def _paged_attn_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                       page_size, rep, scale, num_pages_per_slot,
+                       page_size, rep, width, scale, num_pages_per_slot,
                        quantized):
     """Grid (slot, logical page): online-softmax accumulate one page.
+
+    Layouts are chosen for Mosaic, not for the caller: the wrapper hands
+    ``q`` in as ``[kvh, M, hd]`` (row ``m = w * rep + r`` is query ``w`` of
+    head ``g * rep + r``, M padded to a sublane multiple) so every matmul
+    is a leading-batch ``[kvh, M, *]`` contraction, and the only relayout
+    in the body is one major<->second-minor swap of the f32 page
+    (``[ps, kvh, hd] -> [kvh, ps, hd]``).
+
     ``quantized`` is a static trace-time flag: the int8 variant takes two
-    extra scale operands (``[pages, kvh]`` f32, blocked per page) and
+    extra scale operands (``[pages, kvh, 1]`` f32, blocked per page) and
     dequantizes the page inside the VMEM pass — codes are cast to f32 and
     multiplied by the per-page-per-head scale, so int8 K/V bytes cross HBM
     and full precision exists only in VMEM."""
@@ -123,53 +130,52 @@ def _paged_attn_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    qb = q_ref[0]                                  # [W, h, hd]
+    qg = q_ref[0].astype(jnp.float32) * scale      # [kvh, M, hd]
     kb = k_ref[0].astype(jnp.float32)              # [ps, kvh, hd]
     vb = v_ref[0].astype(jnp.float32)
     if quantized:
-        kb = kb * ks_ref[0][None, :, None]         # scale [kvh] broadcast
-        vb = vb * vs_ref[0][None, :, None]
-    W = qb.shape[0]
-    kvh, hd = kb.shape[1], kb.shape[2]
+        kb = kb * ks_ref[0][None]                  # scale [kvh, 1] broadcast
+        vb = vb * vs_ref[0][None]
+    kb = kb.transpose(1, 0, 2)                     # [kvh, ps, hd]
+    vb = vb.transpose(1, 0, 2)
+    M = qg.shape[1]
 
     # bottom-right causal mask in pool coordinates: query w (at absolute
     # position lens+w) sees keys k_pos <= lens + w — exactly the
     # reference view math, including this step's own freshly written
-    # positions (the engine scatters new K/V before calling the kernel)
-    k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (W, ps), 1)
-    q_pos = lens_ref[s] + jax.lax.broadcasted_iota(jnp.int32, (W, ps), 0)
-    mask = k_pos <= q_pos
+    # positions (the engine scatters new K/V before calling the kernel).
+    # Row m holds query w = m // rep, spelled as compares (padding rows
+    # past width*rep clamp to the last query and are sliced off outside)
+    row = jax.lax.broadcasted_iota(jnp.int32, (M, ps), 0)
+    w_idx = jnp.zeros((M, ps), jnp.int32)
+    for w in range(1, width):
+        w_idx += (row >= w * rep).astype(jnp.int32)
+    k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (M, ps), 1)
+    mask = k_pos <= lens_ref[s] + w_idx
 
-    # GQA uncontracted: q regrouped [W, kvh, rep, hd] dots the unrepeated
-    # page (the r4 serving lesson — never materialize a repeated cache)
-    qg = (qb.reshape(W, kvh, rep, hd).astype(jnp.float32) * scale)
+    # GQA uncontracted: each kv head's M = W*rep query rows dot the
+    # unrepeated page (the r4 serving lesson — never materialize a
+    # repeated cache)
     sblk = jax.lax.dot_general(
-        qg.transpose(1, 0, 2, 3).reshape(kvh, W * rep, hd),
-        kb.transpose(1, 2, 0),                     # [kvh, hd, ps]
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)        # [kvh, W*rep, ps]
-    sblk = sblk.reshape(kvh, W, rep, ps).transpose(1, 0, 2, 3)
-    sblk = jnp.where(mask[:, None, None, :], sblk, NEG_INF)
+        qg, kb, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)        # [kvh, M, ps]
+    sblk = jnp.where(mask[None], sblk, NEG_INF)
 
-    m_prev, l_prev = m_ref[:], l_ref[:]            # [W, kvh, rep]
-    m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=-1))
-    p = jnp.exp(sblk - m_new[..., None])
+    m_prev, l_prev = m_ref[:], l_ref[:]            # [kvh, M, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=-1, keepdims=True))
+    p = jnp.exp(sblk - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1)
+    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
-        p.transpose(1, 0, 2, 3).reshape(kvh, W * rep, ps),
-        vb.transpose(1, 0, 2),                     # [kvh, ps, hd]
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)        # [kvh, W*rep, hd]
-    pv = pv.reshape(kvh, W, rep, hd).transpose(1, 0, 2, 3)
-    acc_ref[:] = acc_ref[:] * alpha[..., None] + pv
+        p, vb, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)        # [kvh, M, hd]
+    acc_ref[:] = acc_ref[:] * alpha + pv
     m_ref[:] = m_new
 
     @pl.when(j == num_pages_per_slot - 1)
     def _():
-        W_, kvh_, rep_, hd_ = acc_ref.shape
-        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[..., None]
-        o_ref[0] = out.reshape(W_, kvh_ * rep_, hd_).astype(o_ref.dtype)
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lens, *, rep, scale,
@@ -191,6 +197,10 @@ def paged_attention(q, k_pool, v_pool, page_table, lens, *, rep, scale,
         raise ValueError("k_scale and v_scale must be passed together")
     if interpret is None:
         interpret = interpret_mode()
+    M = -(-W * rep // 8) * 8                       # sublane-aligned rows
+
+    def idx_q(s, j, pt, lens):
+        return (s, 0, 0, 0)
 
     def idx_kv(s, j, pt, lens):
         # logical pages wholly past the slot's visible window read the
@@ -203,43 +213,54 @@ def paged_attention(q, k_pool, v_pool, page_table, lens, *, rep, scale,
     def idx_scale(s, j, pt, lens):
         # same redirect as the pages: a masked page's scale row is the
         # null page's — finite, and the mask discards the product anyway
-        visible = j * ps <= lens[s] + (W - 1)
-        return (jnp.where(visible, pt[s, j], 0), 0)
+        return idx_kv(s, j, pt, lens)[:3]
+
+    # [S, W, h, hd] -> [S, kvh, W*rep (padded to M), hd]: head g*rep+r of
+    # query w becomes row w*rep+r of kv head g
+    qg = q.reshape(S, W, kvh, rep, hd).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(S, kvh, W * rep, hd)
+    if M != W * rep:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, M - W * rep), (0, 0)))
 
     in_specs = [
-        pl.BlockSpec((1, W, h, hd), lambda s, j, pt, lens: (s, 0, 0, 0)),
+        pl.BlockSpec((1, kvh, M, hd), idx_q),
         pl.BlockSpec((1, ps, kvh, hd), idx_kv),
         pl.BlockSpec((1, ps, kvh, hd), idx_kv),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [qg, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, kvh), idx_scale),
-                     pl.BlockSpec((1, kvh), idx_scale)]
-        operands += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        # [pages, kvh] -> [pages, kvh, 1]: a (1, kvh, 1) block covers the
+        # array's two minor dimensions whole (a (1, kvh) block of the 2-D
+        # array has a second-minor extent Mosaic cannot tile), and keeps
+        # kvh on sublanes, where the page's kv-head axis already sits
+        in_specs += [pl.BlockSpec((1, kvh, 1), idx_scale),
+                     pl.BlockSpec((1, kvh, 1), idx_scale)]
+        operands += [jnp.asarray(k_scale, jnp.float32)[:, :, None],
+                     jnp.asarray(v_scale, jnp.float32)[:, :, None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, W, h, hd),
-                               lambda s, j, pt, lens: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, M, hd), idx_q),
         scratch_shapes=[
-            pltpu.VMEM((W, kvh, rep), jnp.float32),        # running max
-            pltpu.VMEM((W, kvh, rep), jnp.float32),        # denominator
-            pltpu.VMEM((W, kvh, rep, hd), jnp.float32),    # f32 accum
+            pltpu.VMEM((kvh, M, 1), jnp.float32),          # running max
+            pltpu.VMEM((kvh, M, 1), jnp.float32),          # denominator
+            pltpu.VMEM((kvh, M, hd), jnp.float32),         # f32 accum
         ],
     )
     kernel = functools.partial(
-        _paged_attn_kernel, page_size=ps, rep=rep, scale=scale,
+        _paged_attn_kernel, page_size=ps, rep=rep, width=W, scale=scale,
         num_pages_per_slot=P, quantized=quantized)
-    # the kernel body is dtype-explicit (int32 positions, f32
-    # accumulators) so it traces identically with the package's global
-    # x64 on or off
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, W, h, hd), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lens, jnp.int32),
-      *operands)
+    # Mosaic has no 64-bit types and the package turns x64 on at import:
+    # trace the call (index maps and body) with it off
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, kvh, M, hd), q.dtype),
+            interpret=interpret,
+        )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lens, jnp.int32),
+          *operands)
+    out = out[:, :, :W * rep].reshape(S, kvh, W, rep, hd)
+    return out.transpose(0, 2, 1, 3, 4).reshape(S, W, h, hd)

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ...core.jax_compat import shard_map as _shard_map
-
 NEG_INF = -1e30
 
 
@@ -108,7 +106,7 @@ def ring_attention(q, k, v, mesh: Mesh, sp_axis: str = "sp", causal: bool = True
     spec = P(data_axis, sp_axis, None, None)
     body = partial(_ring_body, sp_axis=sp_axis, n_shards=n, causal=causal,
                    scale=scale)
-    return _shard_map(
+    return jax.shard_map(
         lambda q_, k_, v_: body(q_, k_, v_),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,

@@ -508,7 +508,8 @@ def _attention_residual(x, lp, *, cfg, cos_t, sin_t, f_in, g_out, gather,
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         if use_flash:
-            out = _flash_core(q, k, v, True, scale, _use_pallas(q))
+            out = _flash_core(q, k, v, True, scale, _use_pallas(
+                q.shape[1], k.shape[1], q.shape[-1], True))
         else:
             qt = jnp.swapaxes(q, 1, 2).astype(jnp.float32) * scale
             kt = jnp.swapaxes(k, 1, 2).astype(jnp.float32)

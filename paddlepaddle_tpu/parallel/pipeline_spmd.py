@@ -36,8 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map as _shard_map
-
 from .schedules import (OP_B, OP_B_LAST, OP_BW, OP_BW_LAST, OP_BX,
                         OP_BX_LAST, OP_F, OP_IDLE, PipelineSchedule,
                         _arrival_tables, build_schedule)
@@ -395,7 +393,7 @@ def spmd_pipeline_train(stacked_params, head_params, acts, labels,
                                   if seq_axis is not None and labels.ndim >= 2
                                   else [None] * (labels.ndim - 1)))
 
-    loss, gacc, hg, dacts = _shard_map(
+    loss, gacc, hg, dacts = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(p_specs, h_specs, x_spec, y_spec),
         out_specs=(P(), p_specs, h_specs, x_spec),
@@ -461,7 +459,7 @@ def spmd_pipeline(stacked_params, acts, block_fn: Callable, mesh: Mesh,
     p_specs = jax.tree_util.tree_map(lambda _: P(pp_axis), stacked_params)
     x_spec = P(None, data_axis, *([None] * (ndim_rest - 1)))
 
-    out = _shard_map(
+    out = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(p_specs, x_spec),
         out_specs=x_spec,

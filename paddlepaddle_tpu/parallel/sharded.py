@@ -187,7 +187,12 @@ class ShardedTrainStep:
                     loss = self.loss_fn(self.model, *batch)
             return unwrap(loss)
 
-        loss, grads = jax.value_and_grad(loss_of)(params)
+        # tell the Pallas kernels inside which mesh they are traced for
+        # (ops/kernels.partition_over): GSPMD cannot split a Mosaic call
+        from ..ops.kernels import partition_over
+
+        with partition_over(self.mesh, self._batch_dim_spec):
+            loss, grads = jax.value_and_grad(loss_of)(params)
         new_params, new_opt = self.optimizer.apply(grads, opt_state, params, lr=lr)
         return new_params, new_opt, loss
 

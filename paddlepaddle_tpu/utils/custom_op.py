@@ -85,15 +85,14 @@ class CustomOp:
                 f"custom op {self.name!r} was registered without a "
                 "sharding_rule; plain calls already propagate GSPMD "
                 "shardings")
-        from ..core.jax_compat import shard_map
         from ..parallel.mpu import _current_mesh
 
         mesh = mesh or _current_mesh()
         if mesh is None:
             raise ValueError("no active mesh: enter `with mesh:` or pass one")
         in_specs, out_specs = self.sharding_rule
-        inner = shard_map(self._core, mesh=mesh,
-                          in_specs=in_specs, out_specs=out_specs)
+        inner = jax.shard_map(self._core, mesh=mesh,
+                              in_specs=in_specs, out_specs=out_specs)
 
         def call(*args, **kwargs):
             return apply_op(inner, *args, op_name=f"{self.name}_sharded",

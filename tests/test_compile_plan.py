@@ -124,7 +124,8 @@ def test_lazy_build_stays_inside_the_plan(model):
 
 # -- bundles -----------------------------------------------------------------
 
-def test_bundle_round_trip_token_exact_zero_retrace(warm_engine, model,
+def test_bundle_round_trip_token_exact_zero_retrace(needs_bundles,
+                                                    warm_engine, model,
                                                     tmp_path):
     path = str(tmp_path / "bundle")
     manifest = warm_engine.save_serving_bundle(path)
@@ -147,7 +148,8 @@ def test_bundle_round_trip_token_exact_zero_retrace(warm_engine, model,
     assert info["plan"]["fingerprint"] == manifest["fingerprint"][:16]
 
 
-def test_bundle_mismatch_and_corruption_fall_back(warm_engine, model,
+def test_bundle_mismatch_and_corruption_fall_back(needs_bundles,
+                                                  warm_engine, model,
                                                   tmp_path):
     path = str(tmp_path / "bundle_m")
     warm_engine.save_serving_bundle(path)
@@ -177,7 +179,8 @@ def test_bundle_mismatch_and_corruption_fall_back(warm_engine, model,
 
 # -- persistent compile cache + watchdog labeling ----------------------------
 
-def test_compile_cache_hits_and_watchdog_labels(model, tmp_path):
+def test_compile_cache_hits_and_watchdog_labels(model, tmp_path,
+                                                can_serialize_executables):
     cache_dir = str(tmp_path / "ccache")
     watchdog.install(threshold=3)   # order-independent of the fixtures
     watchdog.reset()
@@ -208,16 +211,15 @@ def test_compile_cache_hits_and_watchdog_labels(model, tmp_path):
         outs = _serve(e2, _reqs(n=1))
         assert len(outs[0]) == 11
         # a bundle saved from the HIT engine must load back: e2's
-        # executables are cache-DESERIALIZED, and re-serializing those
-        # yields payloads with no kernel object code on this jaxlib's
-        # CPU backend ("Symbols not found" at load). save_bundle probes
-        # every payload and recompiles for real, cache detached
-        hit_path = str(tmp_path / "hit_bundle")
-        e2.save_serving_bundle(hit_path)
-        e3 = BatchDecodeEngine(model, max_slots=2, chunk=4, page_size=16,
-                               bundle=hit_path)
-        assert e3._bundle_info["loaded"] is True, \
-            e3._bundle_info.get("error")
+        # executables are cache-DESERIALIZED, and save_bundle proves
+        # every payload's round trip before writing it
+        if can_serialize_executables:
+            hit_path = str(tmp_path / "hit_bundle")
+            e2.save_serving_bundle(hit_path)
+            e3 = BatchDecodeEngine(model, max_slots=2, chunk=4,
+                                   page_size=16, bundle=hit_path)
+            assert e3._bundle_info["loaded"] is True, \
+                e3._bundle_info.get("error")
     finally:
         compile_cache.uninstall()
         watchdog.set_storm_callback(None)
@@ -236,10 +238,11 @@ def test_compile_cache_hits_and_watchdog_labels(model, tmp_path):
 def test_compile_cache_flag_family():
     from paddlepaddle_tpu.core import flags
 
-    assert flags.flag_value("compile_cache_dir") == ""
     assert flags.flag_value("compile_cache_min_compile_secs") == 0.0
-    # empty dir -> install refuses (cache stays off)
+    # no directory from the environment or the caller -> install refuses
+    # (cache stays off)
     assert compile_cache.install("") is False
+    assert compile_cache.install() is False
 
 
 # -- serving engine + health surfaces ----------------------------------------
@@ -256,7 +259,8 @@ def test_serving_health_compile_block_and_static_mode(model):
         ServingEngine(model, mode="static", bundle="/tmp/nope")
 
 
-def test_serving_engine_bundle_passthrough(warm_engine, model, tmp_path):
+def test_serving_engine_bundle_passthrough(needs_bundles, warm_engine, model,
+                                           tmp_path):
     path = str(tmp_path / "bundle_se")
     warm_engine.save_serving_bundle(path)
     srv = ServingEngine(model, mode="continuous", max_batch_size=2,
@@ -347,7 +351,7 @@ def test_perf_gate_coldstart_metrics(tmp_path):
 # -- full e2e: int8 + prefix variants (slow) ---------------------------------
 
 @pytest.mark.slow
-def test_bundle_full_e2e_int8_with_prefix_variant(tmp_path):
+def test_bundle_full_e2e_int8_with_prefix_variant(needs_bundles, tmp_path):
     # BOTH phases in fresh subprocesses — the real deploy shape (a
     # bundle-save job, then a restarted serving process). In-process,
     # earlier suite tests that *executed* persistent-cache-retrieved
@@ -441,7 +445,8 @@ def test_bundle_full_e2e_int8_with_prefix_variant(tmp_path):
 
 # -- fused-kernel programs through the cold-start machinery ------------------
 
-def test_fused_program_warmup_bundle_round_trip_and_mismatch(model,
+def test_fused_program_warmup_bundle_round_trip_and_mismatch(needs_bundles,
+                                                             model,
                                                              tmp_path):
     """ISSUE 15 satellite: the fused paged-decode program is a first-
     class CompilePlan citizen — warmup() still guarantees a compile-free

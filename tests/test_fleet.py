@@ -773,7 +773,10 @@ def test_perf_gate_fleet_fields(tmp_path):
         return p
 
     b = rec("base.json", base)
-    bench = os.path.join(_REPO, "BENCH_r05.json")
+    # a driver-format bench record for the gate's required --baseline
+    bench = rec("bench.json", {"n": 5, "rc": 0, "parsed": {
+        "metric": "llama_train_tokens_per_sec_per_chip", "value": 1000.0,
+        "detail": {"mfu": 0.5, "configs": {}}}})
     assert pg.main(["--baseline", bench, "--serving", b, b]) == 0
     # post-step TTFT regression past the latency budget fails
     worse = rec("ttft.json", {"serving_bench": {"traffic": {

@@ -242,9 +242,15 @@ def test_engine_greedy_parity_and_hbm_reduction(model):
         f"fused program must read fewer HBM bytes ({rows})"
 
 
-def test_engine_spec_verify_parity_fused(model):
+def test_engine_spec_verify_parity_fused():
     """The speculative verify program (W=k+1 through the SAME fused
-    forward) stays token-exact vs the reference engine."""
+    forward) stays token-exact vs the reference engine. In float32, where
+    exact is the right bar: in bf16 the reference layer rounds its
+    attention to bf16 while the kernel accumulates in f32, and random
+    weights put near-ties everywhere (5 of 93 tokens flip; 0 in f32). The
+    bf16 W=4 kernel is held to its jnp reference by tolerance on the chip
+    (chip_smoke.py, kernels leg)."""
+    model = _model("float32")
     prompts = _prompts(seed=1)
 
     def run(fused):

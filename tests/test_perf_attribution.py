@@ -473,7 +473,7 @@ def test_perf_gate_synthetic(tmp_path):
     assert _gate(["--baseline", str(base), "--current", str(pcur),
                   "--strict"]) == 1
 
-    # driver-format artifacts (the real BENCH_r*.json shape) parse
+    # driver-format artifacts (a record wrapped as {"parsed": ...}) parse
     wrapped = tmp_path / "wrapped.json"
     wrapped.write_text(json.dumps({"n": 5, "rc": 0, "parsed": bench}))
     assert _gate(["--baseline", str(wrapped), "--current", str(base)]) == 0
@@ -513,10 +513,11 @@ def test_perf_gate_paged_kv_serving_fields(tmp_path):
                       "--serving", str(bad), str(sbase)]) == 1, bad_kw
 
 
-def test_perf_gate_real_baseline_dry_run():
-    """The run_tier1 smoke: the shipped BENCH_r05.json parses and the
-    gate passes against itself."""
-    repo = os.path.dirname(_TOOLS)
-    r05 = os.path.join(repo, "BENCH_r05.json")
-    assert _gate(["--baseline", r05]) == 0
-    assert _gate(["--baseline", r05, "--dry-run"]) == 0
+def test_perf_gate_driver_format_dry_run(tmp_path):
+    """The run_tier1 smoke: a driver-format record (bench.py's line under
+    ``parsed``) parses and the gate passes against itself."""
+    bench, _ = _bench_doc()
+    rec = tmp_path / "driver_record.json"
+    rec.write_text(json.dumps({"n": 5, "rc": 0, "parsed": bench}))
+    assert _gate(["--baseline", str(rec)]) == 0
+    assert _gate(["--baseline", str(rec), "--dry-run"]) == 0

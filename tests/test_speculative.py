@@ -225,7 +225,7 @@ def test_spec_cancel_mid_speculation_returns_pages(spec_engine):
 
 def test_spec_plan_warmup_and_bundle_roundtrip(tmp_path, spec_engine,
                                                target, draft_weak,
-                                               workload):
+                                               workload, request):
     """draft_admit/draft_k/verify_k are first-class plan entries: warmup
     leaves a compile-free serve window, a bundle round trip loads them
     with ZERO compiles through the fingerprint gate, and a draft-model
@@ -244,6 +244,7 @@ def test_spec_plan_warmup_and_bundle_roundtrip(tmp_path, spec_engine,
     assert sum(watchdog.compile_counts().values()) == before, \
         "speculative serve window must be compile-free after warmup"
 
+    request.getfixturevalue("needs_bundles")   # the rest saves a bundle
     path = str(tmp_path / "spec_bundle")
     manifest = eng.save_serving_bundle(path)
     keys = {e["key"] for e in manifest["entries"]}

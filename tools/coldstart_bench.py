@@ -9,7 +9,7 @@ three restart strategies the framework ships:
 
 * ``cold``       — nothing on disk: every program pays full XLA
   retrace + backend compile (the pre-PR-10 behavior);
-* ``cache_warm`` — ``PADDLE_COMPILE_CACHE`` points at a warm directory:
+* ``cache_warm`` — ``JAX_COMPILATION_CACHE_DIR`` points at a warm directory:
   compiles become disk retrievals (retrace still paid, backend compile
   skipped; the recompile watchdog labels these as cache hits);
 * ``bundle``     — ``BatchDecodeEngine(bundle=…)`` loads AOT-serialized
@@ -275,8 +275,9 @@ def main(argv=None) -> int:
         body["bundle_save"] = _run_child(args, "save")
         sys.stderr.write("[coldstart] bundle-load restart...\n")
         body["bundle"] = _run_child(args, "bundle")
-    cache_env = {"PADDLE_COMPILE_CACHE": os.path.join(args.dir,
-                                                      "compile_cache")}
+    # a within-run cache: placed from outside, the one way there is
+    cache_env = {"JAX_COMPILATION_CACHE_DIR": os.path.join(args.dir,
+                                                           "compile_cache")}
     cache_primed = False
     if "cache" in modes:
         sys.stderr.write("[coldstart] priming: populate compile cache...\n")

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Perf regression gate — compare bench/serving artifacts against a baseline.
 
-The first automated guard on the r01->r05 perf trajectory: given a baseline
-record (a ``BENCH_r*.json`` driver artifact or a raw ``bench.py`` JSON line)
-and a current one, compare every shared metric with direction-aware
+Given a baseline record (a driver artifact wrapping ``bench.py``'s line
+under ``parsed``, or the raw ``bench.py`` JSON line) and a current one, compare every shared metric with direction-aware
 tolerances and exit nonzero on regression:
 
 * **higher-is-better** (tokens/s, images/s, MFU): regression when
@@ -31,12 +30,12 @@ without parsing human text::
                  "direction", "verdict"}, ...]}
 
 Usage:
-    python tools/perf_gate.py --baseline BENCH_r05.json --current out.json
-    python tools/perf_gate.py --baseline BENCH_r05.json --current out.json \
+    python tools/perf_gate.py --baseline base.json --current out.json
+    python tools/perf_gate.py --baseline base.json --current out.json \
         --serving serving_now.json serving_base.json
-    python tools/perf_gate.py --baseline BENCH_r05.json --dry-run
+    python tools/perf_gate.py --baseline base.json --dry-run
         # parse + report only, always exit 0 (the run_tier1 smoke)
-    python tools/perf_gate.py --baseline BENCH_r05.json --current out.json \
+    python tools/perf_gate.py --baseline base.json --current out.json \
         --json > verdict.json
 
 Exit codes: 0 ok / 1 regression (or missing metric under --strict) /
@@ -68,7 +67,7 @@ def _first_json(text: str) -> Optional[dict]:
 
 
 def load_record(path: str) -> dict:
-    """Load a driver ``BENCH_r*.json`` (uses its ``parsed`` field), a raw
+    """Load a driver record (uses its ``parsed`` field), a raw
     bench stdout capture, a bench ``--out`` artifact (``meta`` block +
     body — the body keys pass through untouched), or a plain JSON
     object."""
@@ -309,7 +308,7 @@ def compare(base: Dict[str, Tuple[float, str]],
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--baseline", required=True,
-                    help="baseline record (BENCH_r*.json or bench output)")
+                    help="baseline record (driver record or bench output)")
     ap.add_argument("--current",
                     help="current record to gate (default: baseline vs "
                     "itself — a wiring smoke)")

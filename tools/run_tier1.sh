@@ -92,9 +92,9 @@
 # and the prof-on serving leg of tools/check_serving_overhead.py.
 #
 # Perf regression gate (not run here — needs a bench artifact): after a
-# bench run, `python tools/perf_gate.py --baseline BENCH_r05.json
+# bench run on the chip, `python tools/perf_gate.py --baseline <old>.json
 # --current <new>.json` exits nonzero on a tokens/s / MFU / TTFT
-# regression beyond tolerance; `--baseline BENCH_r05.json --dry-run` is
+# regression beyond tolerance; `--baseline <old>.json --dry-run` is
 # the wiring smoke (always exit 0) and is covered by
 # tests/test_perf_attribution.py in this tier. The --serving pair also
 # gates the paged-KV serving_bench fields (mixed_tok_s, prefix_hit_rate,

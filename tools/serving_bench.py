@@ -657,12 +657,16 @@ def run_remote_fleet(args, hedge_after=_HEDGE_FROM_ARGS):
     and with hedged requests armed (--hedge). Reports availability,
     failover/retry/hedge/stall counts, per-point injection tallies — the
     hostile-network drill as a reproducible bench row."""
+    from paddlepaddle_tpu.distributed.env import refuse_chip_contention
     from paddlepaddle_tpu.inference.remote_replica import (
         ProcessReplicaFactory,
     )
     from paddlepaddle_tpu.inference.router import ServingRouter
     from paddlepaddle_tpu.resilience.netchaos import NetChaosProxy
 
+    # this process builds no model and touches no jax backend, so one
+    # replica process finds the chip free; several would fight over it
+    refuse_chip_contention(args.replicas, "--replicas")
     if hedge_after is _HEDGE_FROM_ARGS:
         hedge_after = (None if args.hedge in (None, "off")
                        else "auto" if args.hedge == "auto"
