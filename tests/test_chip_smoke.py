@@ -26,6 +26,10 @@ def test_smoke_legs_tiny_on_cpu():
     report = chip_smoke.run_legs(chip_smoke.TINY, interpret=True)
     legs = report["legs"]
     assert report["ok"] is True and report["device"]["platform"] == "cpu"
+    # the verdict line is built from these: exactly three keys, typed
+    dev = report["device"]
+    assert set(dev) == {"platform", "kind", "count"}
+    assert isinstance(dev["kind"], str) and type(dev["count"]) is int
     assert legs["train"]["status"] == "ok"
     losses = legs["train"]["losses"]
     assert losses == sorted(losses, reverse=True) and losses[-1] < losses[0]
