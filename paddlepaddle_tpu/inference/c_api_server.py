@@ -669,9 +669,11 @@ class CApiServer:
                     return
                 t = threading.Thread(target=self._serve_conn, args=(conn,),
                                      daemon=True)
-                t.start()
+                # listed BEFORE its handler starts: a handler that ends at
+                # once must find the connection to take it off the list
                 with self._conns_lock:
                     self._conns.append(conn)
+                t.start()
                 # prune finished handlers so a long-lived server does not
                 # accumulate dead Thread objects per connection
                 self._threads = [x for x in self._threads if x.is_alive()]

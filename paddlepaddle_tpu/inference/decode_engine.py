@@ -56,11 +56,17 @@ import numpy as np
 
 from ..core import autograd as _ag
 from ..core.dispatch import unwrap
+from ..observability.recorder import phase, phase_counters
 from . import compile_plan as _cp
 from .kv_pool import PagePool, PrefixCache, pages_needed, prefix_hash
 from .robustness import KVCapacityError
 from .robustness import safe_inc as _safe_inc
 from .robustness import safe_set as _safe_set
+
+# the phases of a decode chunk that the engine times itself (the serving
+# loop adds serve.sweep, serve.wait_request and serve.admit around them)
+CHUNK_PHASES = ("serve.decode_dispatch", "serve.first_sync",
+                "serve.chunk_sync", "serve.deliver")
 
 
 def _bucket(n: int, q: int = 128) -> int:
@@ -424,7 +430,11 @@ class BatchDecodeEngine:
         self._host_slots = [_Slot() for _ in range(self.S)]
         self._first_pending: Dict[int, object] = {}  # slot -> device scalar
         self.stats = {"tokens_out": 0, "requests": 0, "decode_calls": 0,
-                      "peak_busy": 0}
+                      "peak_busy": 0, "turnaround_s": 0.0,
+                      "turnaround_n": 0, **phase_counters(CHUNK_PHASES)}
+        # perf_counter at the return of the last chunk's sync, until the
+        # next compiled program is called (or the loop waits for work)
+        self._t_synced: Optional[float] = None
         # speculative decoding: a draft model proposes spec_k greedy
         # tokens per slot and ONE batched target forward verifies all
         # k+1 positions — same emitted stream (greedy acceptance is
@@ -1617,6 +1627,7 @@ class BatchDecodeEngine:
                 if compiled is not None:
                     fn = compiled
             self._programs[fn_key] = fn
+        self._close_turnaround()
         try:
             (self.caches, self.lens, self.tokens, self.active, self.temps,
              self.eos_ids, self.budgets, self.top_ks, self.key, first) = \
@@ -1687,6 +1698,16 @@ class BatchDecodeEngine:
         self.stats["requests"] += 1
         return True
 
+    def _close_turnaround(self) -> None:
+        """The host's turn-around ends here: from the return of the last
+        chunk's sync to this call of a compiled program the device had
+        nothing of ours to run. Counted once per chunk."""
+        t = self._t_synced
+        if t is not None:
+            self._t_synced = None
+            self.stats["turnaround_s"] += time.perf_counter() - t
+            self.stats["turnaround_n"] += 1
+
     def _release_kv(self, slot: int, zero_row: bool = True) -> None:
         """Return a slot's private pages to the free list, drop its prefix
         ref, and (by default) zero its page-table row so in-flight decode
@@ -1748,24 +1769,26 @@ class BatchDecodeEngine:
         chunk uses it to count tokens that landed at the same sync."""
         if not self._first_pending:
             return []
-        slots = sorted(self._first_pending)
-        vals = np.asarray(jnp.stack([self._first_pending[i] for i in slots]))
-        now = time.perf_counter()
-        stamped = []
-        for i, slot in enumerate(slots):
-            s = self._host_slots[slot]
-            if s.req is not None:
-                s.emitted.append(int(vals[i]))
-                self.stats["tokens_out"] += 1
-                # the prefill's sampled token reaching the HOST is the
-                # honest first-token time (TTFT numerator)
-                if getattr(s.req.result, "_t_first", 1) is None:
-                    _stamp(s.req, "_t_first", now)
-                    stamped.append(slot)
-                    tr = _trace_of(s.req)
-                    if tr is not None:
-                        tr.event("first_token", t0=now)
-        self._first_pending.clear()
+        with phase("serve.first_sync", self.stats):
+            slots = sorted(self._first_pending)
+            vals = np.asarray(
+                jnp.stack([self._first_pending[i] for i in slots]))
+            now = time.perf_counter()
+            stamped = []
+            for i, slot in enumerate(slots):
+                s = self._host_slots[slot]
+                if s.req is not None:
+                    s.emitted.append(int(vals[i]))
+                    self.stats["tokens_out"] += 1
+                    # the prefill's sampled token reaching the HOST is the
+                    # honest first-token time (TTFT numerator)
+                    if getattr(s.req.result, "_t_first", 1) is None:
+                        _stamp(s.req, "_t_first", now)
+                        stamped.append(slot)
+                        tr = _trace_of(s.req)
+                        if tr is not None:
+                            tr.event("first_token", t0=now)
+            self._first_pending.clear()
         return stamped
 
     def reset_slots(self, slots=None):
@@ -1820,24 +1843,37 @@ class BatchDecodeEngine:
         dfn = self._program(dkey)
         vfn = self._program(vkey)
         parts = []
-        for _ in range(steps):
-            spec.draft_caches, props = dfn(
-                spec.draft_params, spec.draft_caches, spec.prev_tokens,
-                self.tokens, self.lens, self.active)
-            (self.caches, self.lens, self.tokens, spec.prev_tokens,
-             self.active, self.budgets, payload) = vfn(
-                self.params, self.caches, self.page_table, self.lens,
-                self.tokens, spec.prev_tokens, self.active, self.budgets,
-                self.eos_ids, props)
-            parts.append(payload)
+        self._close_turnaround()
+        with phase("serve.decode_dispatch", self.stats):
+            for _ in range(steps):
+                spec.draft_caches, props = dfn(
+                    spec.draft_params, spec.draft_caches, spec.prev_tokens,
+                    self.tokens, self.lens, self.active)
+                (self.caches, self.lens, self.tokens, spec.prev_tokens,
+                 self.active, self.budgets, payload) = vfn(
+                    self.params, self.caches, self.page_table, self.lens,
+                    self.tokens, spec.prev_tokens, self.active,
+                    self.budgets, self.eos_ids, props)
+                parts.append(payload)
         # post-success, exactly like the non-spec chunk: a failed first
         # call must not mask these keys from a later warmup()
         self._warmed.add(dkey)
         self._warmed.add(vkey)
         self.stats["decode_calls"] += 1
         stamped = self._collect_firsts()
-        pk = np.asarray(parts[0] if steps == 1
-                        else jnp.concatenate(parts, axis=1))
+        with phase("serve.chunk_sync", self.stats):
+            pk = np.asarray(parts[0] if steps == 1
+                            else jnp.concatenate(parts, axis=1))
+        self._t_synced = time.perf_counter()
+        with phase("serve.deliver", self.stats):
+            self._deliver_spec(pk, stamped, t0)
+
+    def _deliver_spec(self, pk, stamped, t0):
+        """Host half of a speculative chunk: append what each slot
+        emitted, account the rejected proposals, retire what finished."""
+        spec = self.spec
+        k = spec.k
+        steps = self._spec_steps_per_chunk
         blocks = pk.reshape(self.S, steps, k + 3)
         em = blocks[:, :, : k + 1]           # emitted tokens, -1 padded
         acc = blocks[:, :, k + 1]            # raw accepted-run lengths
@@ -1902,36 +1938,37 @@ class BatchDecodeEngine:
         if fn is None:
             fn = self._build_program("decode")
             self._programs["decode"] = fn
+        self._close_turnaround()
         t0 = time.perf_counter()
-        (self.caches, self.tokens, self.lens, self.active, self.budgets,
-         self.key, packed) = fn(*args)
+        with phase("serve.decode_dispatch", self.stats):
+            (self.caches, self.tokens, self.lens, self.active, self.budgets,
+             self.key, packed) = fn(*args)
         # post-success: a failed first chunk must not mask the key from a
         # later warmup()
         self._warmed.add("decode")
         self.stats["decode_calls"] += 1
         self._collect_firsts()
-        pk = np.asarray(packed)                 # the ONE sync per chunk
-        if perf_on and pure_decode:
-            # the packed readback IS this chunk's host sync, so the wall
-            # is real device time (plus the per-call link floor)
-            p.observe("serving.decode", time.perf_counter() - t0,
-                      bucket=cost_bucket)
-        em, act = pk[:, :-1], pk[:, -1].astype(bool)
-        t_sync = None
-        for slot, s in enumerate(self._host_slots):
-            if s.req is None:
-                continue
-            toks = [int(t) for t in em[slot] if t >= 0]
-            s.emitted.extend(toks)
-            self.stats["tokens_out"] += len(toks)
-            tr = _trace_of(s.req)
-            if tr is not None and toks:
-                if t_sync is None:
-                    t_sync = time.perf_counter()
-                tr.event("decode.chunk", t0=t0, t1=t_sync,
-                         tokens=len(toks))
-            if not act[slot] or len(s.emitted) >= s.budget:
-                self._retire(slot)
+        with phase("serve.chunk_sync", self.stats):
+            pk = np.asarray(packed)             # the ONE sync per chunk
+        t_sync = self._t_synced = time.perf_counter()
+        with phase("serve.deliver", self.stats):
+            if perf_on and pure_decode:
+                # the packed readback IS this chunk's host sync, so the
+                # wall is real device time (plus the per-call link floor)
+                p.observe("serving.decode", t_sync - t0, bucket=cost_bucket)
+            em, act = pk[:, :-1], pk[:, -1].astype(bool)
+            for slot, s in enumerate(self._host_slots):
+                if s.req is None:
+                    continue
+                toks = [int(t) for t in em[slot] if t >= 0]
+                s.emitted.extend(toks)
+                self.stats["tokens_out"] += len(toks)
+                tr = _trace_of(s.req)
+                if tr is not None and toks:
+                    tr.event("decode.chunk", t0=t0, t1=t_sync,
+                             tokens=len(toks))
+                if not act[slot] or len(s.emitted) >= s.budget:
+                    self._retire(slot)
 
     def flush(self):
         """Deliver results for slots that finished during admission (first

@@ -65,7 +65,9 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core import flags as _flags
+from ..observability.recorder import phase, phase_counters
 from ..resilience.chaos import chaos_point
+from .decode_engine import CHUNK_PHASES
 from .kv_pool import pages_needed
 from .robustness import (
     CircuitBreaker,
@@ -90,6 +92,14 @@ from .robustness import safe_set as _rob_safe_set
 _obs_srv = None
 
 _BREAKER_STATE_NUM = {"closed": 0, "half_open": 1, "open": 2}
+
+# the continuous loop's phases: disjoint, on the engine thread; the first
+# three are timed in _loop_continuous, CHUNK_PHASES inside the decode engine
+LOOP_PHASES = ("serve.sweep", "serve.wait_request", "serve.admit",
+               *CHUNK_PHASES)
+# what the decode engine counts for itself and the loop copies after a chunk
+_ENGINE_SPAN_KEYS = ("turnaround_s", "turnaround_n",
+                     *phase_counters(CHUNK_PHASES))
 
 # process-wide request ids: the join key across SLO metrics, trace spans
 # (request#<id>) and flight-recorder lifecycle events
@@ -464,7 +474,11 @@ class ServingEngine:
         self.stats = {"requests": 0, "batches": 0, "batched_requests": 0,
                       "decode_tokens": 0, "batches_failed": 0, "shed": 0,
                       "cancelled": 0, "deadline_expired": 0,
-                      "decode_failures": 0}
+                      "decode_failures": 0,
+                      # the continuous loop's phase clock (docs/serving.md)
+                      **phase_counters(LOOP_PHASES), "admit_deferred": 0,
+                      "turnaround_s": 0.0, "turnaround_n": 0,
+                      "loop_busy_s": 0.0}
         # robustness limits: explicit args win, else FLAGS_serving_* (whose
         # 0 default means "off"), so a fleet can arm them by env alone
         self.max_queue = _flag_or(max_queue, "serving_max_queue")
@@ -1214,18 +1228,8 @@ class ServingEngine:
         self._hang_tripped = False
         self._decode_started_at = time.monotonic()
         try:
-            from ..observability.recorder import trace_region
-
-            region = trace_region("serving.decode_chunk", "serving")
-        except Exception:
-            region = None
-        try:
             chaos_point("serving.decode")
-            if region is not None:
-                with region:
-                    fn()
-            else:
-                fn()
+            fn()
         finally:
             dt = time.monotonic() - self._decode_started_at
             self._decode_started_at = None
@@ -1354,8 +1358,12 @@ class ServingEngine:
         run multi-step decode chunks, retire finished slots mid-flight. The
         BatchDecodeEngine delivers each request's future on retirement."""
         eng = self._engine
+        stats = self.stats
         while not self._stop.is_set():
-            self._sweep_slots()
+            t_iter = time.perf_counter()
+            waited = stats["span_s.serve.wait_request"]
+            with phase("serve.sweep", stats):
+                self._sweep_slots()
             busy = any(s.req is not None for s in eng._host_slots)
             draining = self._draining.is_set()
             if draining and not busy:
@@ -1365,11 +1373,22 @@ class ServingEngine:
                 if self._breaker.allow():
                     probe = self._breaker.state == "half_open"
                     while True:
-                        req = self._next_request(block=not busy)
+                        req = self._next_request(block=False)
+                        if req is None and not busy:
+                            with phase("serve.wait_request", stats):
+                                req = self._next_request(block=True)
+                            # idle for want of work: what follows the last
+                            # sync is no turn-around of the host's
+                            eng._t_synced = None
                         if req is None:
                             break
+                        # one count per admission; a refusal (no slot,
+                        # no pages) is counted apart, as admit_deferred
                         try:
-                            if eng._admit(req):
+                            with phase("serve.admit", stats, n=0) as admit:
+                                ok = eng._admit(req)
+                                admit.n = int(ok)
+                            if ok:
                                 admitted = True
                                 busy = True
                                 self._bump("batched_requests")
@@ -1379,6 +1398,7 @@ class ServingEngine:
                                 # no free slot: hold at the FIFO head, decode
                                 # to free one — never rotated behind arrivals
                                 self._deferred.appendleft(req)
+                                stats["admit_deferred"] += 1
                                 break
                         except BaseException as e:  # noqa: BLE001
                             req.result._set(error=e)
@@ -1395,9 +1415,16 @@ class ServingEngine:
                 obs("batch_size",
                     sum(1 for s in eng._host_slots if s.req is not None))
             before = eng.stats["tokens_out"]
+            # the host's part of the iteration is booked before the chunk
+            # delivers anything, the chunk's part after it: whoever copies
+            # stats on receiving a result finds the seconds and the spans
+            # of the same work in them
+            t_chunk = self._book_busy(
+                t_iter, stats["span_s.serve.wait_request"] - waited)
             try:
                 self._decode_attempt(eng._decode_chunk)
             except BaseException as e:  # noqa: BLE001 — fail the slots
+                self._book_busy(t_chunk)
                 for i, s in enumerate(eng._host_slots):
                     if s.req is not None:
                         s.req.result._set(error=e)
@@ -1413,6 +1440,7 @@ class ServingEngine:
                 if obs is not None:
                     obs("batch", "error")
                 continue
+            self._book_busy(t_chunk)
             self._breaker.record_success()
             self._last_decode_ok = time.monotonic()
             self._bump("decode_tokens", eng.stats["tokens_out"] - before)
@@ -1420,3 +1448,17 @@ class ServingEngine:
                 obs("batch", "ok")
             if admitted:
                 self._bump("batches")
+
+    def _book_busy(self, since: float, waited: float = 0.0) -> float:
+        """Add the wall time since ``since``, less the ``waited`` seconds
+        of it that the loop blocked for want of work, to ``loop_busy_s``
+        (called only in iterations that run a chunk), then copy the
+        engine's own spans: in that order, so a reader between the two
+        finds the spans short of the busy time, never over it. Returns
+        now."""
+        stats, eng = self.stats, self._engine.stats
+        now = time.perf_counter()
+        stats["loop_busy_s"] += (now - since) - waited
+        for k in _ENGINE_SPAN_KEYS:
+            stats[k] = eng[k]
+        return now

@@ -10,10 +10,10 @@ active.
 
 Design constraints:
 
-* zero dependencies, thread-safe: a ``threading.local`` span stack gives
-  correct nesting per thread; completed spans append to a bounded
-  ``deque`` (ring buffer — old events fall off, the recorder never OOMs a
-  long-running trainer);
+* no dependency but ``jax.profiler``, thread-safe: a ``threading.local``
+  span stack gives correct nesting per thread; completed spans append
+  to a bounded ``deque`` (ring buffer — old events fall off, the
+  recorder never OOMs a long-running trainer);
 * two admission paths: *hooked* spans from the hot-path instrumentation
   (dispatch/autograd/collectives) are gated by ``FLAGS_obs_trace``, while
   *explicit* spans (``RecordEvent`` / ``trace_region(..., force=True)``)
@@ -30,6 +30,8 @@ import threading
 import time
 from collections import defaultdict, deque
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _PID = 0  # single-process timeline; multi-host traces merge on rank metadata
 
@@ -92,9 +94,7 @@ class Recorder:
         timelines; hot-path hooks pass False (annotation costs ~µs)."""
         ann = None
         if annotate:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(name)
+            ann = TraceAnnotation(name)
             ann.__enter__()
         self._local.stack.append((name, cat, time.perf_counter(), ann))
 
@@ -218,6 +218,57 @@ class Recorder:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f)
         return path
+
+
+class phase:
+    """One named phase of a long-running loop, timed where it happens.
+
+    Reads ``time.perf_counter()`` at both ends, holds a
+    ``jax.profiler.TraceAnnotation`` open in between (jax records it only
+    while a profiler session is on, so the span lands on the device
+    trace's clock; otherwise it costs about a microsecond), and adds the
+    duration to ``sink["span_s.<name>"]`` and ``n`` to
+    ``sink["span_n.<name>"]``: the counters are always on. With
+    ``FLAGS_obs_trace`` on it also records into the ring, as
+    :class:`trace_region` does. The body may set ``n`` (an admission that
+    was refused counts none); ``sink`` must already hold both keys, so a
+    reader copying it on another thread never sees it change size.
+    """
+
+    __slots__ = ("name", "sink", "n", "_ann", "_t0")
+
+    def __init__(self, name: str, sink: dict, n: int = 1):
+        self.name = name
+        self.sink = sink
+        self.n = n
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self.sink["span_s." + self.name] += t1 - self._t0
+        self.sink["span_n." + self.name] += self.n
+        from . import _recorder_if_tracing
+
+        rec = _recorder_if_tracing()
+        if rec is not None:
+            rec._record(self.name, "phase", self._t0, t1, None)
+        return False
+
+
+def phase_counters(names) -> Dict[str, float]:
+    """The zeroed ``span_s.*`` / ``span_n.*`` keys :class:`phase` adds to,
+    for a ``stats`` dict to start with."""
+    out: Dict[str, float] = {}
+    for name in names:
+        out["span_s." + name] = 0.0
+        out["span_n." + name] = 0
+    return out
 
 
 class trace_region:
