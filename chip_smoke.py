@@ -511,8 +511,8 @@ def _kernel_paged(size: Size, interpret: bool, W: int, int8: bool) -> Dict[str, 
         return pool[table].reshape(S, P * ps, kvh, hd)
 
     def reference(q, kview, vview):
-        return de.BatchDecodeEngine._ref_gqa_attention(
-            None, q, kview, vview, lens, rep=rep, scale=scale)
+        return de._ref_gqa_attention(q, kview, vview, lens, rep=rep,
+                                     scale=scale)
 
     if int8:
         (kq, ksc), (vq, vsc) = de._kv_quant_pages(kpool), \

@@ -16,10 +16,13 @@ layout (``kv_layout="paged"``, the default) fixes it the static-shape way:
 
 * PAGED KV POOL: one ``[num_pages, page_size, kvh, hd]`` buffer per layer
   plus a device-resident page table ``[slots, max_len/page_size]`` int32.
-  The decode body GATHERS each layer's logical ``[slots, L]`` view through
-  the page table (the XLA equivalent of the GPU block table — a gather
-  index, not pointer chasing), runs the UNCHANGED ragged-attention math,
-  and scatters the one newly written position back to its physical page.
+  The decode body GATHERS each layer's logical view through the page
+  table (the XLA equivalent of the GPU block table — a gather index, not
+  pointer chasing), runs the UNCHANGED ragged-attention math, and
+  scatters the one newly written position back to its physical page. The
+  view is as wide as the longest live context needs, not ``[slots, L]``:
+  one rung of a short static ladder of page counts, chosen in-graph once
+  per call (``_view_rung``).
   Admission allocates pages from a host-side free list
   (:mod:`~.kv_pool`), scatters the prefill prefix page-by-page, and slot
   retirement returns pages — so concurrency is bounded by total KV bytes
@@ -46,6 +49,7 @@ layout (``kv_layout="paged"``, the default) fixes it the static-shape way:
 
 from __future__ import annotations
 
+import functools
 import sys
 import time
 from typing import Dict, List, Optional
@@ -71,6 +75,101 @@ CHUNK_PHASES = ("serve.decode_dispatch", "serve.first_sync",
 
 def _bucket(n: int, q: int = 128) -> int:
     return -(-n // q) * q
+
+
+# The view ladder, in eighths of the page table: 16, 24, 56 and 64 pages at
+# max_len 4096 with pages of 64 tokens. Each rung is there for a property of
+# the chip or of the engine's geometry, read on a TPU v5 lite at 32 slots, 8 kv
+# heads of 128 in bf16, 16 layers (PERF.md section 6, PRs 29 and 30), where a
+# view of n pages a slot is S*n*ps*kvh*hd*2 bytes = n * 4 MiB, once for K and
+# once for V in a layer.
+# * FOUR rungs, not eight: a rung is one more branch in every layer, and costs
+#   START-UP by its count, not its width: about a second of every start-up a
+#   rung at 16 layers, retrieved from the compile cache or compiled. All eight
+#   eighths put 12-16% on a 60 s start-up.
+# * 2 and 3 eighths, both low and close together: the v5e's compiler keeps a
+#   view in the chip's fast memory (layout `S(1)`) up to 28 pages a slot,
+#   112 MiB, and not from 30 pages, 120 MiB, on. A step whose views stay
+#   there costs far less a page than one whose views do not (steps on rungs
+#   16 and 24 average 14.6 ms, on 16 and 32 pages 20.2 ms), so a short
+#   context gets two rungs under that limit and not one on it. The limit is
+#   a count of BYTES, not a share of the table: with more slots, a longer
+#   max_len or wider heads the same eighths are wider views.
+# * 7 eighths: the rung under the top. A table that is nearly full drops to
+#   it in the calls where no live context has reached the last eighth (a
+#   fifth of them with contexts of 2-3.7k tokens), an eighth of gather and
+#   attention spared. A context between 3 and 7 eighths pays for 7: a rung
+#   in between costs what every rung costs.
+# * 8 eighths: the table itself; every context fits.
+VIEW_EIGHTHS = (2, 3, 7, 8)
+
+
+def _view_ladder(pages: int) -> tuple:
+    """The page counts a decode step's logical K/V view may take:
+    ``VIEW_EIGHTHS`` of the table ``pages``, (16, 24, 56, 64) for 64."""
+    return tuple(sorted({max(1, -(-pages * k // 8)) for k in VIEW_EIGHTHS}))
+
+
+@functools.lru_cache(maxsize=None)
+def _view_branches(attend, ladder: tuple, *static) -> tuple:
+    """The ``lax.switch`` branches of one attention over a bounded view:
+    ``attend(n, *static, *operands)`` for each page count ``n`` of the
+    ladder. The SAME callables come back for the same arguments, and they
+    close over nothing traced: jax keeps a branch's trace by the
+    callable's identity, so a program traces each rung once, not once a
+    layer (seconds of every start-up otherwise). Both reference
+    formulations, bf16 and int8 KV, bound their gather through here."""
+    return tuple(jax.jit(functools.partial(attend, n, *static))
+                 for n in ladder)
+
+
+def _attend_view(n, ps, n_rep, scale, q, k_new, v_new, kp, vp, page_table,
+                 pos):
+    """``_cached_attention`` against ``pool[page_table[:, :n]]``: the same
+    write-then-attend order, bottom-right mask and f32 accumulation, over
+    ``n * ps`` positions in place of ``max_len``."""
+    from ..models.llama import _cached_attention
+
+    S, table = q.shape[0], page_table[:, :n]
+    kview = kp[table].reshape(S, n * ps, *kp.shape[2:])
+    vview = vp[table].reshape(S, n * ps, *vp.shape[2:])
+    return _cached_attention(q, k_new, v_new, kview, vview, pos, n_rep,
+                             scale)[0]
+
+
+def _ref_gqa_attention(q, kview, vview, lens, *, rep, scale):
+    """Reference gather-dequant attention over a materialized logical
+    view [S, T, kvh, hd]: the same bottom-right causal rule, GQA
+    grouping (q head g*rep+r reads kv head g) and f32 accumulation as
+    the Pallas kernel — the non-kernel half of the int8-KV parity
+    pair (docs/kernels.md fallback matrix)."""
+    S, W, h, hd = q.shape
+    kvh = kview.shape[2]
+    T = kview.shape[1]
+    qg = q.astype(jnp.float32).reshape(S, W, kvh, rep, hd) * scale
+    att = jnp.einsum("swgrd,stgd->swgrt", qg,
+                     kview.astype(jnp.float32))
+    k_pos = jnp.arange(T, dtype=jnp.int32)
+    q_pos = lens[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
+    mask = k_pos[None, None, :] <= q_pos[:, :, None]      # [S, W, T]
+    att = jnp.where(mask[:, :, None, None, :], att, -1e30)
+    p = jax.nn.softmax(att, axis=-1)
+    out = jnp.einsum("swgrt,stgd->swgrd", p,
+                     vview.astype(jnp.float32))
+    return out.reshape(S, W, h, hd).astype(q.dtype)
+
+
+def _attend_view_int8(n, ps, dtype, rep, scale, q, kq, ksc, vq, vsc,
+                      page_table, lens):
+    """The int8-KV reference over the same bounded table: gather-dequant
+    ``n`` pages a slot, then :func:`_ref_gqa_attention` (the new rows are
+    in the pool already)."""
+    S, table = q.shape[0], page_table[:, :n]
+    kview = _kv_dequant_gather(kq, ksc, table, dtype).reshape(
+        S, n * ps, *kq.shape[2:])
+    vview = _kv_dequant_gather(vq, vsc, table, dtype).reshape(
+        S, n * ps, *vq.shape[2:])
+    return _ref_gqa_attention(q, kview, vview, lens, rep=rep, scale=scale)
 
 
 # -- int8 KV-page quantization (kv_quant="int8") ----------------------------
@@ -211,6 +310,31 @@ def _account(kind: str, n: int) -> None:
         goodput.account(kind, n)
     except Exception:
         pass
+
+
+class _PagedView:
+    """What a layer gets as ``cache`` in the paged decode forward: one
+    layer's K/V pool behind the page table. The layer's attention calls
+    :meth:`attend` in place of writing through a dense ``(k, v)`` view
+    (models/llama.py): the view is gathered here, as wide as ``rung`` says,
+    and the new rows come back for the engine to store in the pool."""
+
+    __slots__ = ("ladder", "page_size", "kp", "vp", "page_table", "rung")
+
+    def __init__(self, eng, kp, vp, page_table, rung):
+        self.ladder, self.page_size = eng._ladder, eng.page_size
+        self.kp, self.vp, self.page_table, self.rung = kp, vp, page_table, rung
+
+    def attend(self, q, k_new, v_new, pos, n_rep, scale):
+        """(out, K rows, V rows): :func:`_attend_view` on the rung's
+        branch, and the new rows in the pool's dtype."""
+        out = jax.lax.switch(
+            self.rung,
+            _view_branches(_attend_view, self.ladder, self.page_size, n_rep,
+                           scale),
+            q, k_new, v_new, self.kp, self.vp, self.page_table, pos)
+        return (out, k_new.astype(self.kp.dtype),
+                v_new.astype(self.vp.dtype))
 
 
 class _Slot:
@@ -358,6 +482,7 @@ class BatchDecodeEngine:
             self.prefix = PrefixCache()
             self.prefix_enabled = bool(prefix_cache)
             self.page_table = jnp.zeros((self.S, self.P), jnp.int32)
+            self._ladder = _view_ladder(self.P)
             if self.kv_quant == "int8":
                 # each pool entry is (codes int8, scale f32 [pages, kvh]):
                 # a nested pytree, so program args / scan carries /
@@ -396,6 +521,7 @@ class BatchDecodeEngine:
             self.prefix = None
             self.prefix_enabled = False
             self.page_table = None
+            self._ladder = ()
             self.caches = [(jnp.zeros((self.S, self.L, kvh, hd), dtype),
                             jnp.zeros((self.S, self.L, kvh, hd), dtype))
                            for _ in range(cfg.num_hidden_layers)]
@@ -431,7 +557,9 @@ class BatchDecodeEngine:
         self._first_pending: Dict[int, object] = {}  # slot -> device scalar
         self.stats = {"tokens_out": 0, "requests": 0, "decode_calls": 0,
                       "peak_busy": 0, "turnaround_s": 0.0,
-                      "turnaround_n": 0, **phase_counters(CHUNK_PHASES)}
+                      "turnaround_n": 0, "decode_view_pages": 0,
+                      "decode_table_pages": 0,
+                      **phase_counters(CHUNK_PHASES)}
         # perf_counter at the return of the last chunk's sync, until the
         # next compiled program is called (or the loop waits for work)
         self._t_synced: Optional[float] = None
@@ -666,23 +794,50 @@ class BatchDecodeEngine:
                 logits = unwrap(self.model.lm_head(hidden))
         return logits, [(unwrap(k), unwrap(v)) for k, v in new_caches]
 
-    def _forward_paged(self, params, toks, pools, page_table, lens):
+    def _view_rung(self, lens, active, span: int):
+        """Which rung of ``self._ladder`` (an int32 index) is the narrowest
+        view that holds every position an ACTIVE slot touches while its
+        ``lens`` advances by ``span``. A retired slot's ``lens`` is stale
+        and does not count. The fused kernel walks the whole table itself:
+        there the rung is the top one."""
+        top = len(self._ladder) - 1
+        if self.fused.get("enabled"):
+            return jnp.int32(top)
+        extent = jnp.max(jnp.where(active, lens, 0)) + span
+        holds = jnp.asarray(self._ladder, jnp.int32) * self.page_size
+        return jnp.minimum(jnp.sum(holds < extent), top).astype(jnp.int32)
+
+    def _view_pages_column(self, rung):
+        """The rung's page count as an ``[S, 1]`` column of a program's
+        packed host-sync payload: how the host learns what was gathered."""
+        pages = jnp.asarray(self._ladder, jnp.int32)[rung]
+        return jnp.broadcast_to(pages, (self.S, 1))
+
+    def _forward_paged(self, params, toks, pools, page_table, lens, rung):
         """One forward over ``toks [S, W]`` at per-slot positions
         ``lens..lens+W-1`` through the page table: each layer gathers its
-        logical ``[S, P*page_size]`` K/V view (the page table IS the gather
-        index), runs the unchanged ragged-attention math against it, and
-        scatters all W newly written positions back to their physical
-        pages. W=1 is the chunked decode step; the speculative verify
-        program runs W=k+1 through the SAME implementation, so the two
-        paths cannot diverge. Retired slots' table rows are zeroed and
-        positions past ``max_len`` are redirected explicitly, so
-        out-of-stream writes land in the sacrificial null page — never in
-        another slot's pages."""
+        logical K/V view (the page table IS the gather index), runs the
+        unchanged ragged-attention math against it, and scatters all W
+        newly written positions back to their physical pages. The view is
+        LENGTH-BOUNDED: ``[S, n*page_size]`` gathered through
+        ``page_table[:, :n]``, ``n`` the page count of ``rung``
+        (:meth:`_view_rung`, taken once per program call from the longest
+        ACTIVE context), not the whole ``[S, P*page_size]`` table — the
+        positions left out were masked before the softmax and contributed
+        exact zeros, so tokens are unchanged while gather and attention
+        cost follows what is live. W=1 is the chunked decode step; the
+        speculative verify program runs W=k+1 through the SAME
+        implementation, so the two paths cannot diverge. Retired slots'
+        table rows are zeroed and positions past ``max_len`` are
+        redirected explicitly, so out-of-stream writes land in the
+        sacrificial null page — never in another slot's pages; a stale
+        ``lens`` past the view finds its write into the view dropped, and
+        the pool takes the new rows as projected, not read back from the
+        view."""
         S, ps, P, L = self.S, self.page_size, self.P, self.L
         W = toks.shape[1]
         rows = jnp.arange(S, dtype=jnp.int32)[:, None]         # [S, 1]
         pos = lens[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-        pos_g = jnp.minimum(pos, P * ps - 1)
         page_idx = jnp.minimum(pos // ps, P - 1)
         phys = jnp.where(
             pos < L,
@@ -696,22 +851,22 @@ class BatchDecodeEngine:
             # test token-exact) — the generic layer call below would
             # attend this step's full-precision rows instead
             return self._forward_paged_fused(params, toks, pools,
-                                             page_table, lens, phys, off)
+                                             page_table, lens, phys, off,
+                                             rung)
         with _ag.no_grad(), self.model.bind_state(params):
             mdl = self.model.model
             x = mdl.embed_tokens(toks)
             cos, sin = mdl.rope_cos, mdl.rope_sin
             new_pools = []
             for layer, (kp, vp) in zip(mdl.layers, pools):
-                kview = kp[page_table].reshape(
-                    S, P * ps, *kp.shape[2:])
-                vview = vp[page_table].reshape(
-                    S, P * ps, *vp.shape[2:])
-                x, (kc, vc) = layer(x, cos, sin, None,
-                                    cache=(kview, vview), pos=lens)
-                kc, vc = unwrap(kc), unwrap(vc)
-                kp = kp.at[phys, off].set(kc[rows, pos_g])
-                vp = vp.at[phys, off].set(vc[rows, pos_g])
+                x, (k_new, v_new) = layer(
+                    x, cos, sin, None, pos=lens,
+                    cache=_PagedView(self, kp, vp, page_table, rung))
+                # the write to the physical pool stays outside the switch:
+                # the donated pool is updated in place, never carried
+                # through a branch
+                kp = kp.at[phys, off].set(unwrap(k_new))
+                vp = vp.at[phys, off].set(unwrap(v_new))
                 new_pools.append((kp, vp))
             hidden = mdl.norm(x)
             if self.model.lm_head is None:
@@ -720,29 +875,8 @@ class BatchDecodeEngine:
                 logits = unwrap(self.model.lm_head(hidden))
         return logits, new_pools
 
-    def _ref_gqa_attention(self, q, kview, vview, lens, *, rep, scale):
-        """Reference gather-dequant attention over a materialized logical
-        view [S, T, kvh, hd]: the same bottom-right causal rule, GQA
-        grouping (q head g*rep+r reads kv head g) and f32 accumulation as
-        the Pallas kernel — the non-kernel half of the int8-KV parity
-        pair (docs/kernels.md fallback matrix)."""
-        S, W, h, hd = q.shape
-        kvh = kview.shape[2]
-        T = kview.shape[1]
-        qg = q.astype(jnp.float32).reshape(S, W, kvh, rep, hd) * scale
-        att = jnp.einsum("swgrd,stgd->swgrt", qg,
-                         kview.astype(jnp.float32))
-        k_pos = jnp.arange(T, dtype=jnp.int32)
-        q_pos = lens[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-        mask = k_pos[None, None, :] <= q_pos[:, :, None]      # [S, W, T]
-        att = jnp.where(mask[:, :, None, None, :], att, -1e30)
-        p = jax.nn.softmax(att, axis=-1)
-        out = jnp.einsum("swgrt,stgd->swgrd", p,
-                         vview.astype(jnp.float32))
-        return out.reshape(S, W, h, hd).astype(q.dtype)
-
     def _forward_paged_fused(self, params, toks, pools, page_table, lens,
-                             phys, off):
+                             phys, off, rung):
         """The fused-kernel form of :meth:`_forward_paged`: identical
         math (same projections, rope offsets, write positions and causal
         rule — parity is test-pinned token-exact), but each layer
@@ -753,7 +887,7 @@ class BatchDecodeEngine:
         submodules directly — `_resolve_fused` verified the shape.
 
         Under ``kv_quant="int8"`` this is ALSO the reference path (kernel
-        off → gather-dequant + :meth:`_ref_gqa_attention`): both forms
+        off → gather-dequant + :func:`_ref_gqa_attention`): both forms
         quantize-scatter first and attend the identical int8 bytes, which
         is the parity contract."""
         import math as _math
@@ -771,7 +905,7 @@ class BatchDecodeEngine:
         quant = self.kv_quant == "int8"
         use_kernel = bool(self.fused.get("enabled"))
         interp = self.fused.get("paged_attention") == "interpret"
-        ps, P = self.page_size, self.P
+        ps = self.page_size
         with _ag.no_grad(), self.model.bind_state(params):
             mdl = self.model.model
             x = mdl.embed_tokens(toks)
@@ -801,15 +935,11 @@ class BatchDecodeEngine:
                             scale=scale, k_scale=ksc, v_scale=vsc,
                             interpret=interp)
                     else:
-                        kview = _kv_dequant_gather(
-                            kq, ksc, page_table, self._kv_dtype).reshape(
-                                S, P * ps, kvh, hd)
-                        vview = _kv_dequant_gather(
-                            vq, vsc, page_table, self._kv_dtype).reshape(
-                                S, P * ps, kvh, hd)
-                        out = self._ref_gqa_attention(
-                            unwrap(q), kview, vview, lens, rep=rep,
-                            scale=scale)
+                        out = jax.lax.switch(
+                            rung,
+                            _view_branches(_attend_view_int8, self._ladder,
+                                           ps, self._kv_dtype, rep, scale),
+                            unwrap(q), kq, ksc, vq, vsc, page_table, lens)
                     new_pools.append(((kq, ksc), (vq, vsc)))
                 else:
                     kp = kp.at[phys, off].set(unwrap(k).astype(kp.dtype))
@@ -1015,21 +1145,23 @@ class BatchDecodeEngine:
     def _decode_program(self, n_steps: int):
         """``n_steps`` decode steps over all slots in one program; per-slot
         eos (-1 = none) and budget countdown in-graph. Returns the packed
-        [slots, n_steps+1] int32 host-sync payload (emitted tokens, -1
-        where idle, last column = active flag). A factory so the perf
-        plane can lower an ``n_steps=1`` variant for cost capture — XLA's
-        cost analysis counts a scan body ONCE regardless of trip count,
-        so the chunk program's own count would under-report by ~chunk.
+        int32 host-sync payload: [slots, n_steps+1] (emitted tokens, -1
+        where idle, then the active flag), and [slots, n_steps+2] in the
+        paged layout, whose last column is the pages of the K/V view that
+        every step of the call gathered. A factory so the perf plane can
+        lower an ``n_steps=1`` variant for cost capture — XLA's cost
+        analysis counts a scan body ONCE regardless of trip count, so the
+        chunk program's own count would under-report by ~chunk.
         Paged layout threads the pool through the scan carry and reads the
         (loop-invariant) page table as a plain capture-free argument."""
 
         paged = self.kv_layout == "paged"
 
         def step(caches, tokens, lens, active, temps, budgets, top_ks,
-                 eos_ids, key, params, page_table):
+                 eos_ids, key, params, page_table, rung):
             if paged:
                 logits, caches = self._forward_paged(
-                    params, tokens[:, None], caches, page_table, lens)
+                    params, tokens[:, None], caches, page_table, lens, rung)
             else:
                 logits, caches = self._forward(params, tokens[:, None],
                                                caches, lens)
@@ -1046,20 +1178,25 @@ class BatchDecodeEngine:
 
         def run(params, caches, page_table, tokens, lens, active, temps,
                 eos_ids, budgets, top_ks, key):
+            # one rung for the whole call, from where the longest live
+            # context will stand after its last step
+            rung = self._view_rung(lens, active, n_steps) if paged else None
+
             def body(carry, _):
                 caches, tokens, lens, active, budgets, key = carry
                 caches, tokens, lens, active, budgets, key, emitted = step(
                     caches, tokens, lens, active, temps, budgets, top_ks,
-                    eos_ids, key, params, page_table)
+                    eos_ids, key, params, page_table, rung)
                 return (caches, tokens, lens, active, budgets, key), emitted
 
             (caches_, tokens_, lens_, active_, budgets_, key_), out = \
                 jax.lax.scan(
                     body, (caches, tokens, lens, active, budgets, key), None,
                     length=n_steps)
-            packed = jnp.concatenate(
-                [out.T, active_[:, None].astype(jnp.int32)],
-                axis=1)                                 # [slots, n_steps+1]
+            cols = [out.T, active_[:, None].astype(jnp.int32)]
+            if paged:
+                cols.append(self._view_pages_column(rung))
+            packed = jnp.concatenate(cols, axis=1)  # [slots, n_steps+1(+1)]
             return caches_, tokens_, lens_, active_, budgets_, key_, packed
 
         if paged:
@@ -1186,7 +1323,7 @@ class BatchDecodeEngine:
         if kind == "verify":
             return (self.caches, self.lens, self.tokens,
                     self.spec.prev_tokens, self.active, self.budgets,
-                    jnp.zeros((self.S, info["k"] + 3), jnp.int32))
+                    jnp.zeros((self.S, info["k"] + 4), jnp.int32))
         return (self.caches, self.lens, self.tokens, self.active,
                 self.temps, self.eos_ids, self.budgets, self.top_ks,
                 self.key, jnp.int32(0))
@@ -1281,7 +1418,7 @@ class BatchDecodeEngine:
             if self.spec is not None and self._spec_steps_per_chunk > 1:
                 # the spec chunk's payload concat is the one host-level op
                 # its serve path adds — flush its ~ms compile here too
-                parts = [jnp.zeros((self.S, self.spec.k + 3), jnp.int32)
+                parts = [jnp.zeros((self.S, self.spec.k + 4), jnp.int32)
                          ] * self._spec_steps_per_chunk
                 np.asarray(jnp.concatenate(parts, axis=1))
         except Exception:
@@ -1708,6 +1845,12 @@ class BatchDecodeEngine:
             self.stats["turnaround_s"] += time.perf_counter() - t
             self.stats["turnaround_n"] += 1
 
+    def _count_view(self, pages: int) -> None:
+        """Once per decode call: the pages of the K/V view its steps
+        gathered, beside the whole table's."""
+        self.stats["decode_view_pages"] += pages
+        self.stats["decode_table_pages"] += self.P
+
     def _release_kv(self, slot: int, zero_row: bool = True) -> None:
         """Return a slot's private pages to the free list, drop its prefix
         ref, and (by default) zero its page-table row so in-flight decode
@@ -1874,10 +2017,12 @@ class BatchDecodeEngine:
         spec = self.spec
         k = spec.k
         steps = self._spec_steps_per_chunk
-        blocks = pk.reshape(self.S, steps, k + 3)
+        blocks = pk.reshape(self.S, steps, k + 4)
         em = blocks[:, :, : k + 1]           # emitted tokens, -1 padded
         acc = blocks[:, :, k + 1]            # raw accepted-run lengths
         act = blocks[:, -1, k + 2].astype(bool)
+        # the widest view among the call's verify steps
+        self._count_view(int(blocks[0, :, k + 3].max()))
         chunk_emitted = 0
         for slot, s in enumerate(self._host_slots):
             if s.req is None:
@@ -1956,7 +2101,9 @@ class BatchDecodeEngine:
                 # the packed readback IS this chunk's host sync, so the
                 # wall is real device time (plus the per-call link floor)
                 p.observe("serving.decode", t_sync - t0, bucket=cost_bucket)
-            em, act = pk[:, :-1], pk[:, -1].astype(bool)
+            em, act = pk[:, :self.chunk], pk[:, self.chunk].astype(bool)
+            if self.kv_layout == "paged":
+                self._count_view(int(pk[0, self.chunk + 1]))
             for slot, s in enumerate(self._host_slots):
                 if s.req is None:
                     continue

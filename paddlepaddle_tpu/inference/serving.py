@@ -98,8 +98,8 @@ _BREAKER_STATE_NUM = {"closed": 0, "half_open": 1, "open": 2}
 LOOP_PHASES = ("serve.sweep", "serve.wait_request", "serve.admit",
                *CHUNK_PHASES)
 # what the decode engine counts for itself and the loop copies after a chunk
-_ENGINE_SPAN_KEYS = ("turnaround_s", "turnaround_n",
-                     *phase_counters(CHUNK_PHASES))
+_ENGINE_SPAN_KEYS = ("turnaround_s", "turnaround_n", "decode_view_pages",
+                     "decode_table_pages", *phase_counters(CHUNK_PHASES))
 
 # process-wide request ids: the join key across SLO metrics, trace spans
 # (request#<id>) and flight-recorder lifecycle events
@@ -478,7 +478,9 @@ class ServingEngine:
                       # the continuous loop's phase clock (docs/serving.md)
                       **phase_counters(LOOP_PHASES), "admit_deferred": 0,
                       "turnaround_s": 0.0, "turnaround_n": 0,
-                      "loop_busy_s": 0.0}
+                      "loop_busy_s": 0.0,
+                      # pages the decode steps gathered, over the table's
+                      "decode_view_pages": 0, "decode_table_pages": 0}
         # robustness limits: explicit args win, else FLAGS_serving_* (whose
         # 0 default means "off"), so a fleet can arm them by env alone
         self.max_queue = _flag_or(max_queue, "serving_max_queue")

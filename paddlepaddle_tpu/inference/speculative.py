@@ -311,10 +311,11 @@ class SpeculativeDecoder:
         prefix (the bonus token always does), the per-slot budget has room,
         and no earlier token in this run was the slot's eos. ``lens``
         advances by the emitted count — that IS the KV rollback. Returns
-        one packed [S, k+3] payload per step (k+1 emitted-token columns,
+        one packed [S, k+4] payload per step (k+1 emitted-token columns,
         -1 padded; the raw accepted-run length, -1 when the slot is
-        inactive; the end-of-step active flag) so a chunk of steps syncs
-        to the host as a single transfer."""
+        inactive; the end-of-step active flag; the pages of the K/V view
+        the forward gathered) so a chunk of steps syncs to the host as a
+        single transfer."""
 
         def run(params, caches, page_table, lens, tokens, prev, active,
                 budgets, eos_ids, proposals):
@@ -331,8 +332,9 @@ class SpeculativeDecoder:
             # greedy tokens, so quantization error shows up as a lower
             # acceptance rate, never as a divergent committed stream
             toks = jnp.concatenate([tokens[:, None], proposals], axis=1)
+            rung = eng._view_rung(lens, active, k + 1)
             logits, caches = eng._forward_paged(
-                params, toks, caches, page_table, lens)
+                params, toks, caches, page_table, lens, rung)
             g = jnp.argmax(logits.astype(jnp.float32),
                            axis=-1).astype(jnp.int32)           # [S, k+1]
             match = (proposals == g[:, :k]).astype(jnp.int32)
@@ -369,7 +371,8 @@ class SpeculativeDecoder:
             a_report = jnp.where(active, a, -1)
             payload = jnp.concatenate(
                 [emitted, a_report[:, None],
-                 active_new[:, None].astype(jnp.int32)], axis=1)
+                 active_new[:, None].astype(jnp.int32),
+                 eng._view_pages_column(rung)], axis=1)
             return (caches, lens_new, tokens_new, prev_new, active_new,
                     budgets_new, payload)
 

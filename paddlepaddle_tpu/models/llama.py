@@ -224,6 +224,18 @@ class LlamaAttention(Layer):
         self.o_proj = Linear(h, h, bias_attr=False)
 
     def forward(self, x, cos, sin, attn_mask=None, cache=None, pos=None):
+        """``cache`` (decoding from ``pos``) is this layer's K/V store, in
+        one of two forms, and the second element returned follows it:
+
+        * a ``(k, v)`` pair of dense ``[b, L, kvh, hd]`` caches: the new
+          rows are written at ``pos``, every position up to them is
+          attended (:func:`_cached_attention`), and the updated pair comes
+          back;
+        * a store that keeps its own layout, as the serving engine's paged
+          pool does: any object with ``attend(q, k_new, v_new, pos, n_rep,
+          scale) -> (out, k_rows, v_rows)`` over jax arrays, holding the
+          same write-then-attend order and causal rule. What comes back is
+          the rows for its owner to store, not a cache."""
         b, s = x.shape[0], x.shape[1]
         q = self.q_proj(x).reshape([b, s, self.num_heads, self.head_dim])
         k = self.k_proj(x).reshape([b, s, self.num_kv_heads, self.head_dim])
@@ -238,10 +250,16 @@ class LlamaAttention(Layer):
             q, k = _apply_rope(q, k, cos, sin, offset=pos)
             rep = self.num_heads // self.num_kv_heads
             scale = 1.0 / math.sqrt(self.head_dim)
-            out, kc, vc = apply_op(
-                lambda qa, ka, va, kca, vca: _cached_attention(
-                    qa, ka, va, kca, vca, pos, rep, scale),
-                q, k, v, cache[0], cache[1], op_name="cached_attention")
+            if hasattr(cache, "attend"):
+                out, kc, vc = apply_op(
+                    lambda qa, ka, va: cache.attend(qa, ka, va, pos, rep,
+                                                    scale),
+                    q, k, v, op_name="cached_attention")
+            else:
+                out, kc, vc = apply_op(
+                    lambda qa, ka, va, kca, vca: _cached_attention(
+                        qa, ka, va, kca, vca, pos, rep, scale),
+                    q, k, v, cache[0], cache[1], op_name="cached_attention")
             return self.o_proj(out.reshape([b, s, -1])), (kc, vc)
         q, k = _apply_rope(q, k, cos, sin)
         if self.num_kv_heads != self.num_heads:
