@@ -1,9 +1,9 @@
 """Builder of the ``mistral`` family: puts a configuration file's Mistral decoder
 into the program under test (``models.llama`` through ``jit.train.TrainStep``
 or ``inference.serving.ServingEngine``) with weights made on the device from
-``--seed``. A family with another block brings a builder and a reference of
-its own as new files beside this one; ``run.py`` finds them by the
-configuration's ``family`` key.
+``--seed``. A family with another block brings a builder, a reference and its
+counts of operations and bytes (``work``) as new files beside this one;
+``run.py`` finds them by the configuration's ``family`` key.
 """
 
 from __future__ import annotations
@@ -13,6 +13,8 @@ import importlib
 
 import jax
 import jax.numpy as jnp
+
+from benchmark import work  # noqa: F401  (the dense GQA decoder's counts are this family's; ``run.py`` hands them to the readers)
 
 reference = importlib.import_module("benchmark.families.mistral_reference")
 

@@ -88,6 +88,8 @@ def check_outputs(sample, ctx, control: str = ""):
 
 
 def run(ctx) -> dict:
+    from paddlepaddle_tpu.inference.serving import LOOP_PHASES      # the loop's own names for what it does between chunks
+
     cfg, traffic, seed = ctx.config, ctx.traffic, ctx.seed
     clock = time.perf_counter
     engine = ctx.family.build_serve(cfg, seed)
@@ -173,5 +175,5 @@ def run(ctx) -> dict:
                 "compile_before": compile_before, "compile_after": compile_after,
                 "slots": cfg["engine"]["max_batch_size"], "decode_chunk": cfg["engine"]["decode_chunk"],
                 "warm_requests": warmed, "checked_tokens": checked_tokens,
-                "span_names": ("loadgen.submit",), "unattributed": "engine_thread"},
+                "span_names": ("loadgen.submit", *LOOP_PHASES), "unattributed": "engine_thread"},
     }

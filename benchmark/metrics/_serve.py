@@ -27,7 +27,8 @@ def computed_span(r, page):
 
 def prompt_ops(obs, requests):
     cfg, page = obs["config"], obs["config"]["engine"]["kv_page_size"]
-    return sum(work.forward_ops(cfg, *computed_span(r, page), with_head_tokens=1) for r in requests)
+    forward_ops = work.counts(obs).forward_ops
+    return sum(forward_ops(cfg, *computed_span(r, page), with_head_tokens=1) for r in requests)
 
 
 def module_seconds(obs, pattern):
@@ -66,6 +67,6 @@ def whole_mfu(obs):
     decoded = obs["events"][obs["i_close"]][1] - obs["events"][obs["i_open"]][1]
     tokens, slots = live_context(obs, t0, t1)
     mean_ctx = tokens / slots if slots else 0.0
-    per_token = work.forward_ops(cfg, int(mean_ctx), int(mean_ctx) + 1, with_head_tokens=1)
+    per_token = work.counts(obs).forward_ops(cfg, int(mean_ctx), int(mean_ctx) + 1, with_head_tokens=1)
     ops = decoded * per_token + prompt_ops(obs, in_window(obs, "t_admit"))
     return 100.0 * ops / (t1 - t0) / work.peaks(obs["device_kind"])["flops_per_s"]

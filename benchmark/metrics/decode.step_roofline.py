@@ -8,5 +8,5 @@ def read(obs):
     if not calls:
         return None
     tokens, slots = _serve.live_context(obs, obs["trace_t0"], obs["trace_t1"])
-    least = work.decode_step_least_s(obs["config"], tokens, slots, work.peaks(obs["device_kind"]))
+    least = work.counts(obs).decode_step_least_s(obs["config"], tokens, slots, work.peaks(obs["device_kind"]))
     return 100.0 * least / (seconds / (calls * obs["decode_chunk"]))

@@ -4,8 +4,8 @@
 
 Everything that belongs to one configuration, one traffic mix or one per-layer
 metric is a file found by its name in ``BENCHMARK.json``:
-``benchmark/configs/<config>.json`` (its ``family`` names the builder and the
-reference in ``benchmark/families/``), ``benchmark/traffic/<traffic>.json``
+``benchmark/configs/<config>.json`` (its ``family`` names the builder, the
+reference and the counts of operations and bytes in ``benchmark/families/``), ``benchmark/traffic/<traffic>.json``
 (its ``kind`` names the runner in ``benchmark/kinds/``),
 ``benchmark/metrics/<metric>.py`` (``read(obs)`` returns the value, or None
 where there is nothing to read) and ``benchmark/limits/<workload>.json`` (the
@@ -197,7 +197,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, data_root: st
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": jax.device_count(),
               "memory_peak_bytes": int(ctx.memory_peak_bytes or 0)}
     obs = res["obs"]
-    obs.update(config=data.config, traffic=data.traffic, cell=data.cell, device_kind=dev.device_kind,
+    # operations and bytes are the family's to count (its ``work``); the peaks are ``benchmark.work``'s alone
+    obs.update(work=family.work, config=data.config, traffic=data.traffic, cell=data.cell,
+               device_kind=dev.device_kind,
                chips=int(data.cell["chips"]), memory_peak_bytes=device["memory_peak_bytes"],
                end_to_end=res["end_to_end"], seconds=ctx.seconds)
     values = dict(res["end_to_end"], setup_s=ctx.setup_s)

@@ -6,13 +6,17 @@ On this TPU client a device plane is ``/device:TPU:<n>`` with the lines
 ``XLA Modules`` (one event per program run, named ``jit_<fn>(<fingerprint>)``)
 and ``XLA Ops`` (one event per HLO operation, named by its HLO text); host
 threads are lines of ``/host:CPU``, and ``jax.profiler.TraceAnnotation`` spans
-appear on the ``python`` line of the thread that made them. Times are
-nanoseconds on one clock (device and host differ by about a millisecond).
+appear on the line of the Python thread that made them, which the client names
+after the interpreter as it was started: ``python``, or ``python3`` under
+``python3 benchmark/run.py``. Times are nanoseconds on one clock (device and
+host differ by about a millisecond).
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
+import itertools
 import os
 import re
 from collections import defaultdict
@@ -21,6 +25,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 Span = Tuple[str, float, float]          # name, start_ns, end_ns
 
 _WRAPPERS = re.compile(r"^(while|conditional|call)(\.|$)")
+# a Python thread's line carries the interpreter's name, whatever version it was started under
+_PYTHON_LINE = re.compile(r"^python[\d.]*$")
 
 
 def find_trace(trace_dir: str) -> str:
@@ -32,7 +38,7 @@ def find_trace(trace_dir: str) -> str:
 
 def read_planes(path: str) -> Dict[str, Dict[str, List[Span]]]:
     """{plane: {line: [(name, start_ns, end_ns)]}} of the device planes and the
-    host's python lines."""
+    host's Python threads' lines (merged under ``python``)."""
     from jax.profiler import ProfileData
 
     out: Dict[str, Dict[str, List[Span]]] = {}
@@ -41,9 +47,12 @@ def read_planes(path: str) -> Dict[str, Dict[str, List[Span]]]:
             continue
         lines = out.setdefault(plane.name, {})
         for line in plane.lines:
-            if plane.name == "/host:CPU" and line.name != "python":
-                continue
-            lines.setdefault(line.name, []).extend(
+            name = line.name
+            if plane.name == "/host:CPU":
+                if not _PYTHON_LINE.match(name):
+                    continue
+                name = "python"
+            lines.setdefault(name, []).extend(
                 (ev.name, float(ev.start_ns), float(ev.start_ns) + float(ev.duration_ns))
                 for ev in line.events)
     return out
@@ -85,20 +94,29 @@ def self_times(ops: Sequence[Span]) -> List[Tuple[str, float, float]]:
     return out
 
 
-def reduce(planes: Dict[str, Dict[str, List[Span]]], window_span: str = "bench.window",
+def reduce(planes: Dict[str, Dict[str, List[Span]]], window_span: Optional[str] = "bench.window",
            host_spans: Sequence[str] = (), unattributed: str = "unattributed") -> dict:
     """Busy and idle seconds of the traced window (averaged over the device
     planes), seconds per module and per operation of device 0, the calls of each
-    module, and the idle gaps of device 0 by host span."""
+    module (a call cut by the window's edge counts by its part inside), and the
+    idle gaps of device 0 by host span. The window is the host
+    span ``window_span``, and a trace without it is an error; only where None
+    is asked for is it the first operation's start to the last one's end."""
     host = [s for line in planes.get("/host:CPU", {}).values() for s in line]
     devices = sorted(p for p in planes if p.startswith("/device:TPU:"))
     if not devices:
         raise RuntimeError("the trace holds no device plane")
-    win = [s for s in host if s[0] == window_span]
     all_ops = [s for d in devices for s in planes[d].get("XLA Ops", [])]
     if not all_ops:
         raise RuntimeError("no operation ran on the device in the traced window")
-    w0, w1 = (win[0][1], win[0][2]) if win else (min(s[1] for s in all_ops), max(s[2] for s in all_ops))
+    if window_span is None:
+        w0, w1 = min(s[1] for s in all_ops), max(s[2] for s in all_ops)
+    else:
+        win = next((s for s in host if s[0] == window_span), None)
+        if win is None:
+            raise RuntimeError(f"the trace holds no host span {window_span!r}: "
+                               f"{len(host)} events on the host's Python lines")
+        w0, w1 = win[1], win[2]
     clip = lambda spans: [(n, max(a, w0), min(b, w1)) for n, a, b in spans if b > w0 and a < w1]
 
     busy_per_device = []
@@ -132,11 +150,17 @@ def reduce(planes: Dict[str, Dict[str, List[Span]]], window_span: str = "bench.w
             per_op[short] += self_ns
             per_op_text[name] += self_ns
             per_op_text_n[name] += 1
-    calls: Dict[str, int] = defaultdict(int)
-    for name, _, _ in mods0:
-        calls[re.sub(r"\(\d+\)$", "", name)] += 1
+    # a call that an edge of the window cuts counts by the part of it inside, as its seconds do: seconds over calls
+    # is then the time of a whole call wherever the edges fall
+    calls: Dict[str, float] = defaultdict(float)
+    for name, a, b in d0.get("XLA Modules", []):
+        if b > w0 and a < w1:
+            calls[re.sub(r"\(\d+\)$", "", name)] += (min(b, w1) - max(a, w0)) / (b - a) if b > a else 1.0
 
-    named = [s for s in host if s[0] in set(host_spans)]
+    wanted = set(host_spans)
+    named = sorted((s for s in host if s[0] in wanted), key=lambda s: s[1])
+    starts = [s[1] for s in named]
+    ends_so_far = list(itertools.accumulate((s[2] for s in named), max))     # the latest end among the spans up to each one
     gaps: Dict[str, float] = defaultdict(float)
     busy0 = union([(a, b) for _, a, b in ops0])
     edges = [w0] + [x for ab in busy0 for x in ab] + [w1]
@@ -144,10 +168,13 @@ def reduce(planes: Dict[str, Dict[str, List[Span]]], window_span: str = "bench.w
         if b <= a:
             continue
         best, cover = unattributed, 0.0
-        for n, sa, sb in named:
+        i = bisect.bisect_left(starts, b) - 1       # the spans that begin before the gap ends, latest first
+        while i >= 0 and ends_so_far[i] > a:
+            n, sa, sb = named[i]
             c = min(b, sb) - max(a, sa)
             if c > cover and c >= 0.5 * (b - a):   # a span names a gap it covers half of
                 best, cover = n, c
+            i -= 1
         gaps[best] += b - a
 
     ns = 1e-9

@@ -4,9 +4,16 @@
 is worked out from the configuration's sizes. Counts are what the mathematics
 needs (a multiply-add is two operations; causal attention counts the lower
 triangle; recomputation is not counted in an MFU), whatever implements it.
+
+The counts below are a dense GQA decoder's (the ``mistral`` family's). A family
+with another block brings a module with the same functions as a new file, names
+it ``work`` in its builder, and the readers take it from ``obs["work"]``
+(``counts(obs)``); the peaks stay here, for every family.
 """
 
 from __future__ import annotations
+
+import sys
 
 # Google Cloud documentation, "TPU v5e" system architecture: per chip 197 TFLOP/s
 # in bf16, 819 GB/s of HBM bandwidth, 16 GB of HBM. Keyed by ``device_kind`` as
@@ -15,6 +22,12 @@ PEAKS = {
     "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9,
                     "source": "cloud.google.com/tpu/docs/v5e"},
 }
+
+
+def counts(obs: dict):
+    """The module that counts operations and bytes for this run's family: what ``run.py`` put into ``obs``, or this
+    one where a hand-made ``obs`` has none."""
+    return obs.get("work") or sys.modules[__name__]
 
 
 def peaks(device_kind: str) -> dict:

@@ -88,3 +88,39 @@ def test_serve_readers_on_made_up_records(serve_obs, monkeypatch):
         assert read(name) is None, name
     serve_obs["requests"][1]["t_first"] = None      # a missed request in the tail leaves none, never an infinite one
     assert read("sched.ttft_p90_ms.steady") is None
+
+
+class _CountingWork:
+    """A family's counts handed over in ``obs["work"]``: ``benchmark.work``'s functions, each call noted."""
+
+    def __init__(self):
+        self.called = set()
+
+    def __getattr__(self, name):
+        from benchmark import work
+
+        if name == "peaks":
+            raise AssertionError("the peaks are benchmark.work's alone, never the family's")
+        self.called.add(name)
+        return getattr(work, name)
+
+
+WORK_READERS = {"serve.mfu": "forward_ops", "serve.mfu.tpot": "forward_ops", "prefill.mfu": "forward_ops",
+                "decode.step_roofline": "decode_step_least_s", "train.mfu": "train_ops_per_step",
+                "flash_fwd_roofline": "flash_forward_ops", "flash_bwd_roofline": "flash_backward_ops"}
+
+
+@pytest.mark.parametrize("name", sorted(WORK_READERS))
+def test_a_reader_takes_its_counts_from_the_family_and_reads_the_same(train_obs, serve_obs, name):
+    """``run_cell`` puts the family's ``work`` into ``obs``; for ``mistral`` that is ``benchmark.work``, so the stored
+    records read the same to the last digit with the key and without it, and the count was asked of the family."""
+    from benchmark import work
+    from benchmark.families import mistral
+
+    assert mistral.work is work
+    obs = train_obs if name.startswith(("train.", "flash_")) else serve_obs
+    read = run.load_reader(BASE, name)
+    without = read(obs)
+    family = _CountingWork()
+    assert without is not None and read(dict(obs, work=family)) == without == read(dict(obs, work=work))
+    assert WORK_READERS[name] in family.called

@@ -74,14 +74,17 @@ def test_sync_wait_share_cannot_read_over_100(obs):
 
 
 def test_manifest_gives_the_phase_readers_their_cells():
+    """The manifest's layout, not a measurement: the five entries exist, each with its source, layer and the end-to-end
+    metric it moves, and the cell each was accepted in is among its ``workloads``. Where in ``per_layer`` they stand and
+    which later cells joined them is the manifest's to grow: entries and cell names are appended, never moved."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     entries = {m["name"]: m for m in manifest["per_layer"] if m["name"] in EXPECTED}
     assert sorted(entries) == sorted(EXPECTED)
-    assert [m["name"] for m in manifest["per_layer"][-5:]] == [
-        "sched.turnaround_ms_per_chunk", "sched.deliver_ms_per_chunk", "sched.sync_wait_share",
-        "sched.admit_host_ms_per_request", "sched.first_token_wait_p50_ms"]
+    moves = {"sched.turnaround_ms_per_chunk": "out_tokens_per_s", "sched.deliver_ms_per_chunk": "out_tokens_per_s",
+             "sched.sync_wait_share": "out_tokens_per_s", "sched.admit_host_ms_per_request": "ttft_mean_ms",
+             "sched.first_token_wait_p50_ms": "ttft_mean_ms"}
+    accepted_in = {"out_tokens_per_s": "serve-chat-saturated", "ttft_mean_ms": "serve-docqa-steady"}
     for name, m in entries.items():
-        assert (m["source"], m["layer"]) == ("program_span", "scheduler")
-        cell = {"out_tokens_per_s": "serve-chat-saturated", "ttft_mean_ms": "serve-docqa-steady"}[m["moves"]]
-        assert m["workloads"] == [cell], name
+        assert (m["source"], m["layer"], m["moves"]) == ("program_span", "scheduler", moves[name]), name
+        assert accepted_in[m["moves"]] in m["workloads"], name
