@@ -49,6 +49,7 @@ layout (``kv_layout="paged"``, the default) fixes it the static-shape way:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import sys
 import time
@@ -61,8 +62,10 @@ import numpy as np
 from ..core import autograd as _ag
 from ..core.dispatch import unwrap
 from ..observability.recorder import phase, phase_counters
+from ..parallel.moe import ExpertShareLayer, PickTap
 from . import compile_plan as _cp
-from .kv_pool import PagePool, PrefixCache, pages_needed, prefix_hash
+from .kv_pool import (PagePool, PrefixCache, cache_spec_of, pages_needed,
+                      prefix_hash, spec_bytes_per_token)
 from .robustness import KVCapacityError
 from .robustness import safe_inc as _safe_inc
 from .robustness import safe_set as _safe_set
@@ -135,6 +138,38 @@ def _attend_view(n, ps, n_rep, scale, q, k_new, v_new, kp, vp, page_table,
     vview = vp[table].reshape(S, n * ps, *vp.shape[2:])
     return _cached_attention(q, k_new, v_new, kview, vview, pos, n_rep,
                              scale)[0]
+
+
+def _attend_view_latent(n, ps, scale, q_abs, q_rope, c_new, r_new, c_pool,
+                        r_pool, page_table, pos):
+    """Absorbed latent attention against ``pool[page_table[:, :n]]``: every
+    head of a slot attends the ONE row a token has, kept in two pools (the
+    latent ``c`` and its rotated key): scores ``q_abs . c + q_rope . r``,
+    values ``c``; the same write-then-attend order, bottom-right mask and f32
+    accumulation as :func:`_attend_view`. ``q_abs [S, W, H, rank]``,
+    ``q_rope [S, W, H, rope]``; returns ``[S, W, H, rank]``."""
+    S, W, H, _ = q_abs.shape
+    T = n * ps
+    table = page_table[:, :n]
+    cols = pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
+    slots = jnp.arange(S, dtype=jnp.int32)[:, None]
+
+    def view(pool, new):
+        v = pool[table].reshape(S, T, pool.shape[-1])
+        return v.at[slots, cols].set(new.astype(v.dtype))
+
+    c, r = view(c_pool, c_new), view(r_pool, r_new)
+    # the W x H queries of a slot are the rows of one matrix against its view
+    att = (jnp.einsum("smr,str->smt", q_abs.reshape(S, W * H, -1), c,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("smr,str->smt", q_rope.reshape(S, W * H, -1), r,
+                        preferred_element_type=jnp.float32)) * scale
+    valid = jnp.arange(T, dtype=jnp.int32)[None, None, :] <= cols[:, :, None]
+    p = jax.nn.softmax(
+        jnp.where(jnp.repeat(valid, H, axis=1), att, -1e30), axis=-1)
+    out = jnp.einsum("smt,str->smr", p.astype(c.dtype), c,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(S, W, H, -1).astype(q_abs.dtype)
 
 
 def _ref_gqa_attention(q, kview, vview, lens, *, rep, scale):
@@ -313,28 +348,42 @@ def _account(kind: str, n: int) -> None:
 
 
 class _PagedView:
-    """What a layer gets as ``cache`` in the paged decode forward: one
-    layer's K/V pool behind the page table. The layer's attention calls
-    :meth:`attend` in place of writing through a dense ``(k, v)`` view
-    (models/llama.py): the view is gathered here, as wide as ``rung`` says,
-    and the new rows come back for the engine to store in the pool."""
+    """What a layer gets as ``cache`` in the paged decode forward: the
+    layer's pools (in the order of its cache spec) behind the page table.
+    An attention calls :meth:`attend` (a K and a V pool: models/llama.py) or
+    :meth:`attend_latent` (one pool of latent rows:
+    models/longcat_flash.py) in place of writing through a dense cache:
+    the view is gathered here, as wide as ``rung`` says, and the new rows
+    come back for the engine to store in the pool."""
 
-    __slots__ = ("ladder", "page_size", "kp", "vp", "page_table", "rung")
+    __slots__ = ("ladder", "page_size", "pools", "page_table", "rung")
 
-    def __init__(self, eng, kp, vp, page_table, rung):
+    def __init__(self, eng, pools, page_table, rung):
         self.ladder, self.page_size = eng._ladder, eng.page_size
-        self.kp, self.vp, self.page_table, self.rung = kp, vp, page_table, rung
+        self.pools, self.page_table, self.rung = pools, page_table, rung
 
     def attend(self, q, k_new, v_new, pos, n_rep, scale):
         """(out, K rows, V rows): :func:`_attend_view` on the rung's
         branch, and the new rows in the pool's dtype."""
+        kp, vp = self.pools
         out = jax.lax.switch(
             self.rung,
             _view_branches(_attend_view, self.ladder, self.page_size, n_rep,
                            scale),
-            q, k_new, v_new, self.kp, self.vp, self.page_table, pos)
-        return (out, k_new.astype(self.kp.dtype),
-                v_new.astype(self.vp.dtype))
+            q, k_new, v_new, kp, vp, self.page_table, pos)
+        return (out, k_new.astype(kp.dtype), v_new.astype(vp.dtype))
+
+    def attend_latent(self, block, q_abs, q_rope, c_new, r_new, pos, scale):
+        """(out, c rows, key rows): :func:`_attend_view_latent` of the
+        layer's ``block``-th pair of pools on the rung's branch, and the new
+        rows in the pools' dtype."""
+        c_pool, r_pool = self.pools[2 * block: 2 * block + 2]
+        out = jax.lax.switch(
+            self.rung,
+            _view_branches(_attend_view_latent, self.ladder, self.page_size,
+                           scale),
+            q_abs, q_rope, c_new, r_new, c_pool, r_pool, self.page_table, pos)
+        return out, c_new.astype(c_pool.dtype), r_new.astype(r_pool.dtype)
 
 
 class _Slot:
@@ -369,6 +418,17 @@ class BatchDecodeEngine:
                 f"kv_layout must be 'paged' or 'contiguous', got {kv_layout!r}")
         self.model = model
         self.cfg = cfg
+        # the cache the model declares: per layer, the pools a cached token
+        # has a row in. Pools, admission scratch, page accounting and the
+        # decode forward are built from it, whatever the rows are.
+        self.cache_spec = cache_spec_of(model)
+        self._latent = any(p.role == "latent"
+                           for layer in self.cache_spec for p in layer)
+        if mesh is not None or plan is not None:
+            self._refuse_latent(
+                "a tensor-parallel plan or mesh",
+                "the plan shards a pool on its kv heads and a latent row has "
+                "one; sharding the up-projections by head is not built")
         self.S = int(max_slots)
         self.L = int(max_len or cfg.max_position_embeddings)
         self.chunk = int(chunk)
@@ -430,6 +490,11 @@ class BatchDecodeEngine:
         if kv_quant in ("", "off"):
             kv_quant = None
         if kv_quant is not None:
+            self._refuse_latent(
+                "kv_quant",
+                "an int8 page carries one scale a kv head, and a latent row "
+                "has no heads and mixes a normalised latent with rotated "
+                "keys: it needs a scheme of its own")
             if kv_quant == "int4":
                 raise ValueError(
                     "kv_quant='int4': the int8 page format (codes + "
@@ -458,7 +523,6 @@ class BatchDecodeEngine:
                     "directly (quantize-at-scatter needs the raw K/V "
                     "projections); this model is not llama-decoder-shaped")
         self.kv_quant = kv_quant
-        kvh, hd = cfg.num_key_value_heads, cfg.head_dim
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self._kv_dtype = dtype     # compute dtype for scratch/dequant even
         #   when the pool itself stores int8 codes
@@ -487,6 +551,7 @@ class BatchDecodeEngine:
                 # each pool entry is (codes int8, scale f32 [pages, kvh]):
                 # a nested pytree, so program args / scan carries /
                 # donation / bundle templates thread it unchanged
+                kvh, hd = self.cache_spec[0][0].row
                 self.caches = [
                     ((jnp.zeros((n_pages, self.page_size, kvh, hd),
                                 jnp.int8),
@@ -497,13 +562,18 @@ class BatchDecodeEngine:
                     for _ in range(cfg.num_hidden_layers)]
             else:
                 self.caches = [
-                    (jnp.zeros((n_pages, self.page_size, kvh, hd), dtype),
-                     jnp.zeros((n_pages, self.page_size, kvh, hd), dtype))
-                    for _ in range(cfg.num_hidden_layers)]
+                    tuple(jnp.zeros((n_pages, self.page_size, *p.row), dtype)
+                          for p in layer)
+                    for layer in self.cache_spec]
             if kv_host_bytes is None:
                 kv_host_bytes = int(
                     _flag_value("serving_kv_host_bytes") or 0)
             if kv_host_bytes and prefix_cache:
+                self._refuse_latent(
+                    "the host spill tier (kv_host_bytes)",
+                    "a spilled slab is laid out and checked as K/V pairs of "
+                    "[kv heads, head size] rows; the latent layout is not "
+                    "built")
                 from .kv_pool import HostPrefixTier
 
                 self.kv_host = HostPrefixTier(int(kv_host_bytes))
@@ -522,9 +592,10 @@ class BatchDecodeEngine:
             self.prefix_enabled = False
             self.page_table = None
             self._ladder = ()
-            self.caches = [(jnp.zeros((self.S, self.L, kvh, hd), dtype),
-                            jnp.zeros((self.S, self.L, kvh, hd), dtype))
-                           for _ in range(cfg.num_hidden_layers)]
+            self.caches = [
+                tuple(jnp.zeros((self.S, self.L, *p.row), dtype)
+                      for p in layer)
+                for layer in self.cache_spec]
         if self.plan is not None:
             # commit the pools (kv heads on "mp") and every host-rebuilt
             # array (replicated): deterministic placements, so the jitted
@@ -560,6 +631,18 @@ class BatchDecodeEngine:
                       "turnaround_n": 0, "decode_view_pages": 0,
                       "decode_table_pages": 0,
                       **phase_counters(CHUNK_PHASES)}
+        # expert shares (parallel.moe.ExpertShareLayer) count their picks in
+        # the decode program; the counters ride its one packed payload
+        shares = [m for m in model.sublayers()
+                  if isinstance(m, ExpertShareLayer)]
+        self._experts_held = shares[0].count if shares else 0
+        picks = {} if not shares else dict(
+            moe_picks_zero=0, moe_picks_held=0, moe_picks_absent=0,
+            moe_experts_touched=0, moe_layer_steps=0,
+            moe_experts_held=self._experts_held,
+            moe_expert_pairs=(0,) * self._experts_held)
+        self.stats.update(picks)
+        self.pick_stat_keys = tuple(picks)     # the serving loop copies these
         # perf_counter at the return of the last chunk's sync, until the
         # next compiled program is called (or the loop waits for work)
         self._t_synced: Optional[float] = None
@@ -570,6 +653,11 @@ class BatchDecodeEngine:
         # at any nonzero acceptance rate. See inference/speculative.py.
         self.spec = None
         if draft is not None or spec_k:
+            self._refuse_latent(
+                "speculative drafts (draft=/spec_k=)",
+                "the draft decoder builds K/V pair caches from its own kv "
+                "heads and shares the target's admission; pairing it with a "
+                "latent target is not built")
             if draft is None or not spec_k:
                 raise ValueError(
                     "speculative decoding needs BOTH draft= (a small "
@@ -604,8 +692,15 @@ class BatchDecodeEngine:
             # slow, it does not crash-loop
             self.load_serving_bundle(bundle)
 
+    def _refuse_latent(self, what: str, why: str) -> None:
+        """What a latent cache row cannot do yet refuses at construction,
+        with its reason, rather than serving something else in silence."""
+        if self._latent:
+            raise ValueError(f"{what} with a latent (MLA) cache row: {why}")
+
     def _repl(self, x):
         """Replicate-commit under a plan (identity single-chip)."""
+
         return x if self.plan is None else self.plan.replicate(x)
 
     def mesh_info(self) -> Dict[str, object]:
@@ -638,6 +733,12 @@ class BatchDecodeEngine:
             _safe_set("paddle_serving_kv_pages_total",
                       "allocatable KV pages in the paged pool",
                       self.pool.usable)
+            _safe_set("paddle_serving_kv_bytes_per_token",
+                      "bytes one cached token takes over every pool of "
+                      "every layer (the cache spec's rows)",
+                      self._bytes_per_token(),
+                      rows="+".join(f"{p.role}{list(p.row)}"
+                                    for p in self.cache_spec[0]))
         _safe_set("paddle_serving_kv_pages_free",
                   "KV pages currently on the free list",
                   self.pool.free_count)
@@ -657,25 +758,30 @@ class BatchDecodeEngine:
         xs = sorted(self._restore_ms)
         return round(xs[min(len(xs) - 1, int(q * len(xs)))], 3)
 
+    def _bytes_per_token(self) -> int:
+        """Bytes a cached token takes over all pools (int8 codes are one
+        byte; their page scales are counted in ``page_bytes``)."""
+        itemsize = (1 if self.kv_quant == "int8"
+                    else np.dtype(self._kv_dtype).itemsize)
+        return spec_bytes_per_token(self.cache_spec, itemsize)
+
     def kv_stats(self) -> Dict[str, object]:
         """KV-pool snapshot for ``health()``/``/healthz`` and the serving
         bench: layout, page accounting, prefix-cache hit data, host-tier
         spill/restore counters."""
-        cfg = self.cfg
-        kvh, hd = cfg.num_key_value_heads, cfg.head_dim
-        if self.kv_quant == "int8":
-            itemsize = 1                      # int8 codes cross HBM
-        else:
-            itemsize = np.dtype(self._kv_dtype).itemsize
-        per_tok = 2 * kvh * hd * itemsize * cfg.num_hidden_layers
+        per_tok = self._bytes_per_token()
+        rows = {"bytes_per_token": per_tok,
+                "row_shapes": [list(p.row) for p in self.cache_spec[0]],
+                "row_roles": [p.role for p in self.cache_spec[0]]}
         if self.kv_layout != "paged":
             return {"layout": "contiguous",
-                    "kv_bytes": int(self.S * self.L * per_tok)}
+                    "kv_bytes": int(self.S * self.L * per_tok), **rows}
         pool, pfx = self.pool, self.prefix
         # per-page scale overhead in int8 mode: one f32 per (page, head)
         # per K and V per layer — the honest page_bytes the memledger's
         # pinned-prefix reconciliation multiplies by
-        scale_bytes = (2 * kvh * 4 * cfg.num_hidden_layers
+        scale_bytes = (2 * self.cache_spec[0][0].row[0] * 4
+                       * len(self.cache_spec)
                        if self.kv_quant == "int8" else 0)
         page_bytes = int(self.page_size * per_tok + scale_bytes)
         host = {"enabled": False}
@@ -694,6 +800,7 @@ class BatchDecodeEngine:
             "occupancy": round(pool.used / max(pool.usable, 1), 4),
             "page_bytes": page_bytes,
             "kv_bytes": int(pool.num_pages * page_bytes),
+            **rows,
             "prefix": {
                 "enabled": self.prefix_enabled,
                 "entries": len(pfx),
@@ -747,6 +854,11 @@ class BatchDecodeEngine:
                                    "paged_attention": "off"}
         if not want:
             return info
+        self._refuse_latent(
+            "fused_kernels",
+            "the paged-attention kernel walks a K and a V pool of "
+            "[kv heads, head size] rows; an absorbed-latent kernel is not "
+            "built")
         from ..ops.kernels import paged_attention as _pa
 
         if self.kv_layout != "paged":
@@ -792,7 +904,7 @@ class BatchDecodeEngine:
                     self.model.model.embed_tokens.weight).T
             else:
                 logits = unwrap(self.model.lm_head(hidden))
-        return logits, [(unwrap(k), unwrap(v)) for k, v in new_caches]
+        return logits, [tuple(unwrap(c) for c in nc) for nc in new_caches]
 
     def _view_rung(self, lens, active, span: int):
         """Which rung of ``self._ladder`` (an int32 index) is the narrowest
@@ -813,9 +925,39 @@ class BatchDecodeEngine:
         pages = jnp.asarray(self._ladder, jnp.int32)[rung]
         return jnp.broadcast_to(pages, (self.S, 1))
 
-    def _forward_paged(self, params, toks, pools, page_table, lens, rung):
+    def _pick_columns(self, counts):
+        """The expert shares' counters of a decode call (``pick_counts`` and
+        the live layer-steps, summed over its steps) as whole ``[S, n]``
+        columns of the packed payload, zero padded: they come to the host
+        with the chunk's one sync."""
+        n = -(-counts.shape[0] // self.S)
+        flat = jnp.zeros((n * self.S,), jnp.int32).at[:counts.shape[0]].set(
+            counts.astype(jnp.int32))
+        return flat.reshape(n, self.S).T
+
+    def _count_picks(self, columns) -> None:
+        """Host half of :meth:`_pick_columns`: add a call's counters to
+        ``stats`` (``moe_expert_pairs`` is replaced, never mutated, so a
+        shallow copy of ``stats`` stays whole)."""
+        E = self._experts_held
+        v = columns.T.reshape(-1)
+        st = self.stats
+        st["moe_expert_pairs"] = tuple(
+            int(a) + int(b) for a, b in zip(st["moe_expert_pairs"], v[:E]))
+        st["moe_picks_held"] += int(v[:E].sum())
+        st["moe_picks_zero"] += int(v[E])
+        st["moe_picks_absent"] += int(v[E + 1])
+        st["moe_experts_touched"] += int(v[E + 2])
+        st["moe_layer_steps"] += int(v[E + 3])
+
+    def _forward_paged(self, params, toks, pools, page_table, lens, rung,
+                       tap=None):
         """One forward over ``toks [S, W]`` at per-slot positions
-        ``lens..lens+W-1`` through the page table: each layer gathers its
+        ``lens..lens+W-1`` through the page table: each layer is handed
+        its pools behind the table (a :class:`_PagedView`, whatever rows
+        the cache spec gives them) and hands back the new rows, one per
+        pool; ``tap`` (a ``parallel.moe.PickTap``) collects the picks of
+        the expert shares the layers run. Each attention gathers its
         logical K/V view (the page table IS the gather index), runs the
         unchanged ragged-attention math against it, and scatters all W
         newly written positions back to their physical pages. The view is
@@ -858,16 +1000,18 @@ class BatchDecodeEngine:
             x = mdl.embed_tokens(toks)
             cos, sin = mdl.rope_cos, mdl.rope_sin
             new_pools = []
-            for layer, (kp, vp) in zip(mdl.layers, pools):
-                x, (k_new, v_new) = layer(
-                    x, cos, sin, None, pos=lens,
-                    cache=_PagedView(self, kp, vp, page_table, rung))
-                # the write to the physical pool stays outside the switch:
-                # the donated pool is updated in place, never carried
-                # through a branch
-                kp = kp.at[phys, off].set(unwrap(k_new))
-                vp = vp.at[phys, off].set(unwrap(v_new))
-                new_pools.append((kp, vp))
+            with tap if tap is not None else contextlib.nullcontext():
+                for layer, layer_pools in zip(mdl.layers, pools):
+                    x, rows = layer(
+                        x, cos, sin, None, pos=lens,
+                        cache=_PagedView(self, layer_pools, page_table,
+                                         rung))
+                    # the write to the physical pool stays outside the
+                    # switch: the donated pool is updated in place, never
+                    # carried through a branch
+                    new_pools.append(tuple(
+                        p.at[phys, off].set(unwrap(r))
+                        for p, r in zip(layer_pools, rows)))
             hidden = mdl.norm(x)
             if self.model.lm_head is None:
                 logits = unwrap(hidden) @ unwrap(mdl.embed_tokens.weight).T
@@ -990,6 +1134,12 @@ class BatchDecodeEngine:
                 top_ks.at[slot].set(top_k),
                 key, first)
 
+    def _scratch(self, length: int, dtype):
+        """A dense cache of ``length`` positions for one sequence, per layer
+        one array per pool of the spec: what an admission prefills through."""
+        return [tuple(jnp.zeros((1, length, *p.row), dtype) for p in layer)
+                for layer in self.cache_spec]
+
     def _admit_impl(self, params, caches, lens, tokens, active, temps,
                     eos_ids, budgets, top_ks, ids, plen, slot, temp, eos,
                     budget, top_k, key):
@@ -998,21 +1148,17 @@ class BatchDecodeEngine:
         ``slot``, sample the first token, set every per-slot state element.
         No host syncs."""
         bucket = ids.shape[1]
-        kvh, hd = self.cfg.num_key_value_heads, self.cfg.head_dim
-        dtype = caches[0][0].dtype
-        scratch = [(jnp.zeros((1, bucket, kvh, hd), dtype),
-                    jnp.zeros((1, bucket, kvh, hd), dtype))
-                   for _ in range(self.cfg.num_hidden_layers)]
+        scratch = self._scratch(bucket, caches[0][0].dtype)
         logits, scratch = self._forward(params, ids, scratch, jnp.int32(0))
         row = logits[0, plen - 1].astype(jnp.float32)
         key, sub = jax.random.split(key)
         first = self._sample(row[None], temp[None], top_k[None], sub)[0]
-        out_caches = []
         zero = jnp.int32(0)
-        for (kc, vc), (ks, vs) in zip(caches, scratch):
-            kc = jax.lax.dynamic_update_slice(kc, ks, (slot, zero, zero, zero))
-            vc = jax.lax.dynamic_update_slice(vc, vs, (slot, zero, zero, zero))
-            out_caches.append((kc, vc))
+        out_caches = [
+            tuple(jax.lax.dynamic_update_slice(
+                c, s, (slot,) + (zero,) * (c.ndim - 1))
+                for c, s in zip(layer, scr))
+            for layer, scr in zip(caches, scratch)]
         return self._set_slot_state(out_caches, lens, tokens, active, temps,
                                     eos_ids, budgets, top_ks, key, slot,
                                     plen, temp, eos, budget, top_k, first)
@@ -1028,11 +1174,7 @@ class BatchDecodeEngine:
         ps = self.page_size
         npg = pages_needed(bucket, ps)
         pad = npg * ps - bucket
-        kvh, hd = self.cfg.num_key_value_heads, self.cfg.head_dim
-        dtype = self._kv_dtype
-        scratch = [(jnp.zeros((1, bucket, kvh, hd), dtype),
-                    jnp.zeros((1, bucket, kvh, hd), dtype))
-                   for _ in range(self.cfg.num_hidden_layers)]
+        scratch = self._scratch(bucket, self._kv_dtype)
         logits, scratch = self._forward(params, ids, scratch, jnp.int32(0))
         row = logits[0, plen - 1].astype(jnp.float32)
         key, sub = jax.random.split(key)
@@ -1044,30 +1186,33 @@ class BatchDecodeEngine:
         # attention mask already hides them; decode overwrites them)
         valid = (jnp.arange(npg * ps, dtype=jnp.int32)
                  < plen).reshape(npg, ps)[:, :, None, None]
-        out_pools = []
-        for (kp, vp), (ks, vs) in zip(pools, scratch):
-            if pad:
-                ks = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                vs = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            kpg = ks[0].reshape(npg, ps, kvh, hd)
-            vpg = vs[0].reshape(npg, ps, kvh, hd)
-            if self.kv_quant == "int8":
-                (kq, kscale), (vq, vscale) = kp, vp
-                kc, ksc = _kv_quant_pages(
-                    jnp.where(valid, kpg.astype(jnp.float32), 0.0))
-                vc, vsc = _kv_quant_pages(
-                    jnp.where(valid, vpg.astype(jnp.float32), 0.0))
-                out_pools.append(((kq.at[dest].set(kc),
-                                   kscale.at[dest].set(ksc)),
-                                  (vq.at[dest].set(vc),
-                                   vscale.at[dest].set(vsc))))
-            else:
-                kp = kp.at[dest].set(kpg)
-                vp = vp.at[dest].set(vpg)
-                out_pools.append((kp, vp))
+        out_pools = [self._store_pages(layer, scr, dest, npg, pad, valid)
+                     for layer, scr in zip(pools, scratch)]
         return self._set_slot_state(out_pools, lens, tokens, active, temps,
                                     eos_ids, budgets, top_ks, key, slot,
                                     plen, temp, eos, budget, top_k, first)
+
+    def _store_pages(self, layer_pools, rows, dest, npg: int, pad: int,
+                     valid):
+        """One layer's pools with ``rows`` (per pool ``[1, tokens, *row]``
+        of a prefill) written page by page to the physical pages ``dest``;
+        under int8 the K/V pair is quantized page by page, positions
+        outside ``valid`` zeroed first."""
+        ps = self.page_size
+        pages = []
+        for r in rows:
+            if pad:
+                r = jnp.pad(r, ((0, 0), (0, pad)) + ((0, 0),) * (r.ndim - 2))
+            pages.append(r[0].reshape(npg, ps, *r.shape[2:]))
+        if self.kv_quant == "int8":
+            (kq, kscale), (vq, vscale) = layer_pools
+            kc, ksc = _kv_quant_pages(
+                jnp.where(valid, pages[0].astype(jnp.float32), 0.0))
+            vc, vsc = _kv_quant_pages(
+                jnp.where(valid, pages[1].astype(jnp.float32), 0.0))
+            return ((kq.at[dest].set(kc), kscale.at[dest].set(ksc)),
+                    (vq.at[dest].set(vc), vscale.at[dest].set(vsc)))
+        return tuple(p.at[dest].set(pg) for p, pg in zip(layer_pools, pages))
 
     def _admit_prefix_program(self, n_pfx: int, tail_bucket: int):
         """Prefix-HIT admission factory (compiled per (prefix pages, tail
@@ -1084,26 +1229,24 @@ class BatchDecodeEngine:
         def impl(params, pools, page_table, lens, tokens, active, temps,
                  eos_ids, budgets, top_ks, ids, tail_plen, slot, temp, eos,
                  budget, top_k, key):
-            kvh, hd = self.cfg.num_key_value_heads, self.cfg.head_dim
             dtype = self._kv_dtype
             quant = self.kv_quant == "int8"
             row_pages = jax.lax.dynamic_slice(
                 page_table, (slot, jnp.int32(0)), (1, self.P))[0]
             pfx = row_pages[:n_pfx]
             scratch = []
-            for kp, vp in pools:
+            for layer_pools in pools:
                 if quant:
-                    (kq, ksc), (vq, vsc) = kp, vp
-                    kpfx = _kv_dequant_gather(kq, ksc, pfx, dtype).reshape(
-                        1, aligned, kvh, hd)
-                    vpfx = _kv_dequant_gather(vq, vsc, pfx, dtype).reshape(
-                        1, aligned, kvh, hd)
+                    cached = [_kv_dequant_gather(q, sc, pfx, dtype)
+                              for q, sc in layer_pools]
                 else:
-                    kpfx = kp[pfx].reshape(1, aligned, kvh, hd)
-                    vpfx = vp[pfx].reshape(1, aligned, kvh, hd)
-                zk = jnp.zeros((1, tail_bucket, kvh, hd), dtype)
-                scratch.append((jnp.concatenate([kpfx, zk], axis=1),
-                                jnp.concatenate([vpfx, zk], axis=1)))
+                    cached = [p[pfx] for p in layer_pools]
+                scratch.append(tuple(
+                    jnp.concatenate(
+                        [c.reshape(1, aligned, *c.shape[2:]),
+                         jnp.zeros((1, tail_bucket, *c.shape[2:]), dtype)],
+                        axis=1)
+                    for c in cached))
             logits, scratch = self._forward(params, ids, scratch,
                                             jnp.int32(aligned))
             row = logits[0, tail_plen - 1].astype(jnp.float32)
@@ -1112,29 +1255,10 @@ class BatchDecodeEngine:
             dest = row_pages[n_pfx:n_pfx + npg_tail]
             valid = (jnp.arange(npg_tail * ps, dtype=jnp.int32)
                      < tail_plen).reshape(npg_tail, ps)[:, :, None, None]
-            out_pools = []
-            for (kp, vp), (ks, vs) in zip(pools, scratch):
-                kt = ks[:, aligned:]
-                vt = vs[:, aligned:]
-                if pad:
-                    kt = jnp.pad(kt, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    vt = jnp.pad(vt, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                ktp = kt[0].reshape(npg_tail, ps, kvh, hd)
-                vtp = vt[0].reshape(npg_tail, ps, kvh, hd)
-                if quant:
-                    (kq, kscale), (vq, vscale) = kp, vp
-                    kc, ksc = _kv_quant_pages(
-                        jnp.where(valid, ktp.astype(jnp.float32), 0.0))
-                    vc, vsc = _kv_quant_pages(
-                        jnp.where(valid, vtp.astype(jnp.float32), 0.0))
-                    out_pools.append(((kq.at[dest].set(kc),
-                                       kscale.at[dest].set(ksc)),
-                                      (vq.at[dest].set(vc),
-                                       vscale.at[dest].set(vsc))))
-                else:
-                    kp = kp.at[dest].set(ktp)
-                    vp = vp.at[dest].set(vtp)
-                    out_pools.append((kp, vp))
+            out_pools = [
+                self._store_pages(layer, [r[:, aligned:] for r in scr], dest,
+                                  npg_tail, pad, valid)
+                for layer, scr in zip(pools, scratch)]
             return self._set_slot_state(
                 out_pools, lens, tokens, active, temps, eos_ids, budgets,
                 top_ks, key2, slot, aligned + tail_plen, temp, eos, budget,
@@ -1159,9 +1283,18 @@ class BatchDecodeEngine:
 
         def step(caches, tokens, lens, active, temps, budgets, top_ks,
                  eos_ids, key, params, page_table, rung):
+            picks = None
             if paged:
+                tap = PickTap()
                 logits, caches = self._forward_paged(
-                    params, tokens[:, None], caches, page_table, lens, rung)
+                    params, tokens[:, None], caches, page_table, lens, rung,
+                    tap=tap)
+                picks = tap.counts(mask=active)
+                if picks is not None:
+                    # ... and the expert-layer calls of a step with a live slot
+                    picks = jnp.concatenate([picks, (
+                        jnp.any(active) * len(tap.picks)).astype(
+                            jnp.int32)[None]])
             else:
                 logits, caches = self._forward(params, tokens[:, None],
                                                caches, lens)
@@ -1174,7 +1307,7 @@ class BatchDecodeEngine:
             budgets = budgets - active.astype(jnp.int32)
             active = active & ~((eos_ids >= 0) & (nxt == eos_ids)) \
                 & (budgets > 0)
-            return caches, nxt, lens, active, budgets, key, emitted
+            return caches, nxt, lens, active, budgets, key, (emitted, picks)
 
         def run(params, caches, page_table, tokens, lens, active, temps,
                 eos_ids, budgets, top_ks, key):
@@ -1184,18 +1317,20 @@ class BatchDecodeEngine:
 
             def body(carry, _):
                 caches, tokens, lens, active, budgets, key = carry
-                caches, tokens, lens, active, budgets, key, emitted = step(
+                caches, tokens, lens, active, budgets, key, ys = step(
                     caches, tokens, lens, active, temps, budgets, top_ks,
                     eos_ids, key, params, page_table, rung)
-                return (caches, tokens, lens, active, budgets, key), emitted
+                return (caches, tokens, lens, active, budgets, key), ys
 
-            (caches_, tokens_, lens_, active_, budgets_, key_), out = \
-                jax.lax.scan(
+            (caches_, tokens_, lens_, active_, budgets_, key_), (out, picks) \
+                = jax.lax.scan(
                     body, (caches, tokens, lens, active, budgets, key), None,
                     length=n_steps)
             cols = [out.T, active_[:, None].astype(jnp.int32)]
             if paged:
                 cols.append(self._view_pages_column(rung))
+            if picks is not None:
+                cols.append(self._pick_columns(picks.sum(0)))
             packed = jnp.concatenate(cols, axis=1)  # [slots, n_steps+1(+1)]
             return caches_, tokens_, lens_, active_, budgets_, key_, packed
 
@@ -2104,6 +2239,8 @@ class BatchDecodeEngine:
             em, act = pk[:, :self.chunk], pk[:, self.chunk].astype(bool)
             if self.kv_layout == "paged":
                 self._count_view(int(pk[0, self.chunk + 1]))
+                if self._experts_held:
+                    self._count_picks(pk[:, self.chunk + 2:])
             for slot, s in enumerate(self._host_slots):
                 if s.req is None:
                     continue

@@ -26,12 +26,48 @@ from __future__ import annotations
 import hashlib
 import itertools
 import json
+import math
 import struct
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = ["PagePool", "PrefixCache", "PrefixEntry", "HostPrefixTier",
-           "HostSlab", "pages_needed", "prefix_hash",
+           "HostSlab", "PoolSpec", "cache_spec_of", "spec_bytes_per_token",
+           "pages_needed", "prefix_hash",
            "serialize_page_slab", "deserialize_page_slab"]
+
+
+class PoolSpec(NamedTuple):
+    """One pool of a layer's cache: what a cached token's row in it is.
+    ``role`` is ``"k"`` or ``"v"`` (a row of ``[kv heads, head size]`` per
+    token, the grouped-query pair) or ``"latent"`` (ONE vector per token
+    shared by every head, ``[width]``: a latent-attention block's row; a
+    unit axis for the heads it does not have would sit in the pool's tiled
+    minor pair, and the TPU's compiler then re-lays the whole pool out at
+    every program's entry and exit, a second copy of it in memory)."""
+
+    role: str
+    row: Tuple[int, ...]
+
+
+def cache_spec_of(model) -> List[Tuple[PoolSpec, ...]]:
+    """The cache a model declares, per layer a tuple of :class:`PoolSpec`
+    (``model.cache_spec()``). A model that declares none is a grouped-query
+    decoder by its config: a K and a V pool of rows ``[kv heads, head size]``
+    in every layer. A page is a page whatever its rows are: the pool, the
+    prefix cache and the page table count pages, and the engine sizes its
+    buffers, its admission scratch and its bytes a token from this."""
+    declare = getattr(model, "cache_spec", None)
+    if declare is not None:
+        return [tuple(PoolSpec(*p) for p in layer) for layer in declare()]
+    cfg = model.config
+    row = (int(cfg.num_key_value_heads), int(cfg.head_dim))
+    return [(PoolSpec("k", row), PoolSpec("v", row))
+            for _ in range(cfg.num_hidden_layers)]
+
+
+def spec_bytes_per_token(spec, itemsize: int) -> int:
+    """Bytes one cached token takes over every pool of every layer."""
+    return sum(math.prod(p.row) * itemsize for layer in spec for p in layer)
 
 
 def pages_needed(tokens: int, page_size: int) -> int:

@@ -1461,6 +1461,6 @@ class ServingEngine:
         stats, eng = self.stats, self._engine.stats
         now = time.perf_counter()
         stats["loop_busy_s"] += (now - since) - waited
-        for k in _ENGINE_SPAN_KEYS:
+        for k in (*_ENGINE_SPAN_KEYS, *self._engine.pick_stat_keys):
             stats[k] = eng[k]
         return now

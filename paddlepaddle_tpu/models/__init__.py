@@ -8,6 +8,8 @@ define-by-run code runs eagerly and traces to one XLA program via
 * bert   — BERT-base encoder for sequence classification (config 1)
 * resnet — ResNet family (config 2; also in vision.models)
 * moe    — Mixtral/DeepSeekMoE-style expert-parallel LM (config 5)
+* longcat_flash — latent attention (MLA), zero-compute experts and the
+  shortcut-connected double layer; served with one chip's share of the experts
 """
 
 from .bert import (  # noqa: F401
@@ -26,6 +28,10 @@ from .llama import (  # noqa: F401
     LlamaForCausalLM,
     LlamaModel,
     llama_sharding_rules,
+)
+from .longcat_flash import (  # noqa: F401
+    LongcatFlashConfig,
+    LongcatFlashForCausalLM,
 )
 from .moe import (  # noqa: F401
     MoEConfig,

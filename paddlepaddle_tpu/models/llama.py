@@ -376,6 +376,16 @@ class LlamaForCausalLM(Layer):
             if config.dtype != "float32":
                 self.lm_head.to(dtype=config.dtype)
 
+    def cache_spec(self):
+        """Per layer, the pools a cached token has a row in: a K and a V pool
+        of rows ``[kv heads, head size]`` (what the serving engine sizes its
+        pools, its admission scratch and its bytes a token from)."""
+        from ..inference.kv_pool import PoolSpec
+
+        row = (self.config.num_key_value_heads, self.config.head_dim)
+        return [(PoolSpec("k", row), PoolSpec("v", row))
+                for _ in range(self.config.num_hidden_layers)]
+
     def forward(self, input_ids, labels=None, attn_mask=None):
         hidden = self.model(input_ids, attn_mask)
         if self.lm_head is None:

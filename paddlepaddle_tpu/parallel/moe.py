@@ -549,6 +549,182 @@ def _dropless_moe_ffn(x, logits, wg, wu, wd, topk, align=1):
     return y, aux
 
 
+# -- one chip's share of an expert layer ----------------------------------------
+# The grouped kernel XLA emits for ``lax.ragged_dot`` on the TPU walks row tiles
+# of 512: with no more tokens than one tile, every touched expert costs a whole
+# tile there, and the plain batched matmul over the held experts is no more work
+# and needs no sort. Above it the sorted, grouped form does the work of the
+# pairs that exist.
+GROUPED_ABOVE_TOKENS = 512
+
+
+def route_scores_topk(x, router, bias, topk, scale):
+    """Softmax routing without renormalisation: ``p = softmax(f32(x) @ router)``
+    over every output of the router, the ``topk`` largest of ``p + bias`` are
+    picked, and a pick weighs ``scale * p`` (the bias chooses, it does not
+    weigh). Returns (weights [T, topk] f32, expert ids [T, topk] int32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    _, ids = jax.lax.top_k(p + bias.astype(jnp.float32), topk)
+    return scale * jnp.take_along_axis(p, ids, axis=-1), ids.astype(jnp.int32)
+
+
+def _held_experts_dense(x, w_local, wg, wu, wd):
+    """Every held expert on every token, weighted by ``w_local [T, count]``
+    (zero where the token did not pick the expert): three batched matmuls,
+    the down projection contracting expert and width at once."""
+    g = jnp.einsum("td,edf->tef", x, wg, preferred_element_type=jnp.float32)
+    u = jnp.einsum("td,edf->tef", x, wu, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u * w_local[:, :, None]).astype(x.dtype)
+    return jnp.einsum("tef,efd->td", a, wd, preferred_element_type=jnp.float32)
+
+
+def _held_experts_grouped(x, weights, local, count, wg, wu, wd):
+    """The pairs of (token, held expert) sorted by expert and run as grouped
+    matmuls (``lax.ragged_dot``), no capacity and no drop: pairs of experts
+    that are not held sort behind the last group, which no tile visits."""
+    T, k = local.shape
+    N = T * k
+    fe = local.T.reshape(-1)                       # round-major, as _counting_sort's users
+    dest, sidx, counts, _ = _counting_sort(fe, count + 1)
+    held = counts[:count]
+    xin = x[sidx % T]                              # sorted slot -> its token's row
+    mid = (jax.nn.silu(jax.lax.ragged_dot(xin, wg, held,
+                                          preferred_element_type=jnp.float32))
+           * jax.lax.ragged_dot(xin, wu, held, preferred_element_type=jnp.float32))
+    out = jax.lax.ragged_dot(mid.astype(x.dtype), wd, held,
+                             preferred_element_type=jnp.float32)
+    # rows past the last held pair were never computed: they hold anything
+    out = jnp.where((jnp.arange(N, dtype=jnp.int32) < held.sum())[:, None], out, 0.0)
+    w_pair = jnp.where(fe < count, weights.T.reshape(-1), 0.0)
+    return (out[dest] * w_pair[:, None]).reshape(k, T, -1).sum(0)
+
+
+def expert_share_ffn(x, router, bias, wg, wu, wd, *, topk, scale, num_routed,
+                     first):
+    """What ONE chip adds to an expert layer's result for tokens ``x [T, d]``:
+    it holds routed experts ``first .. first + count`` (``count`` is the
+    leading size of the stacked weights) of ``num_routed``; router outputs
+    past ``num_routed`` are zero-compute experts, the identity. The router
+    keeps its whole width and its ``topk`` picks. Returns the partial sum
+    ``sum over held picks of w * E(x) + x * sum of the picked identity
+    weights`` in float32, and ``picks [T, topk]``: the held expert's local
+    index, ``count`` for an identity pick, ``count + 1`` for an expert that
+    lives on another chip (its part is left out)."""
+    count = wg.shape[0]
+    weights, ids = route_scores_topk(x, router, bias, topk, scale)
+    local = ids - first
+    is_held = (local >= 0) & (local < count)
+    is_zero = ids >= num_routed
+    picks = jnp.where(is_held, local, jnp.where(is_zero, count, count + 1))
+    if x.shape[0] <= GROUPED_ABOVE_TOKENS:
+        w_local = jnp.sum(jnp.where(is_held[..., None], weights[..., None], 0.0)
+                          * jax.nn.one_hot(picks, count, dtype=jnp.float32), axis=1)
+        y = _held_experts_dense(x, w_local, wg, wu, wd)
+    else:
+        y = _held_experts_grouped(x, weights, jnp.where(is_held, local, count),
+                                  count, wg, wu, wd)
+    w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1, keepdims=True)
+    return y + x.astype(jnp.float32) * w_zero, picks
+
+
+def pick_counts(picks, count, mask=None):
+    """The counters of one call of an expert share, int32 ``[count + 3]``:
+    pairs on each held expert, identity picks, picks of absent experts, and
+    the held experts with at least one pair. ``mask [T]`` leaves tokens out
+    (a retired slot's lane still computes)."""
+    oh = jax.nn.one_hot(picks, count + 2, dtype=jnp.int32)
+    if mask is not None:
+        oh = oh * mask.astype(jnp.int32)[:, None, None]
+    hist = oh.sum((0, 1))
+    return jnp.concatenate([hist, jnp.sum(hist[:count] > 0, keepdims=True)])
+
+
+class PickTap:
+    """Collects, while a program is traced, the ``picks`` of every expert share
+    it runs (``with PickTap() as tap: ...`` around the model call; the layers
+    call :func:`record_picks`). The owner turns them into counters inside the
+    same trace: nothing crosses a program's edge."""
+
+    _active = None
+
+    def __init__(self):
+        self.picks = []          # (picks [T, topk], count) per expert share run
+
+    def __enter__(self):
+        self._outer, PickTap._active = PickTap._active, self
+        return self
+
+    def __exit__(self, *exc):
+        PickTap._active = self._outer
+
+    def counts(self, mask=None):
+        """Sum of :func:`pick_counts` over the calls collected (all shares
+        hold the same ``count``), or None where there was none."""
+        if not self.picks:
+            return None
+        return sum(pick_counts(p, c, mask) for p, c in self.picks)
+
+
+def record_picks(picks, count):
+    if PickTap._active is not None:
+        PickTap._active.picks.append((picks, count))
+
+
+class ExpertShareLayer(Layer):
+    """One chip's share of a sparse expert layer with zero-compute experts.
+
+    The layer is TOLD which experts it holds: ``held = (first, count)`` of
+    ``num_routed`` routed experts (SwiGLU, stacked ``[count, ...]``), beside
+    ``num_zero`` identity experts that cost nothing and belong to the token's
+    own chip. Routing is at the full width ``num_routed + num_zero`` by
+    softmax scores plus a correction bias, ``topk`` picks weighing
+    ``scaling * p`` with no renormalisation. ``forward`` returns the partial
+    sum this chip adds (what absent experts would add is left out: there is
+    no exchange here, and nothing stands in for it) and the picks
+    (:func:`expert_share_ffn`). Held experts run droplessly at static shapes;
+    the formulation follows from the token count alone."""
+
+    def __init__(self, d_model, d_hidden, num_routed, num_zero, topk,
+                 held=None, scaling=1.0, dtype="float32", init_std=0.02):
+        super().__init__(dtype=dtype)
+        from ..nn.initializer import Constant, Normal
+
+        first, count = (0, num_routed) if held is None else held
+        if not (0 <= first and count >= 1 and first + count <= num_routed):
+            raise ValueError(f"held={held!r} does not lie inside the "
+                             f"{num_routed} routed experts")
+        self.num_routed, self.num_zero, self.topk = num_routed, num_zero, topk
+        self.first, self.count, self.scaling = first, count, float(scaling)
+        normal = Normal(0.0, init_std)
+        self.router = self.create_parameter(
+            [d_model, num_routed + num_zero], default_initializer=normal)
+        self.e_score_correction_bias = self.create_parameter(
+            [num_routed + num_zero], dtype="float32",
+            default_initializer=Constant(0.0))
+        self.gate_proj = self.create_parameter(
+            [count, d_model, d_hidden], default_initializer=normal)
+        self.up_proj = self.create_parameter(
+            [count, d_model, d_hidden], default_initializer=normal)
+        self.down_proj = self.create_parameter(
+            [count, d_hidden, d_model], default_initializer=normal)
+
+    def forward(self, x):
+        shape = x.shape
+
+        def f(a, r, b, wg, wu, wd):
+            y, picks = expert_share_ffn(
+                a.reshape(-1, a.shape[-1]), r, b, wg, wu, wd, topk=self.topk,
+                scale=self.scaling, num_routed=self.num_routed, first=self.first)
+            record_picks(picks, self.count)
+            return y.astype(a.dtype).reshape(a.shape), picks
+
+        return apply_op(f, x, self.router, self.e_score_correction_bias,
+                        self.gate_proj, self.up_proj, self.down_proj,
+                        op_name="expert_share_ffn")
+
+
 class MoELayer(Layer):
     """Token-routed expert FFN bank (reference MoELayer:99).
 
