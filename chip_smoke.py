@@ -81,6 +81,7 @@ class Size:
     varlen: Tuple[int, int, int]      # packed tokens, heads, head_dim
     paged: Dict[str, int]             # slots, page, kvh, hd, rep, pages_per_slot
     moe: Dict[str, int]               # experts, d, h, tokens, topk, capacity
+    latent: Dict[str, int]            # slots, page, heads, rank, rope, pages_per_slot
 
 
 # bench.py's primary config (254M) and the serving/kernel shapes it implies
@@ -98,6 +99,10 @@ FULL = Size(
     # bench.py's MoE config: 8192 tokens top-2 over 16 experts, capacity
     # factor 1.25 -> 8192 * 2 * 1.25 / 16 = 1280 slots per expert
     moe=dict(experts=16, d=1024, h=768, tokens=8192, topk=2, capacity=1280),
+    # serve-agent-saturated's decode attention: 128 slots of 64 heads over
+    # the 512 + 64 latent row, a 4,096-token table of 64-token pages
+    latent=dict(slots=128, page=64, heads=64, rank=512, rope=64,
+                pages_per_slot=64),
 )
 
 # the tier-1 width: same code, seconds on the CPU
@@ -112,6 +117,7 @@ TINY = Size(
     varlen=(32, 2, 64),
     paged=dict(slots=2, page=8, kvh=2, hd=16, rep=2, pages_per_slot=3),
     moe=dict(experts=4, d=16, h=24, tokens=48, topk=2, capacity=8),
+    latent=dict(slots=3, page=8, heads=4, rank=16, rope=8, pages_per_slot=4),
 )
 
 
@@ -479,6 +485,21 @@ def _kernel_varlen(size: Size, interpret: bool) -> Dict[str, object]:
     return _compare("flash_varlen fwd+bwd", got, want, interpret)
 
 
+def _scattered_table(rng, extents, P: int, ps: int):
+    """A page table ``[S, P]``: the pages that hold each slot's ``extents``
+    tokens scattered over a pool of ``S * P + 1`` pages, the rest the null
+    page 0."""
+    import numpy as np
+
+    S = len(extents)
+    table = np.zeros((S, P), np.int32)
+    perm = rng.permutation(np.arange(1, S * P + 1))
+    for s in range(S):
+        n = -(-int(extents[s]) // ps)
+        table[s, :n] = perm[s * P:s * P + n]
+    return table
+
+
 def _kernel_paged(size: Size, interpret: bool, W: int, int8: bool) -> Dict[str, object]:
     import jax
     import jax.numpy as jnp
@@ -494,12 +515,7 @@ def _kernel_paged(size: Size, interpret: bool, W: int, int8: bool) -> Dict[str, 
     rng = np.random.default_rng(4)
     lens = rng.integers(1, P * ps - W, (S,)).astype(np.int32)
     lens[0], lens[-1] = 1, P * ps - W        # both ends of the walk
-    # each slot's visible pages scattered over the pool, the rest null
-    table = np.zeros((S, P), np.int32)
-    perm = rng.permutation(np.arange(1, n_pages))
-    for s in range(S):
-        n = -(-(int(lens[s]) + W) // ps)
-        table[s, :n] = perm[s * P:s * P + n]
+    table = _scattered_table(rng, lens + W, P, ps)
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (S, W, kvh * rep, hd), jnp.bfloat16)
     kpool = jax.random.normal(ks[1], (n_pages, ps, kvh, hd), jnp.float32)
@@ -537,6 +553,51 @@ def _kernel_paged(size: Size, interpret: bool, W: int, int8: bool) -> Dict[str, 
     want = jax.jit(reference)(q.astype(jnp.float32), kview, vview)
     return _compare(f"paged_attention W={W} int8={int8}", got, want,
                     interpret)
+
+
+def _kernel_paged_latent(size: Size, interpret: bool) -> Dict[str, object]:
+    """The absorbed-latent decode kernel against the gathered view it takes
+    the place of (``decode_engine._attend_view_latent`` over the whole
+    table, in float32), on ragged lengths with both ends of the walk."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddlepaddle_tpu.inference import decode_engine as de
+    from paddlepaddle_tpu.ops.kernels.paged_latent_attention import \
+        paged_latent_attention
+
+    p = size.latent
+    S, ps, H, rank, rope, P = (p["slots"], p["page"], p["heads"], p["rank"],
+                               p["rope"], p["pages_per_slot"])
+    n_pages = S * P + 1                      # page 0 is the null page
+    rng = np.random.default_rng(6)
+    lens = rng.integers(1, P * ps - 1, (S,)).astype(np.int32)
+    lens[0], lens[-1] = 0, P * ps - 1        # one token; the table filled
+    table = _scattered_table(rng, lens + 1, P, ps)
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    q_abs = jax.random.normal(ks[0], (S, 1, H, rank), bf)
+    q_rope = jax.random.normal(ks[1], (S, 1, H, rope), bf)
+    c_new = jax.random.normal(ks[2], (S, 1, rank), bf)
+    r_new = jax.random.normal(ks[3], (S, 1, rope), bf)
+    c_pool = jax.random.normal(ks[4], (n_pages, ps, rank), bf)
+    r_pool = jax.random.normal(ks[5], (n_pages, ps, rope), bf)
+    scale = (rank + rope) ** -0.5
+    table, lens = jnp.asarray(table), jnp.asarray(lens)
+
+    got = _mosaic("paged_latent_attention", lambda qa, qr, cn, rn, cp, rp:
+                  paged_latent_attention(qa[:, 0], qr[:, 0], cn[:, 0],
+                                         rn[:, 0], cp, rp, table, lens,
+                                         scale=scale, interpret=interpret),
+                  interpret)(q_abs, q_rope, c_new, r_new, c_pool, r_pool)
+    want = jax.jit(lambda *a: de._attend_view_latent(
+        P, ps, scale, *a, table, lens)[:, 0])(
+            *_f32(q_abs, q_rope, c_new, r_new, c_pool, r_pool))
+    row = _compare("paged_latent_attention", got, want, interpret)
+    row["live_tokens"] = int(lens.sum()) + S
+    row["table_tokens"] = S * P * ps
+    return row
 
 
 def _kernel_gather_gemm(size: Size, interpret: bool) -> Dict[str, object]:
@@ -580,6 +641,7 @@ def kernels_leg(size: Size, interpret: bool) -> Dict[str, object]:
         for int8 in (False, True):
             name = f"paged_attention_W{W}_{'int8' if int8 else 'bf16'}"
             table[name] = _kernel_paged(size, interpret, W, int8)
+    table["paged_latent_attention"] = _kernel_paged_latent(size, interpret)
     table["gather_gemm"] = _kernel_gather_gemm(size, interpret)
     return {"status": "ok", "tolerance": f"{KERNEL_TOL} * max|reference|",
             "table": table}

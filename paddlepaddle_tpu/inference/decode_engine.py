@@ -22,7 +22,10 @@ layout (``kv_layout="paged"``, the default) fixes it the static-shape way:
   scatters the one newly written position back to its physical page. The
   view is as wide as the longest live context needs, not ``[slots, L]``:
   one rung of a short static ladder of page counts, chosen in-graph once
-  per call (``_view_rung``).
+  per call (``_view_rung``). A latent (MLA) cache row's decode step
+  gathers nothing: its attention is the kernel of
+  ops/kernels/paged_latent_attention.py, which reads each slot's pages
+  where they lie, as far as the slot's own length goes.
   Admission allocates pages from a host-side free list
   (:mod:`~.kv_pool`), scatters the prefill prefix page-by-page, and slot
   retirement returns pages — so concurrency is bounded by total KV bytes
@@ -62,6 +65,9 @@ import numpy as np
 from ..core import autograd as _ag
 from ..core.dispatch import unwrap
 from ..observability.recorder import phase, phase_counters
+from ..ops.kernels.paged_latent_attention import (lane_whole,
+                                                  paged_latent_attention,
+                                                  pages_walked)
 from ..parallel.moe import ExpertShareLayer, PickTap
 from . import compile_plan as _cp
 from .kv_pool import (PagePool, PrefixCache, cache_spec_of, pages_needed,
@@ -347,14 +353,21 @@ def _account(kind: str, n: int) -> None:
         pass
 
 
+def _as_row_of(pool, new):
+    """``new [..., width]`` as rows of ``pool``: its dtype, and zeros in the
+    lanes a widened pool has beyond the row's own."""
+    return lane_whole(new.astype(pool.dtype), pool.shape[-1])
+
+
 class _PagedView:
     """What a layer gets as ``cache`` in the paged decode forward: the
     layer's pools (in the order of its cache spec) behind the page table.
     An attention calls :meth:`attend` (a K and a V pool: models/llama.py) or
     :meth:`attend_latent` (one pool of latent rows:
     models/longcat_flash.py) in place of writing through a dense cache:
-    the view is gathered here, as wide as ``rung`` says, and the new rows
-    come back for the engine to store in the pool."""
+    the view is gathered here, as wide as ``rung`` says (a latent row's
+    decode step reads its pages in place and gathers none), and the new
+    rows come back for the engine to store in the pool."""
 
     __slots__ = ("ladder", "page_size", "pools", "page_table", "rung")
 
@@ -374,16 +387,28 @@ class _PagedView:
         return (out, k_new.astype(kp.dtype), v_new.astype(vp.dtype))
 
     def attend_latent(self, block, q_abs, q_rope, c_new, r_new, pos, scale):
-        """(out, c rows, key rows): :func:`_attend_view_latent` of the
-        layer's ``block``-th pair of pools on the rung's branch, and the new
-        rows in the pools' dtype."""
+        """(out, c rows, key rows) over the layer's ``block``-th pair of
+        pools, and the new rows in the pools' dtype. A decode step (one query
+        row a head) IS the kernel that walks each slot's pages in place as
+        far as its own length (ops/kernels/paged_latent_attention.py; no
+        view, no rung). A W-wide call (the speculative verify's shape) keeps
+        :func:`_attend_view_latent` on the rung's branch: the kernel takes
+        one query row, and the static W of the call alone chooses."""
         c_pool, r_pool = self.pools[2 * block: 2 * block + 2]
-        out = jax.lax.switch(
-            self.rung,
-            _view_branches(_attend_view_latent, self.ladder, self.page_size,
-                           scale),
-            q_abs, q_rope, c_new, r_new, c_pool, r_pool, self.page_table, pos)
-        return out, c_new.astype(c_pool.dtype), r_new.astype(r_pool.dtype)
+        if q_abs.shape[1] == 1:
+            out = paged_latent_attention(
+                q_abs[:, 0], q_rope[:, 0], c_new[:, 0], r_new[:, 0], c_pool,
+                r_pool, self.page_table, pos, scale=scale)[:, None]
+        else:
+            out = jax.lax.switch(
+                self.rung,
+                _view_branches(_attend_view_latent, self.ladder,
+                               self.page_size, scale),
+                q_abs, q_rope, c_new, r_new, c_pool, r_pool, self.page_table,
+                pos)
+        # as wide as the pools are held (the decode program holds them in
+        # whole lane tiles: :meth:`BatchDecodeEngine._lane_whole_pools`)
+        return (out, _as_row_of(c_pool, c_new), _as_row_of(r_pool, r_new))
 
 
 class _Slot:
@@ -850,16 +875,23 @@ class BatchDecodeEngine:
 
         want = (flag_value("fused_kernels") if fused_kernels is None
                 else bool(fused_kernels))
+        from ..ops.kernels import paged_attention as _pa
+
         info: Dict[str, object] = {"enabled": False,
                                    "paged_attention": "off"}
+        if self._latent and self.kv_layout == "paged":
+            # not the flag's to switch: a latent row's decode step always
+            # walks its pages in the kernel of its own
+            info["paged_latent_attention"] = (
+                "interpret" if _pa.interpret_mode() else "compiled")
         if not want:
             return info
         self._refuse_latent(
             "fused_kernels",
-            "the paged-attention kernel walks a K and a V pool of "
-            "[kv heads, head size] rows; an absorbed-latent kernel is not "
-            "built")
-        from ..ops.kernels import paged_attention as _pa
+            "the flag's paged-attention kernel walks a K and a V pool of "
+            "[kv heads, head size] rows; a latent row's decode attention is "
+            "a kernel of its own already "
+            "(ops/kernels/paged_latent_attention.py), chosen by no flag")
 
         if self.kv_layout != "paged":
             ok, reason = False, "kv_layout contiguous (no page table)"
@@ -891,7 +923,9 @@ class BatchDecodeEngine:
     def fused_info(self) -> Dict[str, object]:
         """The ``fused`` block of ``health()``/``/healthz``: which fused
         kernels this engine decodes through (and why not, when it fell
-        back)."""
+        back); with a latent row, ``paged_latent_attention`` says how its
+        decode kernel runs (``compiled`` on the TPU, ``interpret``
+        elsewhere)."""
         return dict(self.fused)
 
     # -- compiled pieces ----------------------------------------------------
@@ -924,6 +958,30 @@ class BatchDecodeEngine:
         packed host-sync payload: how the host learns what was gathered."""
         pages = jnp.asarray(self._ladder, jnp.int32)[rung]
         return jnp.broadcast_to(pages, (self.S, 1))
+
+    def _lane_whole_pools(self, pools):
+        """A latent row's pools as its decode kernel copies pages from them:
+        every row in whole lane tiles (Mosaic copies no slice of a narrower
+        array: ops/kernels/paged_latent_attention.py). The decode program
+        widens them ONCE a call, where the 64-wide pool changes its device
+        layout anyway, carries them wide through its steps, and hands them
+        back at the spec's widths (:meth:`_spec_wide_pools`); widened inside
+        the step it would be a copy of every narrow pool in every block of
+        every step."""
+        return [tuple(lane_whole(p) for p in layer) for layer in pools]
+
+    def _spec_wide_pools(self, pools):
+        """:meth:`_lane_whole_pools` undone: every pool at its spec's width."""
+        return [tuple(p[..., :spec.row[-1]] for p, spec in zip(layer, specs))
+                for layer, specs in zip(pools, self.cache_spec)]
+
+    def _walk_pages_column(self, lens, active, span: int):
+        """:meth:`_view_pages_column` of a latent row's decode call, whose
+        kernel walks each slot's own pages and consults no rung: per slot
+        the pages its walk copies by the call's last step (those of its
+        table row that hold a key), 0 for an inactive slot."""
+        pages = pages_walked(lens + span, self.page_size, self.P)
+        return jnp.where(active, pages, 0).astype(jnp.int32)[:, None]
 
     def _pick_columns(self, counts):
         """The expert shares' counters of a decode call (``pick_counts`` and
@@ -1272,14 +1330,17 @@ class BatchDecodeEngine:
         int32 host-sync payload: [slots, n_steps+1] (emitted tokens, -1
         where idle, then the active flag), and [slots, n_steps+2] in the
         paged layout, whose last column is the pages of the K/V view that
-        every step of the call gathered. A factory so the perf plane can
-        lower an ``n_steps=1`` variant for cost capture — XLA's cost
-        analysis counts a scan body ONCE regardless of trip count, so the
-        chunk program's own count would under-report by ~chunk.
+        every step of the call gathered (with a latent row, the pages each
+        slot's walk copies: :meth:`_walk_pages_column`). A factory so the
+        perf plane can lower an ``n_steps=1`` variant for cost capture —
+        XLA's cost analysis counts a scan body ONCE regardless of trip
+        count, so the chunk program's own count would under-report by
+        ~chunk.
         Paged layout threads the pool through the scan carry and reads the
         (loop-invariant) page table as a plain capture-free argument."""
 
         paged = self.kv_layout == "paged"
+        latent = paged and self._latent
 
         def step(caches, tokens, lens, active, temps, budgets, top_ks,
                  eos_ids, key, params, page_table, rung):
@@ -1314,6 +1375,8 @@ class BatchDecodeEngine:
             # one rung for the whole call, from where the longest live
             # context will stand after its last step
             rung = self._view_rung(lens, active, n_steps) if paged else None
+            if latent:
+                caches = self._lane_whole_pools(caches)
 
             def body(carry, _):
                 caches, tokens, lens, active, budgets, key = carry
@@ -1326,9 +1389,12 @@ class BatchDecodeEngine:
                 = jax.lax.scan(
                     body, (caches, tokens, lens, active, budgets, key), None,
                     length=n_steps)
+            if latent:
+                caches_ = self._spec_wide_pools(caches_)
             cols = [out.T, active_[:, None].astype(jnp.int32)]
             if paged:
-                cols.append(self._view_pages_column(rung))
+                cols.append(self._walk_pages_column(lens, active, n_steps)
+                            if latent else self._view_pages_column(rung))
             if picks is not None:
                 cols.append(self._pick_columns(picks.sum(0)))
             packed = jnp.concatenate(cols, axis=1)  # [slots, n_steps+1(+1)]
@@ -1980,10 +2046,13 @@ class BatchDecodeEngine:
             self.stats["turnaround_s"] += time.perf_counter() - t
             self.stats["turnaround_n"] += 1
 
-    def _count_view(self, pages: int) -> None:
-        """Once per decode call: the pages of the K/V view its steps
-        gathered, beside the whole table's."""
-        self.stats["decode_view_pages"] += pages
+    def _count_view(self, column) -> None:
+        """Once per decode call: the pages of the table its steps read,
+        beside the whole table's. ``column`` is the program's report, one
+        number a slot: the rung's page count in every row where a view was
+        gathered, each slot's own walk where the latent kernel ran; their
+        mean over the slots, rounded up, is what is counted."""
+        self.stats["decode_view_pages"] += -(-int(column.sum()) // self.S)
         self.stats["decode_table_pages"] += self.P
 
     def _release_kv(self, slot: int, zero_row: bool = True) -> None:
@@ -2157,7 +2226,7 @@ class BatchDecodeEngine:
         acc = blocks[:, :, k + 1]            # raw accepted-run lengths
         act = blocks[:, -1, k + 2].astype(bool)
         # the widest view among the call's verify steps
-        self._count_view(int(blocks[0, :, k + 3].max()))
+        self._count_view(blocks[:, :, k + 3].max(axis=1))
         chunk_emitted = 0
         for slot, s in enumerate(self._host_slots):
             if s.req is None:
@@ -2238,7 +2307,7 @@ class BatchDecodeEngine:
                 p.observe("serving.decode", t_sync - t0, bucket=cost_bucket)
             em, act = pk[:, :self.chunk], pk[:, self.chunk].astype(bool)
             if self.kv_layout == "paged":
-                self._count_view(int(pk[0, self.chunk + 1]))
+                self._count_view(pk[:, self.chunk + 1])
                 if self._experts_held:
                     self._count_picks(pk[:, self.chunk + 2:])
             for slot, s in enumerate(self._host_slots):

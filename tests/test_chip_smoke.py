@@ -43,7 +43,8 @@ def test_smoke_legs_tiny_on_cpu():
     assert set(table) == {
         "flash", "flash_varlen", "gather_gemm",
         "paged_attention_W1_bf16", "paged_attention_W1_int8",
-        "paged_attention_W4_bf16", "paged_attention_W4_int8"}
+        "paged_attention_W4_bf16", "paged_attention_W4_int8",
+        "paged_latent_attention"}
     assert all(r["mode"] == "interpret" for r in table.values())
     # conftest's 8 virtual CPU devices stand in for the four chips: the
     # sharding evidence is real, the allocator evidence is chip-only

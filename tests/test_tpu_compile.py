@@ -1,0 +1,50 @@
+"""Kernels of the serving path compiled for a TPU v5e WITHOUT one, at the
+benchmark's own shapes: what Mosaic refuses (a slice that is not whole tiles,
+too much fast memory) shows here and not under the Pallas interpreter. The
+topology is described inside a fixture, never at import (one process at a time
+may load the TPU's library; every xdist worker imports this file), and every
+such compile lives in this one file. Nothing runs: no number here is a time.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:                      # no TPU compiler here, or another process holds its library
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# serve-agent-saturated's decode attention: 128 slots, 64 heads, a 512 + 64 latent row, 4,096 pages of 64 tokens
+S, H, RANK, ROPE, PS, P, PAGES = 128, 64, 512, 64, 64, 64, 4096
+
+
+def _compiled(one_chip, rope_pool_width):
+    from paddlepaddle_tpu.ops.kernels.paged_latent_attention import paged_latent_attention
+
+    bf = jnp.bfloat16
+    shape = lambda s, dt=bf: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (shape((S, H, RANK)), shape((S, H, ROPE)), shape((S, RANK)), shape((S, ROPE)),
+            shape((PAGES, PS, RANK)), shape((PAGES, PS, rope_pool_width)), shape((S, P), jnp.int32),
+            shape((S,), jnp.int32))
+    fn = lambda *a: paged_latent_attention(*a, scale=192 ** -0.5, interpret=False)
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("rope_pool_width, pool_pads", [
+    (ROPE, 1),            # the pool as the cache spec holds it: widened to whole lanes before the kernel copies from it
+    (2 * ROPE, 0),        # as the decode program carries it through its steps: nothing left to widen
+])
+def test_the_latent_decode_kernel_compiles_for_v5e_at_the_agent_cells_shape(one_chip, rope_pool_width, pool_pads):
+    text = _compiled(one_chip, rope_pool_width)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    wide_pool = f"bf16[{PAGES},{PS},128]"
+    pads = [l for l in text.splitlines() if " pad(" in l and l.split("=")[1].lstrip().startswith(wide_pool)]
+    assert len(pads) == pool_pads
