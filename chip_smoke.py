@@ -9,10 +9,10 @@ weights as there: 12 layers, random weights from seed 0):
   and falling; the step's program holds the Pallas flash forward and both
   backward kernels.
 * **serve** — ``inference.serving.ServingEngine`` with its defaults, then
-  with ``fused_kernels=True``, then with ``kv_quant="int8"`` on top: eight
-  greedy requests (two share a prefix), every future resolves, no decode
-  failure, a prefix hit, and every token passes the margin rule below
-  against a plain full-sequence forward of the same weights.
+  with ``kv_quant="int8"``: eight greedy requests (two share a prefix),
+  every future resolves, no decode failure, a prefix hit, and every token
+  passes the margin rule below against a plain full-sequence forward of
+  the same weights.
 * **kernels** — every Pallas kernel in ``ops/kernels/`` compiled by Mosaic at
   these models' shapes and compared with its ``jax.numpy`` reference.
 * **four chips** — when ``jax.device_count() >= 4``: ``ShardedTrainStep``
@@ -79,7 +79,6 @@ class Size:
     new_tokens: int
     flash: Tuple[int, int, int, int]  # batch, seq, heads, head_dim
     varlen: Tuple[int, int, int]      # packed tokens, heads, head_dim
-    paged: Dict[str, int]             # slots, page, kvh, hd, rep, pages_per_slot
     moe: Dict[str, int]               # experts, d, h, tokens, topk, capacity
     latent: Dict[str, int]            # slots, page, heads, rank, rope, pages_per_slot
 
@@ -95,7 +94,6 @@ FULL = Size(
     new_tokens=32,
     flash=(8, 1024, 16, 64),
     varlen=(8192, 16, 64),
-    paged=dict(slots=16, page=64, kvh=8, hd=64, rep=2, pages_per_slot=32),
     # bench.py's MoE config: 8192 tokens top-2 over 16 experts, capacity
     # factor 1.25 -> 8192 * 2 * 1.25 / 16 = 1280 slots per expert
     moe=dict(experts=16, d=1024, h=768, tokens=8192, topk=2, capacity=1280),
@@ -115,7 +113,6 @@ TINY = Size(
     prompt_lens=(5, 40, 70, 80), prefix_len=64, new_tokens=6,
     flash=(1, 32, 2, 64),
     varlen=(32, 2, 64),
-    paged=dict(slots=2, page=8, kvh=2, hd=16, rep=2, pages_per_slot=3),
     moe=dict(experts=4, d=16, h=24, tokens=48, topk=2, capacity=8),
     latent=dict(slots=3, page=8, heads=4, rank=16, rope=8, pages_per_slot=4),
 )
@@ -303,8 +300,8 @@ class _Reference:
                 "margin": margin}
 
 
-def _serve_once(what: str, model, size: Size, interpret: bool,
-                ref: _Reference, margin: float, **engine_kw):
+def _serve_once(what: str, model, size: Size, ref: _Reference,
+                margin: float, **engine_kw):
     """Serve the eight requests through one engine and judge what came
     back; returns (report, outputs)."""
     import numpy as np
@@ -333,32 +330,22 @@ def _serve_once(what: str, model, size: Size, interpret: bool,
     hits = health["kv"]["prefix"]["hits"]
     if hits < 1:
         raise AssertionError(f"{what}: no prefix-cache hit: {health['kv']}")
-    fused = health["fused"]["paged_attention"]
-    if engine_kw.get("fused_kernels"):
-        expect = "interpret" if interpret else "compiled"
-        if fused != expect:
-            raise AssertionError(
-                f"{what}: engine.fused['paged_attention'] is {fused!r}, "
-                f"not {expect!r}")
-    out = {"status": "ok", "paged_attention": fused, "prefix_hits": hits,
+    out = {"status": "ok", "prefix_hits": hits,
            "kv_quant": health["kv"]["kv_quant"],
            "wall_s_with_compile": round(wall, 2)}
     out.update(ref.check(what, prompts, outs, margin))
     return out, outs
 
 
-def serve_leg(size: Size, interpret: bool, model):
-    """The three engines in turn; returns (report, the reference)."""
+def serve_leg(size: Size, model):
+    """The two engines in turn; returns (report, the reference)."""
     ref = _Reference(model, size)
     legs = {}
     legs["default"], _ = _serve_once(
-        "serve", model, size, interpret, ref, MARGIN_BF16)
-    legs["fused"], _ = _serve_once(
-        "serve fused", model, size, interpret, ref, MARGIN_BF16,
-        fused_kernels=True)
-    legs["fused_int8_kv"], _ = _serve_once(
-        "serve fused int8", model, size, interpret, ref, MARGIN_INT8_KV,
-        fused_kernels=True, kv_quant="int8")
+        "serve", model, size, ref, MARGIN_BF16)
+    legs["int8_kv"], _ = _serve_once(
+        "serve int8 kv", model, size, ref, MARGIN_INT8_KV,
+        kv_quant="int8")
     return {"status": "ok", **legs}, ref
 
 
@@ -500,61 +487,6 @@ def _scattered_table(rng, extents, P: int, ps: int):
     return table
 
 
-def _kernel_paged(size: Size, interpret: bool, W: int, int8: bool) -> Dict[str, object]:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from paddlepaddle_tpu.inference import decode_engine as de
-    from paddlepaddle_tpu.ops.kernels.paged_attention import paged_attention
-
-    p = size.paged
-    S, ps, kvh, hd, rep, P = (p["slots"], p["page"], p["kvh"], p["hd"],
-                              p["rep"], p["pages_per_slot"])
-    n_pages = S * P + 1                      # page 0 is the null page
-    rng = np.random.default_rng(4)
-    lens = rng.integers(1, P * ps - W, (S,)).astype(np.int32)
-    lens[0], lens[-1] = 1, P * ps - W        # both ends of the walk
-    table = _scattered_table(rng, lens + W, P, ps)
-    ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    q = jax.random.normal(ks[0], (S, W, kvh * rep, hd), jnp.bfloat16)
-    kpool = jax.random.normal(ks[1], (n_pages, ps, kvh, hd), jnp.float32)
-    vpool = jax.random.normal(ks[2], (n_pages, ps, kvh, hd), jnp.float32)
-    scale = hd ** -0.5
-    table, lens = jnp.asarray(table), jnp.asarray(lens)
-
-    def view(pool):                          # the gather the kernel removes
-        return pool[table].reshape(S, P * ps, kvh, hd)
-
-    def reference(q, kview, vview):
-        return de._ref_gqa_attention(q, kview, vview, lens, rep=rep,
-                                     scale=scale)
-
-    if int8:
-        (kq, ksc), (vq, vsc) = de._kv_quant_pages(kpool), \
-            de._kv_quant_pages(vpool)
-        got = _mosaic("paged_attention int8", lambda q, kq, vq, ksc, vsc:
-                      paged_attention(q, kq, vq, table, lens, rep=rep,
-                                      scale=scale, k_scale=ksc, v_scale=vsc,
-                                      interpret=interpret),
-                      interpret)(q, kq, vq, ksc, vsc)
-        kview = de._kv_dequant_gather(kq, ksc, table, jnp.float32).reshape(
-            S, P * ps, kvh, hd)
-        vview = de._kv_dequant_gather(vq, vsc, table, jnp.float32).reshape(
-            S, P * ps, kvh, hd)
-    else:
-        kb, vb = kpool.astype(jnp.bfloat16), vpool.astype(jnp.bfloat16)
-        got = _mosaic("paged_attention", lambda q, kb, vb:
-                      paged_attention(q, kb, vb, table, lens, rep=rep,
-                                      scale=scale, interpret=interpret),
-                      interpret)(q, kb, vb)
-        kview, vview = view(kb.astype(jnp.float32)), \
-            view(vb.astype(jnp.float32))
-    want = jax.jit(reference)(q.astype(jnp.float32), kview, vview)
-    return _compare(f"paged_attention W={W} int8={int8}", got, want,
-                    interpret)
-
-
 def _kernel_paged_latent(size: Size, interpret: bool) -> Dict[str, object]:
     """The absorbed-latent decode kernel against the gathered view it takes
     the place of (``decode_engine._attend_view_latent`` over the whole
@@ -637,10 +569,6 @@ def _kernel_gather_gemm(size: Size, interpret: bool) -> Dict[str, object]:
 def kernels_leg(size: Size, interpret: bool) -> Dict[str, object]:
     table = {"flash": _kernel_flash(size, interpret),
              "flash_varlen": _kernel_varlen(size, interpret)}
-    for W in (1, 4):
-        for int8 in (False, True):
-            name = f"paged_attention_W{W}_{'int8' if int8 else 'bf16'}"
-            table[name] = _kernel_paged(size, interpret, W, int8)
     table["paged_latent_attention"] = _kernel_paged_latent(size, interpret)
     table["gather_gemm"] = _kernel_gather_gemm(size, interpret)
     return {"status": "ok", "tolerance": f"{KERNEL_TOL} * max|reference|",
@@ -795,7 +723,7 @@ def run_legs(size: Size, interpret: bool) -> Dict[str, object]:
 
     model = _build_model(size)
     legs["train"] = timed("train", train_leg, size, interpret, model)
-    legs["serve"], ref = timed("serve", serve_leg, size, interpret, model)
+    legs["serve"], ref = timed("serve", serve_leg, size, model)
     legs["kernels"] = timed("kernels", kernels_leg, size, interpret)
     gc.collect()
     legs["four_chips"] = timed("four_chips", four_chip_leg, size, interpret,

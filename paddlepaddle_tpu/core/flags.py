@@ -89,39 +89,16 @@ define_flag("flash_attn_block_kv", 512, "pallas flash-attn kv block")
 define_flag("eager_delete_tensor_gb", 0.0, "compat no-op (XLA owns memory)")
 define_flag("allocator_strategy", "xla", "compat: allocation handled by XLA runtime")
 
-# Fused-kernel family (ops/kernels/gather_gemm.py + paged_attention.py):
-# Pallas kernels for the two measured data-movement floors — MoE dispatch
-# (fused gather-GEMM, megablox-style) and paged-attention decode (in-kernel
-# page-table walk). Off by default: the reference formulations stay the
-# serving/train default until the fused rows are recorded on-chip
-# (BASELINE.md "Fused kernels"). On CPU (the tier-1 environment) armed
-# kernels execute in Pallas interpret mode — same program, emulated grid —
-# so parity is testable without an accelerator. Any unsupported config
-# (layout, page geometry, layer shape, mesh) falls back to the reference
-# formulation LOUDLY (one stderr line + a fallback counter), never
-# silently and never with wrong results; the resolved per-kernel mode
-# joins the CompilePlan fingerprint so AOT bundles built under a
-# different kernel config are rejected at load instead of serving a
-# different program.
-define_flag("fused_kernels", False,
-            "arm the fused Pallas kernels by DEFAULT (gather-GEMM MoE "
-            "dispatch + paged-attention decode; interpret-mode on CPU). "
-            "Explicit opt-ins — BatchDecodeEngine(fused_kernels=True), "
-            "MoELayer(dispatch_mode='fused') — win over this flag in "
-            "both directions, exactly like every other constructor "
-            "argument in the serving family",
-            env="PADDLE_FUSED_KERNELS")
+# The fused gather-GEMM MoE dispatch (ops/kernels/gather_gemm.py), what
+# MoELayer(dispatch_mode="fused") runs: a Pallas kernel, interpreted off the
+# chip. Where it is unsupported (or this switch is off) the layer falls back
+# to the 'sorted' formulation LOUDLY: one stderr line and a counter, never
+# with other results.
 define_flag("fused_gather_gemm", True,
             "per-kernel KILL SWITCH for the fused gather-GEMM MoE "
             "dispatch: 0 forces the reference 'sorted' formulation even "
             "for explicit dispatch_mode='fused' opt-ins (the incident "
             "lever)", env="PADDLE_FUSED_GATHER_GEMM")
-define_flag("fused_paged_attention", True,
-            "per-kernel KILL SWITCH for the in-kernel page-table-walk "
-            "decode attention: 0 forces the reference pool[page_table] "
-            "formulation even for explicit fused_kernels=True engines "
-            "(the incident lever)",
-            env="PADDLE_FUSED_PAGED_ATTENTION")
 
 # Observability family (observability/): each flag also reads its PADDLE_OBS_*
 # env spelling; all default off so the hot paths carry no instrumentation.

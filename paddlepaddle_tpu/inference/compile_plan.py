@@ -194,17 +194,6 @@ class CompilePlan:
             # serving another draft's executables
             "spec": (engine.spec.facts()
                      if getattr(engine, "spec", None) is not None else None),
-            # fused-kernel resolution, NORMALIZED to the program identity
-            # actually compiled: "fused" (kernel in the decode/verify
-            # programs) vs "reference" (off OR fell back — byte-identical
-            # programs, so a fallback engine still loads a reference
-            # bundle). A kernel-config change compiles DIFFERENT programs
-            # and must reject foreign bundles loudly; the human-readable
-            # fallback reason stays in health()["fused"], not the hash
-            "fused": {
-                "paged_attention": (
-                    "fused" if getattr(engine, "fused", {}).get("enabled")
-                    else "reference")},
             "jax": jax.__version__,
             "jaxlib": jaxlib.__version__,
             "platform": jax.default_backend(),

@@ -182,8 +182,9 @@ def _ref_gqa_attention(q, kview, vview, lens, *, rep, scale):
     """Reference gather-dequant attention over a materialized logical
     view [S, T, kvh, hd]: the same bottom-right causal rule, GQA
     grouping (q head g*rep+r reads kv head g) and f32 accumulation as
-    the Pallas kernel — the non-kernel half of the int8-KV parity
-    pair (docs/kernels.md fallback matrix)."""
+    :func:`~paddlepaddle_tpu.models.llama._cached_attention`, in float32
+    throughout: what an int8 pool's attention is, and what a kernel that
+    reads int8 pages in place would be held to."""
     S, W, h, hd = q.shape
     kvh = kview.shape[2]
     T = kview.shape[1]
@@ -202,7 +203,7 @@ def _ref_gqa_attention(q, kview, vview, lens, *, rep, scale):
 
 def _attend_view_int8(n, ps, dtype, rep, scale, q, kq, ksc, vq, vsc,
                       page_table, lens):
-    """The int8-KV reference over the same bounded table: gather-dequant
+    """An int8 pair's attention over the same bounded table: gather-dequant
     ``n`` pages a slot, then :func:`_ref_gqa_attention` (the new rows are
     in the pool already)."""
     S, table = q.shape[0], page_table[:, :n]
@@ -217,9 +218,8 @@ def _attend_view_int8(n, ps, dtype, rep, scale, q, kq, ksc, vq, vsc,
 # KVQuant/KIVI-style symmetric absmax: each K/V page carries one f32 scale
 # per kv head ([num_pages, kvh] riding the pool as a parallel buffer), codes
 # are int8 in [-127, 127]. Dequant is exactly ``codes * scale`` in f32 —
-# the same product whether it runs in the fused kernel's VMEM pass or the
-# reference gather, which is what makes kernel-vs-reference token-exact at
-# identical pool bytes.
+# the one product a later kernel's read has to make as well, to stay
+# token-exact with the gathered view at identical pool bytes.
 
 def _kv_quant_pages(x):
     """Quantize whole pages ``x [npg, ps, kvh, hd]`` (f32) at admission:
@@ -361,24 +361,47 @@ def _as_row_of(pool, new):
 
 class _PagedView:
     """What a layer gets as ``cache`` in the paged decode forward: the
-    layer's pools (in the order of its cache spec) behind the page table.
-    An attention calls :meth:`attend` (a K and a V pool: models/llama.py) or
+    layer's pools (in the order of its cache spec) behind the page table,
+    and where the call's new rows go (``phys``, ``off``). An attention
+    calls :meth:`attend` (a K and a V pool: models/llama.py) or
     :meth:`attend_latent` (one pool of latent rows:
     models/longcat_flash.py) in place of writing through a dense cache:
     the view is gathered here, as wide as ``rung`` says (a latent row's
-    decode step reads its pages in place and gathers none), and the new
-    rows come back for the engine to store in the pool."""
+    decode step reads its pages in place and gathers none), and what comes
+    back beside the output is for :meth:`stored`: the new rows, or, where
+    the pool is an int8 ``(codes, scales)`` pair, the pair with the rows
+    quantised into their pages. How a pool is stored and read is decided
+    here, from the pools themselves and the static width of the call."""
 
-    __slots__ = ("ladder", "page_size", "pools", "page_table", "rung")
+    __slots__ = ("ladder", "page_size", "kv_dtype", "pools", "page_table",
+                 "rung", "phys", "off")
 
-    def __init__(self, eng, pools, page_table, rung):
+    def __init__(self, eng, pools, page_table, rung, phys, off):
         self.ladder, self.page_size = eng._ladder, eng.page_size
+        self.kv_dtype = eng._kv_dtype
         self.pools, self.page_table, self.rung = pools, page_table, rung
+        self.phys, self.off = phys, off
 
     def attend(self, q, k_new, v_new, pos, n_rep, scale):
         """(out, K rows, V rows): :func:`_attend_view` on the rung's
-        branch, and the new rows in the pool's dtype."""
+        branch, and the new rows in the pool's dtype. Over an int8 pair the
+        rows are quantised into their pages FIRST, outside the switch (the
+        donated pool is never carried through a branch), so that the
+        attention reads the bytes the next step will read
+        (:func:`_attend_view_int8`), and the updated pairs come back in
+        place of rows."""
         kp, vp = self.pools
+        if isinstance(kp, tuple):
+            kq, ksc = _kv_quant_scatter(*kp, k_new.astype(self.kv_dtype),
+                                        self.phys, self.off)
+            vq, vsc = _kv_quant_scatter(*vp, v_new.astype(self.kv_dtype),
+                                        self.phys, self.off)
+            out = jax.lax.switch(
+                self.rung,
+                _view_branches(_attend_view_int8, self.ladder,
+                               self.page_size, self.kv_dtype, n_rep, scale),
+                q, kq, ksc, vq, vsc, self.page_table, pos)
+            return (out, (kq, ksc), (vq, vsc))
         out = jax.lax.switch(
             self.rung,
             _view_branches(_attend_view, self.ladder, self.page_size, n_rep,
@@ -410,6 +433,15 @@ class _PagedView:
         # whole lane tiles: :meth:`BatchDecodeEngine._lane_whole_pools`)
         return (out, _as_row_of(c_pool, c_new), _as_row_of(r_pool, r_new))
 
+    def stored(self, kept):
+        """The layer's pools with what its attention handed back in place:
+        a row is written to its page at ``phys, off``; an int8 pair holds
+        its rows already."""
+        return tuple(
+            tuple(unwrap(a) for a in k) if isinstance(p, tuple)
+            else p.at[self.phys, self.off].set(unwrap(k))
+            for p, k in zip(self.pools, kept))
+
 
 class _Slot:
     __slots__ = ("req", "emitted", "budget", "spec_steps", "spec_accepted")
@@ -434,7 +466,6 @@ class BatchDecodeEngine:
                  prefix_cache: bool = True, mesh=None, plan=None,
                  bundle: Optional[str] = None, draft=None, spec_k: int = 0,
                  draft_quant: Optional[str] = None,
-                 fused_kernels: Optional[bool] = None,
                  kv_quant: Optional[str] = None,
                  kv_host_bytes: Optional[int] = None):
         cfg = model.config
@@ -542,11 +573,6 @@ class BatchDecodeEngine:
                     "(codes, scale) pair per layer is a named follow-up "
                     "seam (shard_kv places plain pools only) — serve "
                     "int8 KV single-chip or drop the plan")
-            if not self._llama_shaped_layers():
-                raise ValueError(
-                    "kv_quant='int8' drives the llama decoder submodules "
-                    "directly (quantize-at-scatter needs the raw K/V "
-                    "projections); this model is not llama-decoder-shaped")
         self.kv_quant = kv_quant
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self._kv_dtype = dtype     # compute dtype for scratch/dequant even
@@ -694,13 +720,6 @@ class BatchDecodeEngine:
                                            draft_quant=draft_quant)
             self._spec_steps_per_chunk = max(
                 1, self.chunk // (self.spec.k + 1))
-        # fused Pallas kernels (ops/kernels/paged_attention.py): resolved
-        # ONCE here — the decision (off / interpret / compiled /
-        # fallback+reason) is immutable engine state that joins the
-        # CompilePlan fingerprint, so a bundle built under a different
-        # kernel config is rejected loudly at load instead of silently
-        # serving a different program
-        self.fused = self._resolve_fused(fused_kernels)
         self.compile_plan = _cp.CompilePlan.for_engine(self)
         try:
             # weak registration: the memory ledger attributes this
@@ -843,91 +862,6 @@ class BatchDecodeEngine:
         decoding is off."""
         return {"enabled": False} if self.spec is None else self.spec.info()
 
-    # -- fused kernels -------------------------------------------------------
-    def _llama_shaped_layers(self) -> bool:
-        """The fused decode path drives the layer's submodules directly
-        (projections, norms, mlp); anything not llama-decoder-shaped —
-        or carrying extra residual branches (shared_mlp) the fused loop
-        would silently skip — must fall back to the reference path."""
-        try:
-            layer = self.model.model.layers[0]
-            mdl = self.model.model
-        except Exception:
-            return False
-        attn = getattr(layer, "self_attn", None)
-        return (all(hasattr(attn, a)
-                    for a in ("q_proj", "k_proj", "v_proj", "o_proj"))
-                and all(hasattr(layer, a)
-                        for a in ("input_layernorm",
-                                  "post_attention_layernorm", "mlp"))
-                and getattr(layer, "shared_mlp", None) is None
-                and all(hasattr(mdl, a)
-                        for a in ("embed_tokens", "norm", "rope_cos",
-                                  "rope_sin")))
-
-    def _resolve_fused(self, fused_kernels: Optional[bool]) -> Dict[str, object]:
-        """Resolve the fused-kernel config for this engine: explicit
-        argument wins, else ``FLAGS_fused_kernels``. Requested-but-
-        unsupported is a LOUD non-fatal fallback (one stderr line + a
-        labeled counter) to the reference formulation — never a silent
-        behavior change and never wrong results."""
-        from ..core.flags import flag_value
-
-        want = (flag_value("fused_kernels") if fused_kernels is None
-                else bool(fused_kernels))
-        from ..ops.kernels import paged_attention as _pa
-
-        info: Dict[str, object] = {"enabled": False,
-                                   "paged_attention": "off"}
-        if self._latent and self.kv_layout == "paged":
-            # not the flag's to switch: a latent row's decode step always
-            # walks its pages in the kernel of its own
-            info["paged_latent_attention"] = (
-                "interpret" if _pa.interpret_mode() else "compiled")
-        if not want:
-            return info
-        self._refuse_latent(
-            "fused_kernels",
-            "the flag's paged-attention kernel walks a K and a V pool of "
-            "[kv heads, head size] rows; a latent row's decode attention is "
-            "a kernel of its own already "
-            "(ops/kernels/paged_latent_attention.py), chosen by no flag")
-
-        if self.kv_layout != "paged":
-            ok, reason = False, "kv_layout contiguous (no page table)"
-        else:
-            ok, reason = _pa.paged_attention_supported(
-                page_size=self.page_size, head_dim=self.cfg.head_dim,
-                num_heads=self.cfg.num_attention_heads,
-                num_kv_heads=self.cfg.num_key_value_heads, plan=self.plan,
-                kv_quant=self.kv_quant)
-            if ok and not self._llama_shaped_layers():
-                ok, reason = False, "model layers not llama-decoder-shaped"
-        if ok:
-            mode = "interpret" if _pa.interpret_mode() else "compiled"
-            info.update(enabled=True, paged_attention=mode)
-            return info
-        info["paged_attention"] = f"fallback: {reason}"
-        sys.stderr.write(
-            f"[serving] fused paged-attention kernel unavailable "
-            f"({reason}); serving the reference pool[page_table] "
-            "formulation\n")
-        _safe_inc("paddle_fused_kernel_fallbacks_total",
-                  "fused-kernel requests that fell back to the reference "
-                  "formulation", kernel="paged_attention",
-                  reason=reason.split(" ")[0])
-        _flight_record("compile", "fused_fallback",
-                       kernel="paged_attention", reason=reason)
-        return info
-
-    def fused_info(self) -> Dict[str, object]:
-        """The ``fused`` block of ``health()``/``/healthz``: which fused
-        kernels this engine decodes through (and why not, when it fell
-        back); with a latent row, ``paged_latent_attention`` says how its
-        decode kernel runs (``compiled`` on the TPU, ``interpret``
-        elsewhere)."""
-        return dict(self.fused)
-
     # -- compiled pieces ----------------------------------------------------
     def _forward(self, params, toks, caches, pos):
         """One model step: toks [b, s] -> (logits, caches')."""
@@ -944,11 +878,8 @@ class BatchDecodeEngine:
         """Which rung of ``self._ladder`` (an int32 index) is the narrowest
         view that holds every position an ACTIVE slot touches while its
         ``lens`` advances by ``span``. A retired slot's ``lens`` is stale
-        and does not count. The fused kernel walks the whole table itself:
-        there the rung is the top one."""
+        and does not count."""
         top = len(self._ladder) - 1
-        if self.fused.get("enabled"):
-            return jnp.int32(top)
         extent = jnp.max(jnp.where(active, lens, 0)) + span
         holds = jnp.asarray(self._ladder, jnp.int32) * self.page_size
         return jnp.minimum(jnp.sum(holds < extent), top).astype(jnp.int32)
@@ -1014,8 +945,10 @@ class BatchDecodeEngine:
         ``lens..lens+W-1`` through the page table: each layer is handed
         its pools behind the table (a :class:`_PagedView`, whatever rows
         the cache spec gives them) and hands back the new rows, one per
-        pool; ``tap`` (a ``parallel.moe.PickTap``) collects the picks of
-        the expert shares the layers run. Each attention gathers its
+        pool (an int8 pair comes back whole, the rows quantised into it:
+        :meth:`_PagedView.stored` keeps either); ``tap`` (a
+        ``parallel.moe.PickTap``) collects the picks of the expert shares
+        the layers run. Each attention gathers its
         logical K/V view (the page table IS the gather index), runs the
         unchanged ragged-attention math against it, and scatters all W
         newly written positions back to their physical pages. The view is
@@ -1043,16 +976,6 @@ class BatchDecodeEngine:
             pos < L,
             page_table[jnp.broadcast_to(rows, pos.shape), page_idx], 0)
         off = pos % ps
-        if self.fused.get("enabled") or self.kv_quant == "int8":
-            # int8 KV always takes the direct-submodule path even without
-            # the kernel: quantize-at-scatter must happen BEFORE attention
-            # reads the pool, so kernel and reference attend the SAME
-            # quantized bytes (that identity is what makes the parity
-            # test token-exact) — the generic layer call below would
-            # attend this step's full-precision rows instead
-            return self._forward_paged_fused(params, toks, pools,
-                                             page_table, lens, phys, off,
-                                             rung)
         with _ag.no_grad(), self.model.bind_state(params):
             mdl = self.model.model
             x = mdl.embed_tokens(toks)
@@ -1060,98 +983,13 @@ class BatchDecodeEngine:
             new_pools = []
             with tap if tap is not None else contextlib.nullcontext():
                 for layer, layer_pools in zip(mdl.layers, pools):
-                    x, rows = layer(
-                        x, cos, sin, None, pos=lens,
-                        cache=_PagedView(self, layer_pools, page_table,
-                                         rung))
+                    view = _PagedView(self, layer_pools, page_table, rung,
+                                      phys, off)
+                    x, kept = layer(x, cos, sin, None, pos=lens, cache=view)
                     # the write to the physical pool stays outside the
                     # switch: the donated pool is updated in place, never
                     # carried through a branch
-                    new_pools.append(tuple(
-                        p.at[phys, off].set(unwrap(r))
-                        for p, r in zip(layer_pools, rows)))
-            hidden = mdl.norm(x)
-            if self.model.lm_head is None:
-                logits = unwrap(hidden) @ unwrap(mdl.embed_tokens.weight).T
-            else:
-                logits = unwrap(self.model.lm_head(hidden))
-        return logits, new_pools
-
-    def _forward_paged_fused(self, params, toks, pools, page_table, lens,
-                             phys, off, rung):
-        """The fused-kernel form of :meth:`_forward_paged`: identical
-        math (same projections, rope offsets, write positions and causal
-        rule — parity is test-pinned token-exact), but each layer
-        scatters the W new K/V rows straight to their physical pages and
-        the attention WALKS THE PAGE TABLE IN-KERNEL
-        (ops/kernels/paged_attention.py) instead of materializing
-        ``pool[page_table]`` in HBM. The layer loop drives the llama
-        submodules directly — `_resolve_fused` verified the shape.
-
-        Under ``kv_quant="int8"`` this is ALSO the reference path (kernel
-        off → gather-dequant + :func:`_ref_gqa_attention`): both forms
-        quantize-scatter first and attend the identical int8 bytes, which
-        is the parity contract."""
-        import math as _math
-
-        from ..models.llama import _apply_rope
-        from ..ops.kernels.paged_attention import paged_attention
-
-        S = self.S
-        W = toks.shape[1]
-        cfg = self.cfg
-        nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                       cfg.head_dim)
-        rep = nh // kvh
-        scale = 1.0 / _math.sqrt(hd)
-        quant = self.kv_quant == "int8"
-        use_kernel = bool(self.fused.get("enabled"))
-        interp = self.fused.get("paged_attention") == "interpret"
-        ps = self.page_size
-        with _ag.no_grad(), self.model.bind_state(params):
-            mdl = self.model.model
-            x = mdl.embed_tokens(toks)
-            cos, sin = mdl.rope_cos, mdl.rope_sin
-            new_pools = []
-            for layer, (kp, vp) in zip(mdl.layers, pools):
-                attn = layer.self_attn
-                h_pre = layer.input_layernorm(x)
-                q = attn.q_proj(h_pre).reshape([S, W, nh, hd])
-                k = attn.k_proj(h_pre).reshape([S, W, kvh, hd])
-                v = attn.v_proj(h_pre).reshape([S, W, kvh, hd])
-                q, k = _apply_rope(q, k, cos, sin, offset=lens)
-                # write first, then attend: the causal mask admits this
-                # step's own positions, exactly like the reference
-                # view-write in _cached_attention
-                if quant:
-                    (kq, ksc), (vq, vsc) = kp, vp
-                    kq, ksc = _kv_quant_scatter(
-                        kq, ksc, unwrap(k).astype(self._kv_dtype),
-                        phys, off)
-                    vq, vsc = _kv_quant_scatter(
-                        vq, vsc, unwrap(v).astype(self._kv_dtype),
-                        phys, off)
-                    if use_kernel:
-                        out = paged_attention(
-                            unwrap(q), kq, vq, page_table, lens, rep=rep,
-                            scale=scale, k_scale=ksc, v_scale=vsc,
-                            interpret=interp)
-                    else:
-                        out = jax.lax.switch(
-                            rung,
-                            _view_branches(_attend_view_int8, self._ladder,
-                                           ps, self._kv_dtype, rep, scale),
-                            unwrap(q), kq, ksc, vq, vsc, page_table, lens)
-                    new_pools.append(((kq, ksc), (vq, vsc)))
-                else:
-                    kp = kp.at[phys, off].set(unwrap(k).astype(kp.dtype))
-                    vp = vp.at[phys, off].set(unwrap(v).astype(vp.dtype))
-                    out = paged_attention(unwrap(q), kp, vp, page_table,
-                                          lens, rep=rep, scale=scale,
-                                          interpret=interp)
-                    new_pools.append((kp, vp))
-                x = x + attn.o_proj(out.reshape(S, W, nh * hd))
-                x = x + layer.mlp(layer.post_attention_layernorm(x))
+                    new_pools.append(view.stored(kept))
             hidden = mdl.norm(x)
             if self.model.lm_head is None:
                 logits = unwrap(hidden) @ unwrap(mdl.embed_tokens.weight).T
@@ -2262,12 +2100,7 @@ class BatchDecodeEngine:
         args = self._decode_args()
         p = _perf()
         perf_on = p is not None and p.enabled()
-        # fused engines get their own cost-registry bucket so an A/B in
-        # one process records the reference and fused decode programs as
-        # SEPARATE rows — the hbm_bytes delta between them is the
-        # data-movement claim the kernel makes (docs/kernels.md)
-        cost_bucket = (f"s{self.S}c{self.chunk}"
-                       + ("-fused" if self.fused.get("enabled") else ""))
+        cost_bucket = f"s{self.S}c{self.chunk}"
         if perf_on and not self._decode_captured:
             self._decode_captured = True    # capture attempted once only
             # lower (no backend compile) a 1-step variant and scale by
@@ -2276,8 +2109,7 @@ class BatchDecodeEngine:
             p.cost_of_lowered(
                 "serving.decode", jax.jit(self._decode_program(1)), args,
                 bucket=cost_bucket, scale=float(self.chunk),
-                quant=self.quant or "off", slots=self.S, chunk=self.chunk,
-                fused=self.fused.get("paged_attention", "off"))
+                quant=self.quant or "off", slots=self.S, chunk=self.chunk)
         # chunks right after an admission also pay the _collect_firsts
         # readback inside this window; only PURE decode chunks are folded
         # into the program's wall, so wall_min measures the decode

@@ -422,7 +422,6 @@ class ServingEngine:
                  draft=None,
                  spec_k: int = 0,
                  draft_quant: Optional[str] = None,
-                 fused_kernels: Optional[bool] = None,
                  kv_quant: Optional[str] = None,
                  kv_host_bytes: Optional[int] = None):
         if mode not in ("continuous", "static"):
@@ -518,8 +517,8 @@ class ServingEngine:
                 page_size=kv_page_size, num_pages=kv_num_pages,
                 prefix_cache=prefix_cache, mesh=mesh, plan=plan,
                 bundle=bundle, draft=draft, spec_k=spec_k,
-                draft_quant=draft_quant, fused_kernels=fused_kernels,
-                kv_quant=kv_quant, kv_host_bytes=kv_host_bytes)
+                draft_quant=draft_quant, kv_quant=kv_quant,
+                kv_host_bytes=kv_host_bytes)
             self._spec_enabled = self._engine.spec is not None
             if self._spec_enabled:
                 self._announce_spec()
@@ -903,11 +902,6 @@ class ServingEngine:
             # the speculation is actually paying for its draft overhead
             "spec": (self._engine.spec_info() if self._engine is not None
                      else {"enabled": False}),
-            # fused Pallas kernels (docs/kernels.md): which data-movement
-            # kernels this engine decodes through — "off", "interpret"
-            # (CPU), "compiled" (TPU) or "fallback: <reason>"
-            "fused": (self._engine.fused_info() if self._engine is not None
-                      else {"enabled": False}),
             # replica parallelism for the fleet router / /metrics: mesh
             # axes+devices and the tp degree this engine decodes at
             "mesh": mesh,

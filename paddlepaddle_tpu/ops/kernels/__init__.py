@@ -6,11 +6,10 @@ fused_rms_norm, fused_bias_act …). The flash kernels have an XLA reference
 path used on CPU (tests run on a virtual CPU mesh) and when
 FLAGS_use_pallas_kernels=0.
 
-The FLAGS_fused_kernels family (gather_gemm.py + paged_attention.py —
-the two measured data-movement floors, docs/kernels.md) additionally runs
-in Pallas INTERPRET mode on CPU so parity is test-pinned in the tier-1
-environment, and falls back LOUDLY to the reference formulation on any
-unsupported config.
+gather_gemm.py (the MoE dispatch of ``MoELayer(dispatch_mode="fused")``)
+and paged_latent_attention.py (a latent cache row's decode attention, behind
+the serving engine's paged view: docs/kernels.md) additionally run in Pallas
+INTERPRET mode on CPU, so parity is test-pinned in the tier-1 environment.
 
 Every kernel here is compiled by Mosaic and compared with its
 ``jax.numpy`` reference by ``chip_smoke.py``'s kernels leg on the chip;
@@ -23,7 +22,7 @@ from typing import NamedTuple, Optional, Tuple
 
 
 def interpret_mode() -> bool:
-    """True when fused kernels must run under the Pallas interpreter —
+    """True when these kernels must run under the Pallas interpreter —
     any backend without a Mosaic compiler (the CPU tier-1 environment).
     ONE definition for every kernel in this package, so one kernel cannot
     run compiled and another interpreted on the same host."""

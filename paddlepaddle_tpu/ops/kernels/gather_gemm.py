@@ -32,7 +32,7 @@ Runs compiled on TPU backends (Mosaic accepts it and it matches the
 reference at the MoE bench shape: chip_smoke.py, PR 21) and in Pallas
 interpret mode elsewhere (CPU tier-1), which is how parity vs the einsum
 dispatch is test-pinned without an accelerator
-(tests/test_fused_kernels.py). The price of the tile-aligned gather is one
+(tests/test_gather_gemm_kernel.py). The price of the tile-aligned gather is one
 float32 copy of ``x`` per call; whether the kernel beats the XLA
 formulation on the chip is ROADMAP 1.2's measurement.
 """

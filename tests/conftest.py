@@ -66,7 +66,9 @@ def can_serialize_executables() -> bool:
 
     x = jnp.zeros((3, 128))
     compiled = jax.jit(lambda x: jax.lax.top_k(x, 128)).lower(x).compile()
-    compiled(x)
+    # wait for the run: it is the run that binds the sort's comparator, and
+    # dispatch is asynchronous, so a busy host would else serialize first
+    jax.block_until_ready(compiled(x))
     try:
         serialize_executable.serialize(compiled)
     except jax.errors.JaxRuntimeError as e:

@@ -103,14 +103,6 @@ def test_rung_follows_the_longest_active_context(model, lens, active, span,
     assert int(eng._view_pages_column(rung)[0, 0]) == pages
 
 
-def test_fused_kernel_keeps_the_whole_table(model):
-    eng = _engine(model, fused_kernels=True)
-    assert eng.fused.get("enabled"), eng.fused
-    rung = eng._view_rung(jnp.asarray([3, 0, 0, 0], jnp.int32),
-                          jnp.asarray([1, 0, 0, 0], bool), CHUNK)
-    assert eng._ladder[int(rung)] == eng.P == 8
-
-
 # -- (a) bounded against whole-table, at every rung --------------------------
 
 @pytest.mark.parametrize("rung_pages", LADDER)
@@ -256,22 +248,47 @@ def test_speculative_verify_stays_token_exact_across_rungs(model):
         spec.stop()
 
 
-@pytest.mark.parametrize("partner", ["whole_table", "fused_kernel"])
-def test_int8_reference_stays_token_exact(model, partner):
+def test_int8_reference_stays_token_exact(model):
     reqs = lambda: [_req(p, n) for p, n in _workload()]
     bounded = _engine(model, kv_quant="int8")
     got = _serve(bounded, reqs())
-    if partner == "whole_table":
-        other = _engine(model, whole=True, kv_quant="int8")
-    else:
-        other = _engine(model, kv_quant="int8", fused_kernels=True)
-        assert other.fused.get("enabled"), other.fused
+    other = _engine(model, whole=True, kv_quant="int8")
     for a, b in zip(got, _serve(other, reqs())):
         np.testing.assert_array_equal(a, b)
     st = bounded.stats
     assert 0 < st["decode_view_pages"] < st["decode_table_pages"]
     assert other.stats["decode_view_pages"] \
         == other.stats["decode_table_pages"]
+
+
+def test_int8_speculative_verify_stays_token_exact_across_rungs(model):
+    """The verify's ``W = k + 1`` rows quantised into their pages behind
+    the view: the bounded ladder against the whole table, both int8."""
+    draft = _llama(hidden=32, seed=7)
+
+    def spec_engine(whole):
+        eng = ServingEngine(model, max_batch_size=3, decode_chunk=6,
+                            kv_page_size=PS, draft=draft, spec_k=2,
+                            kv_quant="int8")
+        assert eng._engine._ladder == LADDER
+        if whole:
+            eng._engine._ladder = (eng._engine.P,)
+        return eng
+
+    bounded, whole = spec_engine(False), spec_engine(True)
+    try:
+        want = [np.asarray(whole.submit(p, max_new_tokens=n).result(120))
+                for p, n in _workload()]
+        futs = [bounded.submit(p, max_new_tokens=n) for p, n in _workload()]
+        for f, w in zip(futs, want):
+            np.testing.assert_array_equal(np.asarray(f.result(120)), w)
+        st = bounded._engine.stats
+        assert 0 < st["decode_view_pages"] < st["decode_table_pages"]
+        assert whole._engine.stats["decode_view_pages"] \
+            == whole._engine.stats["decode_table_pages"]
+    finally:
+        bounded.stop()
+        whole.stop()
 
 
 # -- (e) the counters --------------------------------------------------------

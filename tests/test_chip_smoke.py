@@ -33,18 +33,16 @@ def test_smoke_legs_tiny_on_cpu():
     assert legs["train"]["status"] == "ok"
     losses = legs["train"]["losses"]
     assert losses == sorted(losses, reverse=True) and losses[-1] < losses[0]
-    for name in ("default", "fused", "fused_int8_kv"):
+    assert set(legs["serve"]) == {"status", "default", "int8_kv"}
+    for name in ("default", "int8_kv"):
         row = legs["serve"][name]
         assert row["status"] == "ok" and row["prefix_hits"] >= 1
         assert row["worst_gap_over_max_logit"] <= row["margin"]
-    assert legs["serve"]["fused"]["paged_attention"] == "interpret"
-    assert legs["serve"]["fused_int8_kv"]["kv_quant"] == "int8"
+    assert legs["serve"]["default"]["kv_quant"] == "off"
+    assert legs["serve"]["int8_kv"]["kv_quant"] == "int8"
     table = legs["kernels"]["table"]
     assert set(table) == {
-        "flash", "flash_varlen", "gather_gemm",
-        "paged_attention_W1_bf16", "paged_attention_W1_int8",
-        "paged_attention_W4_bf16", "paged_attention_W4_int8",
-        "paged_latent_attention"}
+        "flash", "flash_varlen", "gather_gemm", "paged_latent_attention"}
     assert all(r["mode"] == "interpret" for r in table.values())
     # conftest's 8 virtual CPU devices stand in for the four chips: the
     # sharding evidence is real, the allocator evidence is chip-only

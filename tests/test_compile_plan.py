@@ -441,50 +441,3 @@ def test_bundle_full_e2e_int8_with_prefix_variant(needs_bundles, tmp_path):
     assert out["prefix_hits"] >= 1
     assert out["tokens"] == base, \
         "bundle-restarted engine diverged token-wise from the saver"
-
-
-# -- fused-kernel programs through the cold-start machinery ------------------
-
-def test_fused_program_warmup_bundle_round_trip_and_mismatch(needs_bundles,
-                                                             model,
-                                                             tmp_path):
-    """ISSUE 15 satellite: the fused paged-decode program is a first-
-    class CompilePlan citizen — warmup() still guarantees a compile-free
-    serve window with the kernel armed, a bundle round-trips the fused
-    program with zero cold compiles, and a kernel-config mismatch
-    (bundle saved fused, engine resolved reference — or vice versa)
-    falls back LOUDLY with the differing fact named."""
-    watchdog.install(threshold=3)
-    eng = BatchDecodeEngine(model, max_slots=2, chunk=4, page_size=16,
-                            fused_kernels=True)
-    assert eng.compile_plan.facts["fused"] == {"paged_attention": "fused"}
-    info = eng.warmup()
-    assert info["compiled"] == len(eng.compile_plan.keys())
-    before = _total_compiles()
-    baseline = _serve(eng, _reqs())
-    assert _total_compiles() == before, \
-        "warmup must leave zero compiles in the fused serve window"
-
-    path = str(tmp_path / "fused_bundle")
-    eng.save_serving_bundle(path)
-    cold0 = _cold_compiles()
-    eng2 = BatchDecodeEngine(model, max_slots=2, chunk=4, page_size=16,
-                             fused_kernels=True, bundle=path)
-    assert eng2._bundle_info["loaded"] is True
-    outs = _serve(eng2, _reqs())
-    assert _cold_compiles() == cold0, \
-        "fused bundle path must serve with zero cold compiles"
-    for a, b in zip(baseline, outs):
-        assert (a == b).all()
-
-    # kernel-config mismatch: a REFERENCE engine must reject the fused
-    # bundle (and name the fact), then serve through lazy builds
-    eng3 = BatchDecodeEngine(model, max_slots=2, chunk=4, page_size=16,
-                             fused_kernels=False, bundle=path)
-    assert eng3._bundle_info["loaded"] is False
-    assert "fused" in eng3._bundle_info["error"]
-    with pytest.raises(cp.BundleMismatchError, match="fused"):
-        eng3.load_serving_bundle(path, strict=True)
-    outs3 = _serve(eng3, _reqs())
-    for a, b in zip(baseline, outs3):
-        assert (a == b).all(), "fallback engine must still be token-exact"

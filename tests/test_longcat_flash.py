@@ -319,7 +319,6 @@ def test_serving_engine_copies_the_counters_and_a_dense_model_has_none():
 @pytest.mark.parametrize("kwargs, reason", [
     ({"kv_quant": "int8"}, "one scale a kv head"),
     ({"mesh": object()}, "shards a pool on its kv heads"),
-    ({"fused_kernels": True}, "walks a K and a V pool"),
     ({"draft": object(), "spec_k": 2}, "draft decoder builds K/V pair caches"),
     ({"kv_host_bytes": 1 << 20}, "spilled slab is laid out"),
 ])

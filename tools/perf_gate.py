@@ -151,12 +151,6 @@ def serving_metrics(doc: dict) -> Dict[str, Tuple[float, str]]:
     put("serving.prefix_hit_rate", body.get("prefix_hit_rate"), HIGHER)
     put("serving.concurrency_peak", body.get("concurrency_peak"), HIGHER)
     put("serving.kv_occupancy_peak", body.get("kv_occupancy_peak"), LOWER)
-    # fused-kernel chunk A/B (serving_bench --fused-kernels): the paged
-    # decode chunk's premium over the contiguous no-indirection floor —
-    # the r7 <=5% budget the in-kernel page walk exists to hold; creeping
-    # up means the kernel regressed or silently fell back to the gather
-    put("serving.paged_chunk_overhead_pct",
-        body.get("paged_chunk_overhead_pct"), LOWER)
     # fleet-router column (serving_bench --replicas N): completed/submitted
     # under the workload — the availability the failover path defends
     put("serving.availability", body.get("availability"), HIGHER)

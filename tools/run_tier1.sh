@@ -25,8 +25,8 @@
 # pre-warm); the int8+prefix bundle e2e is `slow`-marked. The full
 # restart-to-first-token measurement needs fresh processes and runs as
 # `python tools/coldstart_bench.py` (its {"coldstart": …} line feeds
-# perf_gate's coldstart.* lower-is-better metrics and BASELINE.md; use
-# --preset tiny as the quick smoke).
+# perf_gate's coldstart.* lower-is-better metrics; use --preset tiny as
+# the quick smoke).
 #
 # Elastic-fleet suite: tests/test_fleet.py runs its fast half here
 # (policy hysteresis/cooldown/bounds units, dynamic router membership
@@ -38,7 +38,7 @@
 # the real-engine 4x-step-during-rollout + preemption drill is
 # chaos+slow-marked (tools/run_chaos.sh). The measured artifact comes
 # from `python tools/serving_bench.py --traffic step:4@10 --autoscale
-# MIN:MAX` (BASELINE.md "Elastic fleet").
+# MIN:MAX`.
 #
 # Speculative-decoding suite: tests/test_speculative.py runs its fast
 # half here (token-exact greedy parity weak-draft + self-draft, rollback
@@ -48,7 +48,7 @@
 # variants are `slow`-marked and the breaker-storm drill is
 # `chaos`-marked (tools/run_chaos.sh). The A/B artifact comes from
 # `python tools/serving_bench.py --spec-k N --draft <preset>` (gated by
-# perf_gate's serving.spec_tok_s; BASELINE.md "Speculative decoding").
+# perf_gate's serving.spec_tok_s).
 #
 # Request-journey suite: tests/test_reqtrace.py (one stitched trace per
 # request: mid-flight-kill failover stitching, per-attempt queue-wait
@@ -58,18 +58,14 @@
 # few seconds total. The reqtrace-on hot-path budget (<5% vs off,
 # retry-once-on-noise) is gated by tools/check_obs_overhead.py gate 5.
 #
-# Fused-kernel suite: tests/test_fused_kernels.py runs its fast half here
-# (gather-GEMM vs einsum/sorted dispatch parity incl. empty experts +
-# capacity overflow, paged-attention kernel vs the gather-view reference
-# at W=1 and W=3, engine-level TOKEN-EXACT greedy parity with
-# fused_kernels armed — bf16/int8/speculative — via Pallas INTERPRET
-# mode on this CPU tier, the loud-fallback drill on unsupported configs,
-# cost-registry HBM-bytes reduction, and the perf_gate smoke for the two
-# new gated fields moe.dispatch_ms + serving.paged_chunk_overhead_pct);
-# heavy kernel shapes + int8 group-wise are `slow`-marked. The measured
-# A/B artifacts come from `python tools/serving_bench.py
-# --fused-kernels` and `python tools/moe_dispatch_bench.py`
-# (BASELINE.md "Fused kernels"; docs/kernels.md).
+# Kernel suites: tests/test_gather_gemm_kernel.py (gather-GEMM vs
+# einsum/sorted dispatch parity incl. empty experts + capacity overflow,
+# MoELayer's loud fallback, the perf_gate smoke for moe.dispatch_ms) and
+# tests/test_paged_latent_kernel.py (the latent decode kernel against the
+# gathered view) run here via Pallas INTERPRET mode on this CPU tier;
+# tests/test_int8_kv_view.py pins int8 KV pages behind the paged view.
+# The dispatch A/B artifact comes from `python tools/moe_dispatch_bench.py`
+# (docs/kernels.md).
 #
 # History-and-alerting suite: tests/test_tsdb_alerts.py (in-process TSDB
 # ring/downsample/rate units, window quantiles, multi-window burn-rate

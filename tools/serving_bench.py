@@ -317,90 +317,6 @@ def warm_engine(eng, model, prompts, args, prefix_cache=True):
         pfx.hits = pfx.misses = pfx.evictions = 0
 
 
-# recompile-watchdog region: an A/B deliberately compiles BOTH
-# formulations' programs from the same call sites — a CPU CI run with the
-# watchdog armed must not read that as a per-callsite storm
-from paddlepaddle_tpu.observability.watchdog import (
-    expected_compiles as _expected_compiles,
-)
-
-
-def time_decode_chunks(model, args, kv_layout, fused=False, iters=8):
-    """Pure decode-chunk wall time (ms/chunk) for one engine variant:
-    fill every slot with a long-budget request, then time chunk calls
-    with no admissions inside the window (the r7 '<=5% chunk overhead'
-    methodology — one packed host sync per chunk, admissions excluded).
-    Returns (ms_per_chunk, fused_info)."""
-    from paddlepaddle_tpu.inference.decode_engine import BatchDecodeEngine
-    from paddlepaddle_tpu.inference.serving import GenerationRequest
-
-    rng = np.random.default_rng(3)
-    # every timed chunk must run with ALL slots still active: the budget
-    # covers warmup + 3 timed repetitions, clamped to the model's window —
-    # and a window too small to hold even one honest repetition is an
-    # ERROR, not a silently-drained measurement (this number feeds the
-    # gated paged_chunk_overhead_pct)
-    budget = min(args.chunk * (3 * iters + 6),
-                 model.config.max_position_embeddings - 64)
-    iters = min(iters, (budget // args.chunk - 2) // 3)
-    if iters < 1:
-        raise RuntimeError(
-            f"chunk A/B needs >= 5 chunks of {args.chunk} inside the "
-            f"model window ({model.config.max_position_embeddings}); "
-            "lower --chunk or raise --max-len")
-    eng = BatchDecodeEngine(
-        model, max_slots=args.slots, chunk=args.chunk, kv_layout=kv_layout,
-        page_size=args.page_size, num_pages=args.num_pages,
-        fused_kernels=fused)
-    for _ in range(args.slots):
-        r = GenerationRequest(
-            rng.integers(0, model.config.vocab_size, (32,)).astype(np.int32),
-            budget, 0.0, 0, None)
-        r.prefix_len = None
-        if not eng._admit(r):      # -O safe: admission IS the setup
-            raise RuntimeError("chunk A/B could not fill every slot")
-    eng._decode_chunk()            # compile + first-token sync flushed
-    eng._decode_chunk()
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            eng._decode_chunk()
-        best = min(best, (time.perf_counter() - t0) / iters)
-    info = eng.fused_info()
-    eng.reset_slots()
-    return round(best * 1e3, 3), info
-
-
-def run_chunk_ab(model, args):
-    """--fused-kernels chunk-time A/B: contiguous (the no-indirection
-    floor) vs paged reference (pool[page_table] gather) vs paged FUSED
-    (in-kernel page walk). ``paged_chunk_overhead_pct`` — the armed
-    engine's chunk time over the contiguous floor — is the r7 <=5%
-    budget perf_gate gates LOWER; the reference row rides along so the
-    kernel's own delta stays visible."""
-    with _expected_compiles("serving_bench_fused_ab"):
-        con_ms, _ = time_decode_chunks(model, args, "contiguous")
-        ref_ms, _ = time_decode_chunks(model, args, "paged")
-        fus_ms, info = time_decode_chunks(model, args, "paged", fused=True)
-    row = {
-        "contiguous_chunk_ms": con_ms,
-        "paged_chunk_ms": ref_ms,
-        "paged_fused_chunk_ms": fus_ms,
-        "paged_ref_overhead_pct": round((ref_ms - con_ms) / con_ms * 100, 2),
-        "paged_chunk_overhead_pct": round((fus_ms - con_ms) / con_ms * 100,
-                                          2),
-        "fused_info": info,
-    }
-    print(f"chunk A/B ({args.slots} slots, chunk {args.chunk}): "
-          f"contiguous {con_ms} ms  paged {ref_ms} ms "
-          f"(+{row['paged_ref_overhead_pct']}%)  "
-          f"paged+fused {fus_ms} ms "
-          f"({row['paged_chunk_overhead_pct']:+}%)  "
-          f"[{info.get('paged_attention')}]", flush=True)
-    return row
-
-
 def build_draft(args, model):
     """Resolve the --draft preset into the engine's ``draft=`` argument:
     the target itself for ``self``, else a scaled-down CONFIG — the
@@ -426,7 +342,7 @@ def build_draft(args, model):
 
 def run_serving(model, prompts, args, kv_layout, slots, num_pages=None,
                 prefix_cache=True, warm=True, tp=1, spec=False,
-                fused=False, kv_quant=None, kv_host_bytes=None):
+                kv_quant=None, kv_host_bytes=None):
     """One engine pass over the workload; returns the metrics row.
     ``tp > 1`` serves through a tensor-parallel engine (sharding plan over
     an ``mp``-axis mesh: weights column/row-parallel, KV pool sharded on
@@ -443,10 +359,6 @@ def run_serving(model, prompts, args, kv_layout, slots, num_pages=None,
                        kv_page_size=args.page_size, kv_num_pages=num_pages,
                        prefix_cache=prefix_cache,
                        mesh=(f"mp{tp}" if tp > 1 else None),
-                       # explicit bool BOTH ways: an ambient
-                       # PADDLE_FUSED_KERNELS=1 must not arm the kernel
-                       # in a row labeled (and baselined) as reference
-                       fused_kernels=bool(fused),
                        kv_quant=kv_quant, kv_host_bytes=kv_host_bytes,
                        **spec_kw) as eng:
         if warm:
@@ -464,7 +376,6 @@ def run_serving(model, prompts, args, kv_layout, slots, num_pages=None,
         kv = eng._engine.kv_stats()
         peak_busy = eng._engine.stats["peak_busy"]
         spec_info = eng._engine.spec_info() if spec else None
-        fused_info = eng._engine.fused_info() if fused else None
     new_tokens = sum(len(o) - len(p) for o, (p, _) in zip(outs, prompts))
     row = {"kv_layout": kv_layout, "slots": slots,
            "aggregate_tok_s": round(new_tokens / max(dt, 1e-9), 1),
@@ -473,8 +384,6 @@ def run_serving(model, prompts, args, kv_layout, slots, num_pages=None,
     row.update(_goodput_cols(gp0, dt))
     if tp > 1:
         row["tp"] = tp
-    if fused_info is not None:
-        row["fused"] = fused_info
     row.update(slo_summary(futs))
     if kv["layout"] == "paged":
         row["kv_pages_total"] = kv["pages_total"]
@@ -1012,13 +921,6 @@ def main():
                          "prefix entries spill page slabs to host RAM on "
                          "eviction and restore into fresh device pages "
                          "on re-hit (0 = tier off)")
-    ap.add_argument("--fused-kernels", action="store_true",
-                    help="arm the fused Pallas paged-attention kernel "
-                    "(FLAGS_fused_kernels; interpret-mode on CPU) for the "
-                    "profile run AND add a chunk-time A/B — contiguous vs "
-                    "paged-reference vs paged-fused — whose "
-                    "paged_chunk_overhead_pct (the r7 <=5% budget) "
-                    "perf_gate gates lower-is-better")
     ap.add_argument("--remote-fleet", action="store_true",
                     help="run the --replicas fleet as REAL OS processes "
                     "(supervised replica_main per replica over the C-API "
@@ -1117,11 +1019,6 @@ def main():
         ap.error("--tp compares one engine against its tensor-parallel "
                  "form; run it with --replicas 1 and without --ab")
 
-    if args.fused_kernels and (args.replicas > 1 or args.tp > 1
-                               or args.traffic):
-        ap.error("--fused-kernels A/Bs one engine's decode formulations; "
-                 "run it without --replicas/--tp/--traffic")
-
     if args.autoscale:
         if not args.traffic:
             ap.error("--autoscale needs an open-loop --traffic profile "
@@ -1197,13 +1094,11 @@ def main():
     else:
         row = run_serving(model, prompts, args, args.kv_layout, args.slots,
                           num_pages=args.num_pages,
-                          fused=args.fused_kernels,
                           kv_quant=(None if args.kv_quant == "off"
                                     else args.kv_quant),
                           kv_host_bytes=(args.kv_host_mb << 20
                                          if args.kv_host_mb else None))
         fmt(row, f"{args.kv_layout} x{args.slots}"
-            + (" +fused" if args.fused_kernels else "")
             + (f" kv={args.kv_quant}" if args.kv_quant != "off" else "")
             + (f" host={args.kv_host_mb}MB" if args.kv_host_mb else ""))
         body.update(row)
@@ -1246,14 +1141,6 @@ def main():
         body["no_prefix_cache"] = ctl
     if args.profile == "mixed":
         body["mixed_tok_s"] = body["aggregate_tok_s"]
-
-    if args.fused_kernels:
-        ab = run_chunk_ab(model, args)
-        body["fused_ab"] = ab
-        # the gated field (perf_gate serving.paged_chunk_overhead_pct,
-        # LOWER): the fused engine's decode-chunk premium over the
-        # contiguous no-indirection floor — the r7 <=5% budget
-        body["paged_chunk_overhead_pct"] = ab["paged_chunk_overhead_pct"]
 
     _emit(body, args)
 
