@@ -81,6 +81,7 @@ class Size:
     varlen: Tuple[int, int, int]      # packed tokens, heads, head_dim
     moe: Dict[str, int]               # experts, d, h, tokens, topk, capacity
     latent: Dict[str, int]            # slots, page, heads, rank, rope, pages_per_slot
+    gqa: Dict[str, int]               # slots, page, heads, kv_heads, head_dim, pages_per_slot
 
 
 # bench.py's primary config (254M) and the serving/kernel shapes it implies
@@ -101,6 +102,10 @@ FULL = Size(
     # the 512 + 64 latent row, a 4,096-token table of 64-token pages
     latent=dict(slots=128, page=64, heads=64, rank=512, rope=64,
                 pages_per_slot=64),
+    # serve-docqa-steady's and serve-chat-saturated's decode attention: 32
+    # slots, 32 query over 8 kv heads of 128, the same table
+    gqa=dict(slots=32, page=64, heads=32, kv_heads=8, head_dim=128,
+             pages_per_slot=64),
 )
 
 # the tier-1 width: same code, seconds on the CPU
@@ -115,6 +120,8 @@ TINY = Size(
     varlen=(32, 2, 64),
     moe=dict(experts=4, d=16, h=24, tokens=48, topk=2, capacity=8),
     latent=dict(slots=3, page=8, heads=4, rank=16, rope=8, pages_per_slot=4),
+    gqa=dict(slots=4, page=8, heads=4, kv_heads=2, head_dim=128,
+             pages_per_slot=4),
 )
 
 
@@ -532,6 +539,51 @@ def _kernel_paged_latent(size: Size, interpret: bool) -> Dict[str, object]:
     return row
 
 
+def _kernel_paged_gqa(size: Size, interpret: bool) -> Dict[str, object]:
+    """The GQA decode kernel against the gathered view it takes the place of
+    (``decode_engine._attend_view`` over the whole table, in float32), on
+    ragged lengths with both ends of the walk and idle slots between."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddlepaddle_tpu.inference import decode_engine as de
+    from paddlepaddle_tpu.ops.kernels.paged_gqa_attention import \
+        paged_gqa_attention
+
+    p = size.gqa
+    S, ps, H, kvh, hd, P = (p["slots"], p["page"], p["heads"], p["kv_heads"],
+                            p["head_dim"], p["pages_per_slot"])
+    n_pages = S * P + 1                      # page 0 is the null page
+    rng = np.random.default_rng(7)
+    lens = rng.integers(1, P * ps - 1, (S,)).astype(np.int32)
+    lens[0], lens[-1] = 0, P * ps - 1        # one token; the table filled
+    lens[1:-1:3] = 0                         # idle slots: nothing to walk
+    table = _scattered_table(rng, lens + 1, P, ps)
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    q = jax.random.normal(ks[0], (S, 1, H, hd), bf)
+    k_new = jax.random.normal(ks[1], (S, 1, kvh, hd), bf)
+    v_new = jax.random.normal(ks[2], (S, 1, kvh, hd), bf)
+    k_pool = jax.random.normal(ks[3], (n_pages, ps, kvh, hd), bf)
+    v_pool = jax.random.normal(ks[4], (n_pages, ps, kvh, hd), bf)
+    scale = hd ** -0.5
+    table, lens = jnp.asarray(table), jnp.asarray(lens)
+
+    got = _mosaic("paged_gqa_attention", lambda q, kn, vn, kp, vp:
+                  paged_gqa_attention(q[:, 0], kn[:, 0], vn[:, 0], kp, vp,
+                                      table, lens, scale=scale,
+                                      interpret=interpret),
+                  interpret)(q, k_new, v_new, k_pool, v_pool)
+    want = jax.jit(lambda *a: de._attend_view(
+        P, ps, H // kvh, scale, *a, table, lens)[:, 0])(
+            *_f32(q, k_new, v_new, k_pool, v_pool))
+    row = _compare("paged_gqa_attention", got, want, interpret)
+    row["live_tokens"] = int(lens.sum()) + S
+    row["table_tokens"] = S * P * ps
+    return row
+
+
 def _kernel_gather_gemm(size: Size, interpret: bool) -> Dict[str, object]:
     import jax
     import jax.numpy as jnp
@@ -570,6 +622,7 @@ def kernels_leg(size: Size, interpret: bool) -> Dict[str, object]:
     table = {"flash": _kernel_flash(size, interpret),
              "flash_varlen": _kernel_varlen(size, interpret)}
     table["paged_latent_attention"] = _kernel_paged_latent(size, interpret)
+    table["paged_gqa_attention"] = _kernel_paged_gqa(size, interpret)
     table["gather_gemm"] = _kernel_gather_gemm(size, interpret)
     return {"status": "ok", "tolerance": f"{KERNEL_TOL} * max|reference|",
             "table": table}

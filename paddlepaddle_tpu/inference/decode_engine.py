@@ -16,16 +16,19 @@ layout (``kv_layout="paged"``, the default) fixes it the static-shape way:
 
 * PAGED KV POOL: one ``[num_pages, page_size, kvh, hd]`` buffer per layer
   plus a device-resident page table ``[slots, max_len/page_size]`` int32.
-  The decode body GATHERS each layer's logical view through the page
-  table (the XLA equivalent of the GPU block table — a gather index, not
-  pointer chasing), runs the UNCHANGED ragged-attention math, and
-  scatters the one newly written position back to its physical page. The
-  view is as wide as the longest live context needs, not ``[slots, L]``:
-  one rung of a short static ladder of page counts, chosen in-graph once
-  per call (``_view_rung``). A latent (MLA) cache row's decode step
-  gathers nothing: its attention is the kernel of
-  ops/kernels/paged_latent_attention.py, which reads each slot's pages
-  where they lie, as far as the slot's own length goes.
+  A decode step's attention reads each ACTIVE slot's pages where they
+  lie, as far as the slot's own length goes, and gathers nothing: a
+  Pallas kernel that walks the page table (ops/kernels/
+  paged_gqa_attention.py for a K and a V pool, paged_latent_attention.py
+  for a latent (MLA) row); the one newly written position is scattered to
+  its physical page outside it. What the kernels do not take (int8 pages,
+  the speculative verify's W-wide call, a tensor-parallel plan, heads
+  narrower than a lane tile) GATHERS each layer's logical view through
+  the page table (the XLA equivalent of the GPU block table — a gather
+  index, not pointer chasing) and runs the UNCHANGED ragged-attention
+  math over it; that view is as wide as the longest live context needs,
+  not ``[slots, L]``: one rung of a short static ladder of page counts,
+  chosen in-graph once per call (``_view_rung``).
   Admission allocates pages from a host-side free list
   (:mod:`~.kv_pool`), scatters the prefill prefix page-by-page, and slot
   retirement returns pages — so concurrency is bounded by total KV bytes
@@ -65,6 +68,8 @@ import numpy as np
 from ..core import autograd as _ag
 from ..core.dispatch import unwrap
 from ..observability.recorder import phase, phase_counters
+from ..ops.kernels.paged_gqa_attention import (paged_gqa_attention,
+                                               reads_in_place)
 from ..ops.kernels.paged_latent_attention import (lane_whole,
                                                   paged_latent_attention,
                                                   pages_walked)
@@ -87,11 +92,17 @@ def _bucket(n: int, q: int = 128) -> int:
 
 
 # The view ladder, in eighths of the page table: 16, 24, 56 and 64 pages at
-# max_len 4096 with pages of 64 tokens. Each rung is there for a property of
-# the chip or of the engine's geometry, read on a TPU v5 lite at 32 slots, 8 kv
-# heads of 128 in bf16, 16 layers (PERF.md section 6, PRs 29 and 30), where a
-# view of n pages a slot is S*n*ps*kvh*hd*2 bytes = n * 4 MiB, once for K and
-# once for V in a layer.
+# max_len 4096 with pages of 64 tokens. It bounds the GATHERED view, which
+# since PR 35 is what serves the calls no page-walk kernel takes: int8
+# ``(codes, scales)`` pairs, a W-wide call (the speculative verify), an
+# engine under a sharding plan (GSPMD cannot partition a Mosaic call) and
+# pools whose rows are not whole lane tiles; a decode step over plain pools
+# reads its pages in place and consults no rung (``_PagedView.attend``). Each
+# rung is there for a property of the chip or of the engine's geometry, read
+# on a TPU v5 lite at 32 slots, 8 kv heads of 128 in bf16, 16 layers, when
+# plain pools were served here too (PERF.md section 6, PRs 29 and 30), where
+# a view of n pages a slot is S*n*ps*kvh*hd*2 bytes = n * 4 MiB, once for K
+# and once for V in a layer.
 # * FOUR rungs, not eight: a rung is one more branch in every layer, and costs
 #   START-UP by its count, not its width: about a second of every start-up a
 #   rung at 16 layers, retrieved from the compile cache or compiled. All eight
@@ -101,15 +112,18 @@ def _bucket(n: int, q: int = 128) -> int:
 #   112 MiB, and not from 30 pages, 120 MiB, on. A step whose views stay
 #   there costs far less a page than one whose views do not (steps on rungs
 #   16 and 24 average 14.6 ms, on 16 and 32 pages 20.2 ms), so a short
-#   context gets two rungs under that limit and not one on it. The limit is
-#   a count of BYTES, not a share of the table: with more slots, a longer
-#   max_len or wider heads the same eighths are wider views.
+#   context gets two rungs under that limit and not one on it: what an int8
+#   or a verify call of chat-length contexts runs. The limit is a count of
+#   BYTES, not a share of the table: with more slots, a longer max_len or
+#   wider heads the same eighths are wider views.
 # * 7 eighths: the rung under the top. A table that is nearly full drops to
 #   it in the calls where no live context has reached the last eighth (a
 #   fifth of them with contexts of 2-3.7k tokens), an eighth of gather and
 #   attention spared. A context between 3 and 7 eighths pays for 7: a rung
 #   in between costs what every rung costs.
 # * 8 eighths: the table itself; every context fits.
+# None of the four has been re-read on the chip for the calls that are left
+# to them (no cell serves int8 pages or drafts: ROADMAP 3.8).
 VIEW_EIGHTHS = (2, 3, 7, 8)
 
 
@@ -366,29 +380,40 @@ class _PagedView:
     calls :meth:`attend` (a K and a V pool: models/llama.py) or
     :meth:`attend_latent` (one pool of latent rows:
     models/longcat_flash.py) in place of writing through a dense cache:
-    the view is gathered here, as wide as ``rung`` says (a latent row's
-    decode step reads its pages in place and gathers none), and what comes
-    back beside the output is for :meth:`stored`: the new rows, or, where
-    the pool is an int8 ``(codes, scales)`` pair, the pair with the rows
-    quantised into their pages. How a pool is stored and read is decided
-    here, from the pools themselves and the static width of the call."""
+    a decode step (one query row a head) over plain pools reads each slot's
+    pages in place, as far as ``walk`` says, and gathers none; any other call
+    gathers its view here, as wide as ``rung`` says. What comes back beside
+    the output is for :meth:`stored`: the new rows, or, where the pool is an
+    int8 ``(codes, scales)`` pair, the pair with the rows quantised into
+    their pages. How a pool is stored and read is decided here, from the
+    pools themselves and the static width of the call. ``walk [S]`` is how
+    far each slot's pages are read in place: its length where it is active,
+    0 where it is idle (a stale length over a zeroed table row would read
+    the null page once a page); None where the caller wants the gathered
+    view whatever the pools are (the speculative verify; an engine under a
+    sharding plan, whose program GSPMD partitions)."""
 
     __slots__ = ("ladder", "page_size", "kv_dtype", "pools", "page_table",
-                 "rung", "phys", "off")
+                 "rung", "phys", "off", "walk")
 
-    def __init__(self, eng, pools, page_table, rung, phys, off):
+    def __init__(self, eng, pools, page_table, rung, phys, off, walk=None):
         self.ladder, self.page_size = eng._ladder, eng.page_size
         self.kv_dtype = eng._kv_dtype
         self.pools, self.page_table, self.rung = pools, page_table, rung
-        self.phys, self.off = phys, off
+        self.phys, self.off, self.walk = phys, off, walk
 
     def attend(self, q, k_new, v_new, pos, n_rep, scale):
-        """(out, K rows, V rows): :func:`_attend_view` on the rung's
-        branch, and the new rows in the pool's dtype. Over an int8 pair the
-        rows are quantised into their pages FIRST, outside the switch (the
-        donated pool is never carried through a branch), so that the
-        attention reads the bytes the next step will read
-        (:func:`_attend_view_int8`), and the updated pairs come back in
+        """(out, K rows, V rows), the new rows in the pool's dtype. A decode
+        step (one query row a head) over plain pools whose rows are whole
+        lane tiles IS the kernel that walks each slot's pages in place
+        (ops/kernels/paged_gqa_attention.py; no view, no rung), at every
+        extent: on the chip it beat the gathered view at chat's lengths as
+        at docqa's (PERF.md section 6, PR 35). A W-wide call, a call with no
+        ``walk`` and a narrower head keep :func:`_attend_view` on the rung's
+        branch. Over an int8 pair the rows are quantised into their pages
+        FIRST, outside the switch (the donated pool is never carried through
+        a branch), so that the attention reads the bytes the next step will
+        read (:func:`_attend_view_int8`), and the updated pairs come back in
         place of rows."""
         kp, vp = self.pools
         if isinstance(kp, tuple):
@@ -402,11 +427,16 @@ class _PagedView:
                                self.page_size, self.kv_dtype, n_rep, scale),
                 q, kq, ksc, vq, vsc, self.page_table, pos)
             return (out, (kq, ksc), (vq, vsc))
-        out = jax.lax.switch(
-            self.rung,
-            _view_branches(_attend_view, self.ladder, self.page_size, n_rep,
-                           scale),
-            q, k_new, v_new, kp, vp, self.page_table, pos)
+        if self.walk is not None and q.shape[1] == 1 and reads_in_place(kp):
+            out = paged_gqa_attention(
+                q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp, self.page_table,
+                self.walk, scale=scale)[:, None]
+        else:
+            out = jax.lax.switch(
+                self.rung,
+                _view_branches(_attend_view, self.ladder, self.page_size,
+                               n_rep, scale),
+                q, k_new, v_new, kp, vp, self.page_table, pos)
         return (out, k_new.astype(kp.dtype), v_new.astype(vp.dtype))
 
     def attend_latent(self, block, q_abs, q_rope, c_new, r_new, pos, scale):
@@ -647,6 +677,12 @@ class BatchDecodeEngine:
                 tuple(jnp.zeros((self.S, self.L, *p.row), dtype)
                       for p in layer)
                 for layer in self.cache_spec]
+        # whether a decode step's K/V attention reads its pages in place
+        # (:meth:`_PagedView.attend`): the decode program then hands the view
+        # its walk lengths and reports each slot's walked pages
+        self._walks_pairs = (
+            kv_layout == "paged" and not self._latent and self.plan is None
+            and all(reads_in_place(p) for p in self.caches[0]))
         if self.plan is not None:
             # commit the pools (kv heads on "mp") and every host-rebuilt
             # array (replicated): deterministic placements, so the jitted
@@ -907,10 +943,11 @@ class BatchDecodeEngine:
                 for layer, specs in zip(pools, self.cache_spec)]
 
     def _walk_pages_column(self, lens, active, span: int):
-        """:meth:`_view_pages_column` of a latent row's decode call, whose
-        kernel walks each slot's own pages and consults no rung: per slot
-        the pages its walk copies by the call's last step (those of its
-        table row that hold a key), 0 for an inactive slot."""
+        """:meth:`_view_pages_column` of a decode call whose attention is a
+        kernel that walks each slot's own pages and consults no rung (a
+        latent row's, a plain K/V pair's): per slot the pages its walk
+        copies by the call's last step (those of its table row that hold a
+        key), 0 for an inactive slot."""
         pages = pages_walked(lens + span, self.page_size, self.P)
         return jnp.where(active, pages, 0).astype(jnp.int32)[:, None]
 
@@ -940,7 +977,7 @@ class BatchDecodeEngine:
         st["moe_layer_steps"] += int(v[E + 3])
 
     def _forward_paged(self, params, toks, pools, page_table, lens, rung,
-                       tap=None):
+                       tap=None, walk=None):
         """One forward over ``toks [S, W]`` at per-slot positions
         ``lens..lens+W-1`` through the page table: each layer is handed
         its pools behind the table (a :class:`_PagedView`, whatever rows
@@ -948,7 +985,9 @@ class BatchDecodeEngine:
         pool (an int8 pair comes back whole, the rows quantised into it:
         :meth:`_PagedView.stored` keeps either); ``tap`` (a
         ``parallel.moe.PickTap``) collects the picks of the expert shares
-        the layers run. Each attention gathers its
+        the layers run; ``walk`` (``where(active, lens, 0)``, from the
+        decode program alone) lets a decode step's attention read its pages
+        in place (:class:`_PagedView`). Without it each attention gathers its
         logical K/V view (the page table IS the gather index), runs the
         unchanged ragged-attention math against it, and scatters all W
         newly written positions back to their physical pages. The view is
@@ -984,7 +1023,7 @@ class BatchDecodeEngine:
             with tap if tap is not None else contextlib.nullcontext():
                 for layer, layer_pools in zip(mdl.layers, pools):
                     view = _PagedView(self, layer_pools, page_table, rung,
-                                      phys, off)
+                                      phys, off, walk)
                     x, kept = layer(x, cos, sin, None, pos=lens, cache=view)
                     # the write to the physical pool stays outside the
                     # switch: the donated pool is updated in place, never
@@ -1168,8 +1207,9 @@ class BatchDecodeEngine:
         int32 host-sync payload: [slots, n_steps+1] (emitted tokens, -1
         where idle, then the active flag), and [slots, n_steps+2] in the
         paged layout, whose last column is the pages of the K/V view that
-        every step of the call gathered (with a latent row, the pages each
-        slot's walk copies: :meth:`_walk_pages_column`). A factory so the
+        every step of the call gathered (where a kernel reads the pages in
+        place, those each slot's walk copies: :meth:`_walk_pages_column`). A
+        factory so the
         perf plane can lower an ``n_steps=1`` variant for cost capture —
         XLA's cost analysis counts a scan body ONCE regardless of trip
         count, so the chunk program's own count would under-report by
@@ -1179,6 +1219,7 @@ class BatchDecodeEngine:
 
         paged = self.kv_layout == "paged"
         latent = paged and self._latent
+        walks = self._walks_pairs
 
         def step(caches, tokens, lens, active, temps, budgets, top_ks,
                  eos_ids, key, params, page_table, rung):
@@ -1187,7 +1228,8 @@ class BatchDecodeEngine:
                 tap = PickTap()
                 logits, caches = self._forward_paged(
                     params, tokens[:, None], caches, page_table, lens, rung,
-                    tap=tap)
+                    tap=tap,
+                    walk=jnp.where(active, lens, 0) if walks else None)
                 picks = tap.counts(mask=active)
                 if picks is not None:
                     # ... and the expert-layer calls of a step with a live slot
@@ -1232,7 +1274,8 @@ class BatchDecodeEngine:
             cols = [out.T, active_[:, None].astype(jnp.int32)]
             if paged:
                 cols.append(self._walk_pages_column(lens, active, n_steps)
-                            if latent else self._view_pages_column(rung))
+                            if latent or walks
+                            else self._view_pages_column(rung))
             if picks is not None:
                 cols.append(self._pick_columns(picks.sum(0)))
             packed = jnp.concatenate(cols, axis=1)  # [slots, n_steps+1(+1)]
@@ -1888,8 +1931,8 @@ class BatchDecodeEngine:
         """Once per decode call: the pages of the table its steps read,
         beside the whole table's. ``column`` is the program's report, one
         number a slot: the rung's page count in every row where a view was
-        gathered, each slot's own walk where the latent kernel ran; their
-        mean over the slots, rounded up, is what is counted."""
+        gathered, each slot's own walk where a kernel read the pages in
+        place; their mean over the slots, rounded up, is what is counted."""
         self.stats["decode_view_pages"] += -(-int(column.sum()) // self.S)
         self.stats["decode_table_pages"] += self.P
 

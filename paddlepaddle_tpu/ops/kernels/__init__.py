@@ -6,10 +6,11 @@ fused_rms_norm, fused_bias_act …). The flash kernels have an XLA reference
 path used on CPU (tests run on a virtual CPU mesh) and when
 FLAGS_use_pallas_kernels=0.
 
-gather_gemm.py (the MoE dispatch of ``MoELayer(dispatch_mode="fused")``)
-and paged_latent_attention.py (a latent cache row's decode attention, behind
-the serving engine's paged view: docs/kernels.md) additionally run in Pallas
-INTERPRET mode on CPU, so parity is test-pinned in the tier-1 environment.
+gather_gemm.py (the MoE dispatch of ``MoELayer(dispatch_mode="fused")``),
+paged_latent_attention.py and paged_gqa_attention.py (a decode step's
+attention over a latent cache row and over a K/V pair, behind the serving
+engine's paged view: docs/kernels.md) additionally run in Pallas INTERPRET
+mode on CPU, so parity is test-pinned in the tier-1 environment.
 
 Every kernel here is compiled by Mosaic and compared with its
 ``jax.numpy`` reference by ``chip_smoke.py``'s kernels leg on the chip;

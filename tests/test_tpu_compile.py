@@ -6,6 +6,8 @@ may load the TPU's library; every xdist worker imports this file), and every
 such compile lives in this one file. Nothing runs: no number here is a time.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -48,3 +50,22 @@ def test_the_latent_decode_kernel_compiles_for_v5e_at_the_agent_cells_shape(one_
     wide_pool = f"bf16[{PAGES},{PS},128]"
     pads = [l for l in text.splitlines() if " pad(" in l and l.split("=")[1].lstrip().startswith(wide_pool)]
     assert len(pads) == pool_pads
+
+
+def test_the_gqa_decode_kernel_compiles_for_v5e_at_the_docqa_cells_shape(one_chip):
+    """serve-docqa-steady's and serve-chat-saturated's decode attention: 32 slots, 32 query over 8 kv heads of 128,
+    a 64-page table over 1,152 pages of 64 tokens, bf16. The pools enter as the engine holds them: seeing a page as
+    ``[page_size * kvh, hd]`` has to be free (a bitcast), or every call would copy 302 MB."""
+    from paddlepaddle_tpu.ops.kernels.paged_gqa_attention import paged_gqa_attention
+
+    slots, heads, kvh, hd, ps, table, pages = 32, 32, 8, 128, 64, 64, 1152
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (shape((slots, heads, hd)), shape((slots, kvh, hd)), shape((slots, kvh, hd)),
+            shape((pages, ps, kvh, hd)), shape((pages, ps, kvh, hd)), shape((slots, table), jnp.int32),
+            shape((slots,), jnp.int32))
+    fn = lambda *a: paged_gqa_attention(*a, scale=hd ** -0.5, interpret=False)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the pools reach the call as they are: no operation of the program has a result of a pool's size
+    moved = re.findall(rf"= bf16\[{pages},[^ ]* (?!bitcast|parameter)\w+\(", text)
+    assert not moved, moved
