@@ -82,6 +82,7 @@ class Size:
     moe: Dict[str, int]               # experts, d, h, tokens, topk, capacity
     latent: Dict[str, int]            # slots, page, heads, rank, rope, pages_per_slot
     gqa: Dict[str, int]               # slots, page, heads, kv_heads, head_dim, pages_per_slot
+    prefill: Dict[str, int]           # heads, nope, rope, v, rank, queries, start
 
 
 # bench.py's primary config (254M) and the serving/kernel shapes it implies
@@ -106,6 +107,11 @@ FULL = Size(
     # slots, 32 query over 8 kv heads of 128, the same table
     gqa=dict(slots=32, page=64, heads=32, kv_heads=8, head_dim=128,
              pages_per_slot=64),
+    # serve-longdoc-saturated's whole-prompt attention at a tenth of its
+    # length: 64 heads, 128 + 64 wide scores, 128-wide values, 1,500
+    # queries behind 1,000 cached rows (blocks of 512 with both ragged ends)
+    prefill=dict(heads=64, nope=128, rope=64, v=128, rank=512, queries=1500,
+                 start=1000),
 )
 
 # the tier-1 width: same code, seconds on the CPU
@@ -122,6 +128,7 @@ TINY = Size(
     latent=dict(slots=3, page=8, heads=4, rank=16, rope=8, pages_per_slot=4),
     gqa=dict(slots=4, page=8, heads=4, kv_heads=2, head_dim=128,
              pages_per_slot=4),
+    prefill=dict(heads=4, nope=8, rope=8, v=8, rank=16, queries=21, start=9),
 )
 
 
@@ -539,6 +546,39 @@ def _kernel_paged_latent(size: Size, interpret: bool) -> Dict[str, object]:
     return row
 
 
+def _kernel_latent_prefill(size: Size, interpret: bool) -> Dict[str, object]:
+    """The long prefill's attention kernel against the one-piece expanded
+    form it takes over from beyond the bound on scores
+    (``latent_attention._expanded_attention``, in float32): queries behind
+    a cached prefix, lengths that are no multiple of the block."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddlepaddle_tpu.models import latent_attention as la
+
+    p = size.prefill
+    H, nope, rope, vd, rank, s, start = (p["heads"], p["nope"], p["rope"],
+                                         p["v"], p["rank"], p["queries"],
+                                         p["start"])
+    L = start + s
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(8), 5)
+    q_nope = jax.random.normal(ks[0], (1, s, H, nope), bf)
+    q_rope = jax.random.normal(ks[1], (1, s, H, rope), bf)
+    c = jax.random.normal(ks[2], (1, L, rank), bf)
+    k_rope = jax.random.normal(ks[3], (1, L, rope), bf)
+    w = jax.random.normal(ks[4], (rank, H * (nope + vd)), bf) * rank ** -0.5
+    pos = start + jnp.arange(s, dtype=jnp.int32)[None]
+    scale = (nope + rope) ** -0.5
+    got = _mosaic("latent_prefill_attention", lambda *a: la._long_attention(
+        *a, pos, nope, scale), interpret)(q_nope, q_rope, c, k_rope, w)
+    want = jax.jit(lambda *a: la._expanded_attention(*a, pos, nope, scale))(
+        *_f32(q_nope, q_rope, c, k_rope, w))
+    row = _compare("latent_prefill_attention", got, want, interpret)
+    row["queries"], row["keys"] = s, L
+    return row
+
+
 def _kernel_paged_gqa(size: Size, interpret: bool) -> Dict[str, object]:
     """The GQA decode kernel against the gathered view it takes the place of
     (``decode_engine._attend_view`` over the whole table, in float32), on
@@ -623,6 +663,7 @@ def kernels_leg(size: Size, interpret: bool) -> Dict[str, object]:
              "flash_varlen": _kernel_varlen(size, interpret)}
     table["paged_latent_attention"] = _kernel_paged_latent(size, interpret)
     table["paged_gqa_attention"] = _kernel_paged_gqa(size, interpret)
+    table["latent_prefill_attention"] = _kernel_latent_prefill(size, interpret)
     table["gather_gemm"] = _kernel_gather_gemm(size, interpret)
     return {"status": "ok", "tolerance": f"{KERNEL_TOL} * max|reference|",
             "table": table}

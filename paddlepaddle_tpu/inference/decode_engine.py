@@ -85,6 +85,12 @@ from .robustness import safe_set as _safe_set
 # loop adds serve.sweep, serve.wait_request and serve.admit around them)
 CHUNK_PHASES = ("serve.decode_dispatch", "serve.first_sync",
                 "serve.chunk_sync", "serve.deliver")
+# what admissions did, by kind, cumulative in ``stats``: calls and the prompt
+# tokens they COMPUTED (a whole prompt; the tail behind a cached prefix), and
+# the tokens prefix hits took from the cache instead
+ADMIT_COUNTERS = ("admit_n.whole", "admit_n.prefix_hit",
+                  "admit_tokens_computed.whole",
+                  "admit_tokens_computed.prefix_hit", "admit_tokens_cached")
 
 
 def _bucket(n: int, q: int = 128) -> int:
@@ -717,6 +723,7 @@ class BatchDecodeEngine:
                       "peak_busy": 0, "turnaround_s": 0.0,
                       "turnaround_n": 0, "decode_view_pages": 0,
                       "decode_table_pages": 0,
+                      **dict.fromkeys(ADMIT_COUNTERS, 0),
                       **phase_counters(CHUNK_PHASES)}
         # expert shares (parallel.moe.ExpertShareLayer) count their picks in
         # the decode program; the counters ride its one packed payload
@@ -725,7 +732,7 @@ class BatchDecodeEngine:
         self._experts_held = shares[0].count if shares else 0
         picks = {} if not shares else dict(
             moe_picks_zero=0, moe_picks_held=0, moe_picks_absent=0,
-            moe_experts_touched=0, moe_layer_steps=0,
+            moe_picks_total=0, moe_experts_touched=0, moe_layer_steps=0,
             moe_experts_held=self._experts_held,
             moe_expert_pairs=(0,) * self._experts_held)
         self.stats.update(picks)
@@ -973,6 +980,7 @@ class BatchDecodeEngine:
         st["moe_picks_held"] += int(v[:E].sum())
         st["moe_picks_zero"] += int(v[E])
         st["moe_picks_absent"] += int(v[E + 1])
+        st["moe_picks_total"] += int(v[:E + 2].sum())
         st["moe_experts_touched"] += int(v[E + 2])
         st["moe_layer_steps"] += int(v[E + 3])
 
@@ -1915,6 +1923,10 @@ class BatchDecodeEngine:
                        **({"prefix_hit": entry is not None} if h else {}))
         self._first_pending[slot] = first   # device scalar, synced at collect
         self.stats["requests"] += 1
+        kind = "whole" if entry is None else "prefix_hit"
+        self.stats["admit_n." + kind] += 1
+        self.stats["admit_tokens_computed." + kind] += prog_plen
+        self.stats["admit_tokens_cached"] += plen - prog_plen
         return True
 
     def _close_turnaround(self) -> None:

@@ -10,6 +10,8 @@ define-by-run code runs eagerly and traces to one XLA program via
 * moe    — Mixtral/DeepSeekMoE-style expert-parallel LM (config 5)
 * longcat_flash — latent attention (MLA), zero-compute experts and the
   shortcut-connected double layer; served with one chip's share of the experts
+* kimi_k2 — the same latent block (latent_attention.py), a dense leading layer,
+  sigmoid-routed experts with a shared expert, YaRN positions; served likewise
 """
 
 from .bert import (  # noqa: F401
@@ -22,6 +24,10 @@ from .gpt import (  # noqa: F401
     GPTForCausalLM,
     GPTModel,
     gpt_sharding_rules,
+)
+from .kimi_k2 import (  # noqa: F401
+    KimiK2Config,
+    KimiK2ForCausalLM,
 )
 from .llama import (  # noqa: F401
     LlamaConfig,

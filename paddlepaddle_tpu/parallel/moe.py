@@ -556,6 +556,19 @@ def _dropless_moe_ffn(x, logits, wg, wu, wd, topk, align=1):
 # and needs no sort. Above it the sorted, grouped form does the work of the
 # pairs that exist.
 GROUPED_ABOVE_TOKENS = 512
+# Up to this many tokens the grouped form sorts and runs EVERY pair in one call
+# of each matmul, held or not; beyond it the held pairs alone run, a block of
+# sorted rows at a time (:func:`_held_experts_row_blocks`): the one-call form
+# gathers a row and writes a float32 output row for every pair (1.9 GB and 3.8
+# GB at a 16,640-token prefill with 8 picks), and ``lax.ragged_dot`` on the TPU
+# costs by the rows it is given, not by the rows that lie in a group.
+GROUPED_ALL_PAIRS_TOKENS = 2048
+# Sorted pairs a block of the row-block form: a held expert sees some hundreds
+# of pairs of a long prefill, so a block spans a few experts' weights (read on
+# a TPU v5 lite at 12 held of 384 experts, 8 picks, 7,168 x 2,048, ms a layer
+# at 512 / 1,024 / 2,048 rows: 8,320 tokens 8.1 / 8.5 / 19.8, 16,640 tokens
+# 11.9 / 12.3 / 13.1; every pair in token chunks of 2,048: 39.3 and 133.7).
+GROUPED_BLOCK_ROWS = 512
 
 
 def route_scores_topk(x, router, bias, topk, scale):
@@ -568,6 +581,26 @@ def route_scores_topk(x, router, bias, topk, scale):
     p = jax.nn.softmax(logits, axis=-1)
     _, ids = jax.lax.top_k(p + bias.astype(jnp.float32), topk)
     return scale * jnp.take_along_axis(p, ids, axis=-1), ids.astype(jnp.int32)
+
+
+def route_sigmoid_topk(x, router, bias, topk, scale):
+    """Sigmoid routing with renormalisation (DeepSeek-V3's, Kimi-K2's):
+    ``s = sigmoid(f32(x) @ router)`` over every output of the router, the
+    ``topk`` largest of ``s + bias`` are picked, and a pick weighs
+    ``scale * s_i / (sum of the picked s + 1e-20)``: the bias chooses, it does
+    not weigh. The published group limit (``n_group``, ``topk_group``) is the
+    identity at one group, which is all that is computed here. Returns as
+    :func:`route_scores_topk`."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), topk)
+    picked = jnp.take_along_axis(s, ids, axis=-1)
+    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    return weights, ids.astype(jnp.int32)
+
+
+ROUTINGS = {"softmax": route_scores_topk, "sigmoid": route_sigmoid_topk}
 
 
 def _held_experts_dense(x, w_local, wg, wu, wd):
@@ -601,19 +634,56 @@ def _held_experts_grouped(x, weights, local, count, wg, wu, wd):
     return (out[dest] * w_pair[:, None]).reshape(k, T, -1).sum(0)
 
 
+def _held_experts_row_blocks(x, weights, local, count, wg, wu, wd):
+    """The pairs of (token, held expert) sorted by expert as in
+    :func:`_held_experts_grouped`, but run ``GROUPED_BLOCK_ROWS`` sorted rows
+    at a time and only as many blocks as hold a held pair (they sort first):
+    the work and the memory follow the pairs this chip holds, not all that
+    were routed, and no pair is dropped however many there are. A block's
+    results are weighed and added to their tokens' rows."""
+    T, k = local.shape
+    R = GROUPED_BLOCK_ROWS
+    fe = local.T.reshape(-1)                       # round-major, as _counting_sort's users
+    _, sidx, counts, _ = _counting_sort(fe, count + 1)
+    ends = jnp.cumsum(counts[:count])              # where each held expert's run ends
+    starts = ends - counts[:count]
+    total = ends[-1]
+    sidx = jnp.pad(sidx, (0, -sidx.shape[0] % R))  # a padded slot lies past ``total``
+    w_pair = weights.T.reshape(-1)
+
+    def block(i, acc):
+        lo = i * R
+        pairs = jax.lax.dynamic_slice(sidx, (lo,), (R,))
+        tok = pairs % T
+        xin = x[tok]
+        sizes = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
+        mid = (jax.nn.silu(jax.lax.ragged_dot(xin, wg, sizes,
+                                              preferred_element_type=jnp.float32))
+               * jax.lax.ragged_dot(xin, wu, sizes, preferred_element_type=jnp.float32))
+        out = jax.lax.ragged_dot(mid.astype(x.dtype), wd, sizes,
+                                 preferred_element_type=jnp.float32)
+        live = lo + jnp.arange(R, dtype=jnp.int32) < total
+        out = jnp.where(live[:, None], out * w_pair[pairs][:, None], 0.0)
+        return acc.at[tok].add(out)
+
+    return jax.lax.fori_loop(0, (total + R - 1) // R, block,
+                             jnp.zeros((T, x.shape[1]), jnp.float32))
+
+
 def expert_share_ffn(x, router, bias, wg, wu, wd, *, topk, scale, num_routed,
-                     first):
+                     first, routing="softmax"):
     """What ONE chip adds to an expert layer's result for tokens ``x [T, d]``:
     it holds routed experts ``first .. first + count`` (``count`` is the
     leading size of the stacked weights) of ``num_routed``; router outputs
     past ``num_routed`` are zero-compute experts, the identity. The router
-    keeps its whole width and its ``topk`` picks. Returns the partial sum
+    keeps its whole width and its ``topk`` picks, scored as ``routing`` names
+    (:data:`ROUTINGS`). Returns the partial sum
     ``sum over held picks of w * E(x) + x * sum of the picked identity
     weights`` in float32, and ``picks [T, topk]``: the held expert's local
     index, ``count`` for an identity pick, ``count + 1`` for an expert that
     lives on another chip (its part is left out)."""
     count = wg.shape[0]
-    weights, ids = route_scores_topk(x, router, bias, topk, scale)
+    weights, ids = ROUTINGS[routing](x, router, bias, topk, scale)
     local = ids - first
     is_held = (local >= 0) & (local < count)
     is_zero = ids >= num_routed
@@ -623,8 +693,13 @@ def expert_share_ffn(x, router, bias, wg, wu, wd, *, topk, scale, num_routed,
                           * jax.nn.one_hot(picks, count, dtype=jnp.float32), axis=1)
         y = _held_experts_dense(x, w_local, wg, wu, wd)
     else:
-        y = _held_experts_grouped(x, weights, jnp.where(is_held, local, count),
-                                  count, wg, wu, wd)
+        grouped = (_held_experts_grouped
+                   if x.shape[0] <= GROUPED_ALL_PAIRS_TOKENS
+                   else _held_experts_row_blocks)
+        y = grouped(x, weights, jnp.where(is_held, local, count), count, wg,
+                    wu, wd)
+    if router.shape[1] == num_routed:                # no identity experts
+        return y, picks
     w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1, keepdims=True)
     return y + x.astype(jnp.float32) * w_zero, picks
 
@@ -673,21 +748,29 @@ def record_picks(picks, count):
 
 
 class ExpertShareLayer(Layer):
-    """One chip's share of a sparse expert layer with zero-compute experts.
+    """One chip's share of a sparse expert layer.
 
     The layer is TOLD which experts it holds: ``held = (first, count)`` of
     ``num_routed`` routed experts (SwiGLU, stacked ``[count, ...]``), beside
-    ``num_zero`` identity experts that cost nothing and belong to the token's
-    own chip. Routing is at the full width ``num_routed + num_zero`` by
-    softmax scores plus a correction bias, ``topk`` picks weighing
-    ``scaling * p`` with no renormalisation. ``forward`` returns the partial
-    sum this chip adds (what absent experts would add is left out: there is
-    no exchange here, and nothing stands in for it) and the picks
-    (:func:`expert_share_ffn`). Held experts run droplessly at static shapes;
-    the formulation follows from the token count alone."""
+    ``num_zero`` identity experts (zero-compute: they cost nothing and belong
+    to the token's own chip; 0 where the model has none). Routing is at the
+    full width ``num_routed + num_zero``, ``topk`` picks of the scores plus a
+    correction bias that chooses and does not weigh, and the model states
+    which scores: ``routing="softmax"`` weighs a pick ``scaling * p`` with no
+    renormalisation (LongCat-Flash), ``routing="sigmoid"`` weighs it
+    ``scaling * s_i / sum of the picked s`` (Kimi-K2, DeepSeek-V3;
+    :func:`route_sigmoid_topk`). ``shared_hidden`` > 0 adds a SHARED expert,
+    a SwiGLU of that width that every token passes on every chip
+    (``shared_gate_proj``, ``shared_up_proj``, ``shared_down_proj``).
+    ``forward`` returns the partial sum this chip adds: its held experts' part,
+    the identity part and the shared expert's (what absent experts would add
+    is left out: there is no exchange here, and nothing stands in for it), and
+    the picks (:func:`expert_share_ffn`). Held experts run droplessly at static
+    shapes; the formulation follows from the token count alone."""
 
     def __init__(self, d_model, d_hidden, num_routed, num_zero, topk,
-                 held=None, scaling=1.0, dtype="float32", init_std=0.02):
+                 held=None, scaling=1.0, dtype="float32", init_std=0.02,
+                 routing="softmax", shared_hidden=0):
         super().__init__(dtype=dtype)
         from ..nn.initializer import Constant, Normal
 
@@ -695,8 +778,11 @@ class ExpertShareLayer(Layer):
         if not (0 <= first and count >= 1 and first + count <= num_routed):
             raise ValueError(f"held={held!r} does not lie inside the "
                              f"{num_routed} routed experts")
+        if routing not in ROUTINGS:
+            raise ValueError(f"routing={routing!r}: one of {sorted(ROUTINGS)}")
         self.num_routed, self.num_zero, self.topk = num_routed, num_zero, topk
         self.first, self.count, self.scaling = first, count, float(scaling)
+        self.routing, self.shared_hidden = routing, int(shared_hidden)
         normal = Normal(0.0, init_std)
         self.router = self.create_parameter(
             [d_model, num_routed + num_zero], default_initializer=normal)
@@ -709,19 +795,32 @@ class ExpertShareLayer(Layer):
             [count, d_model, d_hidden], default_initializer=normal)
         self.down_proj = self.create_parameter(
             [count, d_hidden, d_model], default_initializer=normal)
+        if self.shared_hidden:
+            self.shared_gate_proj = self.create_parameter(
+                [d_model, self.shared_hidden], default_initializer=normal)
+            self.shared_up_proj = self.create_parameter(
+                [d_model, self.shared_hidden], default_initializer=normal)
+            self.shared_down_proj = self.create_parameter(
+                [self.shared_hidden, d_model], default_initializer=normal)
 
     def forward(self, x):
-        shape = x.shape
-
-        def f(a, r, b, wg, wu, wd):
+        def f(a, r, b, wg, wu, wd, *shared):
+            flat = a.reshape(-1, a.shape[-1])
             y, picks = expert_share_ffn(
-                a.reshape(-1, a.shape[-1]), r, b, wg, wu, wd, topk=self.topk,
-                scale=self.scaling, num_routed=self.num_routed, first=self.first)
+                flat, r, b, wg, wu, wd, topk=self.topk, scale=self.scaling,
+                num_routed=self.num_routed, first=self.first,
+                routing=self.routing)
+            if shared:
+                sg, su, sd = shared
+                mid = jax.nn.silu(jnp.matmul(flat, sg)) * jnp.matmul(flat, su)
+                y = y + jnp.matmul(mid, sd, preferred_element_type=jnp.float32)
             record_picks(picks, self.count)
             return y.astype(a.dtype).reshape(a.shape), picks
 
+        shared = ((self.shared_gate_proj, self.shared_up_proj,
+                   self.shared_down_proj) if self.shared_hidden else ())
         return apply_op(f, x, self.router, self.e_score_correction_bias,
-                        self.gate_proj, self.up_proj, self.down_proj,
+                        self.gate_proj, self.up_proj, self.down_proj, *shared,
                         op_name="expert_share_ffn")
 
 

@@ -84,3 +84,27 @@ def needs_bundles(can_serialize_executables):
         pytest.skip("this jaxlib's CPU client cannot serialize an executable "
                     "that has run a full-width top_k (UNIMPLEMENTED), so a "
                     "served engine has no bundle to save")
+
+
+# tests/benchmark/test_window_and_traffic.py (PR 27) holds EVERY traffic file to
+# prompt + output <= 4,096, the `max_len` every serving configuration had until
+# PR 36. `longdoc-saturated.json` (ISSUE 36: 8k-16k documents under a `max_len`
+# of 16,896) cannot meet that line, and a file under BENCHMARK.json's `paths` is
+# only a `benchmark` PR's to edit (it should read the cap from the cell's
+# configuration). Until then that ONE parametrised case is expected to fail, and
+# STRICTLY: it has to fail, with an AssertionError (the cap is its last line and
+# the only one this file can fail), or the run fails; the same invariants (the
+# schedule fixed by the file, a longer horizon only appending, every request
+# inside the configuration's own `max_len`) are held by
+# tests/benchmark/test_kimi_family.py against 16,896.
+_STALE_CAP = ("test_window_and_traffic.py::test_traffic_file_fixes_work_and_schedule"
+              "[longdoc-saturated.json]")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(_STALE_CAP):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="a 4,096-token cap written for PR 27's configurations; "
+                       "this cell's is 16,896 (see the comment above)"))

@@ -43,7 +43,7 @@ def test_smoke_legs_tiny_on_cpu():
     table = legs["kernels"]["table"]
     assert set(table) == {
         "flash", "flash_varlen", "gather_gemm", "paged_latent_attention",
-        "paged_gqa_attention"}
+        "paged_gqa_attention", "latent_prefill_attention"}
     assert all(r["mode"] == "interpret" for r in table.values())
     # conftest's 8 virtual CPU devices stand in for the four chips: the
     # sharding evidence is real, the allocator evidence is chip-only

@@ -69,3 +69,21 @@ def test_the_gqa_decode_kernel_compiles_for_v5e_at_the_docqa_cells_shape(one_chi
     # the pools reach the call as they are: no operation of the program has a result of a pool's size
     moved = re.findall(rf"= bf16\[{pages},[^ ]* (?!bitcast|parameter)\w+\(", text)
     assert not moved, moved
+
+
+@pytest.mark.parametrize("queries, keys", [(16640, 16640), (8320, 8320)])
+def test_the_latent_prefill_kernel_compiles_for_v5e_at_the_longdoc_cells_shapes(one_chip, queries, keys):
+    """serve-longdoc-saturated's whole-prompt admissions, the longest and the shortest: 64 heads, 128 + 64 wide queries
+    and keys against 128-wide values, bf16, a head's keys and values resident (12.8 MB at 16,640, double-buffered, past
+    the default scope of fast memory). One Mosaic call, and its result is the shape ``latent_prefill_roofline`` reads."""
+    from paddlepaddle_tpu.ops.kernels.latent_prefill_attention import latent_prefill_attention
+
+    bf = jnp.bfloat16
+    shape = lambda s, dt=bf: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (shape((1, queries, 64, 128)), shape((1, queries, 64, 64)), shape((1, keys, 64, 128)), shape((1, keys, 64)),
+            shape((1, keys, 64, 128)), shape((1,), jnp.int32))
+    fn = lambda *a: latent_prefill_attention(*a, scale=0.1309, interpret=False)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    rows = -(-queries // 512) * 512
+    assert re.search(rf'= bf16\[64,{rows},128\]\S* custom-call\(.*custom_call_target="tpu_custom_call"', text)
