@@ -91,6 +91,11 @@ CHUNK_PHASES = ("serve.decode_dispatch", "serve.first_sync",
 ADMIT_COUNTERS = ("admit_n.whole", "admit_n.prefix_hit",
                   "admit_tokens_computed.whole",
                   "admit_tokens_computed.prefix_hit", "admit_tokens_cached")
+# decode calls by the sampler branch their admitted requests ask for
+# (BatchDecodeEngine._sample), cumulative in ``stats``: they sum to
+# ``decode_calls``
+SAMPLE_COUNTERS = ("sample_calls.greedy", "sample_calls.draw",
+                   "sample_calls.filter")
 
 
 def _bucket(n: int, q: int = 128) -> int:
@@ -480,12 +485,15 @@ class _PagedView:
 
 
 class _Slot:
-    __slots__ = ("req", "emitted", "budget", "spec_steps", "spec_accepted")
+    __slots__ = ("req", "emitted", "budget", "temp", "top_k", "spec_steps",
+                 "spec_accepted")
 
-    def __init__(self, req=None, budget=0):
+    def __init__(self, req=None, budget=0, temp=0.0, top_k=0):
         self.req = req
         self.emitted: List[int] = []
         self.budget = budget
+        self.temp = temp          # what the request was admitted with
+        self.top_k = top_k
         self.spec_steps = 0       # speculative verify steps this request saw
         self.spec_accepted = 0    # draft tokens the verifier accepted for it
 
@@ -724,6 +732,7 @@ class BatchDecodeEngine:
                       "turnaround_n": 0, "decode_view_pages": 0,
                       "decode_table_pages": 0,
                       **dict.fromkeys(ADMIT_COUNTERS, 0),
+                      **dict.fromkeys(SAMPLE_COUNTERS, 0),
                       **phase_counters(CHUNK_PHASES)}
         # expert shares (parallel.moe.ExpertShareLayer) count their picks in
         # the decode program; the counters ride its one packed payload
@@ -1044,21 +1053,55 @@ class BatchDecodeEngine:
                 logits = unwrap(self.model.lm_head(hidden))
         return logits, new_pools
 
-    TOP_K_CAP = 128  # static bound for the in-graph per-slot top-k filter
+    # static bound of the in-graph per-slot top-k filter: one lax.top_k of
+    # the cap serves every slot's k, and only calls with a live slot that
+    # both draws and filters pay for it (:meth:`_sample`)
+    TOP_K_CAP = 128
 
-    def _sample(self, rows, temps, top_ks, key):
-        """Per-slot sampling: temp==0 -> greedy, else categorical at temp,
-        optionally restricted to the slot's top_k logits (k <= TOP_K_CAP;
-        one static top_k of the cap serves every slot's k)."""
-        kcap = min(self.TOP_K_CAP, rows.shape[-1])
-        topv = jax.lax.top_k(rows, kcap)[0]               # [slots, kcap] desc
-        kth = jnp.take_along_axis(
-            topv, jnp.clip(top_ks[:, None] - 1, 0, kcap - 1), axis=1)
-        rows = jnp.where((top_ks[:, None] > 0) & (rows < kth), -jnp.inf, rows)
-        greedy = jnp.argmax(rows, axis=-1).astype(jnp.int32)
-        scaled = rows / jnp.maximum(temps[:, None], 1e-6)
-        sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
-        return jnp.where(temps <= 0.0, greedy, sampled)
+    @classmethod
+    def _sample(cls, rows, temps, top_ks, key, live):
+        """Per-slot sampling of ``rows`` (``[slots, vocab]`` logits, read as
+        float32): temp==0 -> greedy, else categorical at temp, optionally
+        restricted to the slot's top_k logits (k <= TOP_K_CAP).
+        The call runs only what its ``live`` slots ask for, chosen in the
+        program from its own arguments: all greedy -> the argmax alone; a
+        slot draws and none of those filters -> the draw without the
+        top_k; else the whole body. A slot's token is the same on every
+        branch it may take under the same ``key`` (the filter never
+        removes a row's maximum, and with k == 0 it is the identity); a
+        slot outside ``live`` reads an unspecified token.
+        ``rows`` enter in the head's own dtype and each branch widens them:
+        a float32 operand would let the compiler fuse the widening into the
+        head's matmul and drop its rounding, and tokens would no longer be
+        those of the rounded logits."""
+        draws = live & (temps > 0.0)
+        branch = (jnp.any(draws).astype(jnp.int32)
+                  + jnp.any(draws & (top_ks > 0)).astype(jnp.int32))
+
+        def greedy(rows, temps, top_ks, key):
+            return jnp.argmax(rows, axis=-1).astype(jnp.int32)
+
+        def draw(rows, temps, top_ks, key):
+            scaled = rows / jnp.maximum(temps[:, None], 1e-6)
+            sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
+            return jnp.where(temps <= 0.0, greedy(rows, temps, top_ks, key),
+                             sampled)
+
+        def filtered(rows, temps, top_ks, key):
+            kcap = min(cls.TOP_K_CAP, rows.shape[-1])
+            topv = jax.lax.top_k(rows, kcap)[0]           # [slots, kcap] desc
+            kth = jnp.take_along_axis(
+                topv, jnp.clip(top_ks[:, None] - 1, 0, kcap - 1), axis=1)
+            rows = jnp.where((top_ks[:, None] > 0) & (rows < kth), -jnp.inf,
+                             rows)
+            return draw(rows, temps, top_ks, key)
+
+        def widened(fn):
+            return lambda rows, *a: fn(rows.astype(jnp.float32), *a)
+
+        return jax.lax.switch(
+            branch, [widened(f) for f in (greedy, draw, filtered)], rows,
+            temps, top_ks, key)
 
     def _set_slot_state(self, caches, lens, tokens, active, temps, eos_ids,
                         budgets, top_ks, key, slot, plen, temp, eos, budget,
@@ -1093,9 +1136,10 @@ class BatchDecodeEngine:
         bucket = ids.shape[1]
         scratch = self._scratch(bucket, caches[0][0].dtype)
         logits, scratch = self._forward(params, ids, scratch, jnp.int32(0))
-        row = logits[0, plen - 1].astype(jnp.float32)
+        row = logits[0, plen - 1]
         key, sub = jax.random.split(key)
-        first = self._sample(row[None], temp[None], top_k[None], sub)[0]
+        first = self._sample(row[None], temp[None], top_k[None], sub,
+                             True)[0]
         zero = jnp.int32(0)
         out_caches = [
             tuple(jax.lax.dynamic_update_slice(
@@ -1119,9 +1163,10 @@ class BatchDecodeEngine:
         pad = npg * ps - bucket
         scratch = self._scratch(bucket, self._kv_dtype)
         logits, scratch = self._forward(params, ids, scratch, jnp.int32(0))
-        row = logits[0, plen - 1].astype(jnp.float32)
+        row = logits[0, plen - 1]
         key, sub = jax.random.split(key)
-        first = self._sample(row[None], temp[None], top_k[None], sub)[0]
+        first = self._sample(row[None], temp[None], top_k[None], sub,
+                             True)[0]
         dest = jax.lax.dynamic_slice(page_table, (slot, jnp.int32(0)),
                                      (1, npg))[0]
         # positions past the prompt hold prefill activations for the
@@ -1192,9 +1237,10 @@ class BatchDecodeEngine:
                     for c in cached))
             logits, scratch = self._forward(params, ids, scratch,
                                             jnp.int32(aligned))
-            row = logits[0, tail_plen - 1].astype(jnp.float32)
+            row = logits[0, tail_plen - 1]
             key2, sub = jax.random.split(key)
-            first = self._sample(row[None], temp[None], top_k[None], sub)[0]
+            first = self._sample(row[None], temp[None], top_k[None], sub,
+                                 True)[0]
             dest = row_pages[n_pfx:n_pfx + npg_tail]
             valid = (jnp.arange(npg_tail * ps, dtype=jnp.int32)
                      < tail_plen).reshape(npg_tail, ps)[:, :, None, None]
@@ -1247,9 +1293,8 @@ class BatchDecodeEngine:
             else:
                 logits, caches = self._forward(params, tokens[:, None],
                                                caches, lens)
-            rows = logits[:, 0].astype(jnp.float32)
             key, sub = jax.random.split(key)
-            nxt = self._sample(rows, temps, top_ks, sub)
+            nxt = self._sample(logits[:, 0], temps, top_ks, sub, active)
             nxt = jnp.where(active, nxt, tokens)    # frozen when inactive
             lens = lens + active.astype(jnp.int32)
             emitted = jnp.where(active, nxt, -1)    # -1 = no token
@@ -1896,7 +1941,8 @@ class BatchDecodeEngine:
                 self.reset_slots([slot])
                 raise
             self._warmed.add(dkey)
-        self._host_slots[slot] = _Slot(req, budget=int(req.max_new_tokens))
+        self._host_slots[slot] = _Slot(req, budget=int(req.max_new_tokens),
+                                       temp=temp, top_k=top_k)
         self.stats["peak_busy"] = max(self.stats["peak_busy"],
                                       self.busy_slots())
         _stamp(req, "_t_admit")
@@ -1938,6 +1984,18 @@ class BatchDecodeEngine:
             self._t_synced = None
             self.stats["turnaround_s"] += time.perf_counter() - t
             self.stats["turnaround_n"] += 1
+
+    def _count_decode_call(self) -> None:
+        """Once per decode call, at its dispatch: the call itself, and the
+        sampler branch (:meth:`_sample`) that the requests holding a slot
+        ask for, read from what each was admitted with. The program decides
+        for itself, step by step, from the slots still live; this is the
+        host's count of the same choice and costs no sync."""
+        drawing = [s.top_k for s in self._host_slots
+                   if s.req is not None and s.temp > 0.0]
+        branch = 1 + any(k > 0 for k in drawing) if drawing else 0
+        self.stats["decode_calls"] += 1
+        self.stats[SAMPLE_COUNTERS[branch]] += 1
 
     def _count_view(self, column) -> None:
         """Once per decode call: the pages of the table its steps read,
@@ -2099,7 +2157,7 @@ class BatchDecodeEngine:
         # call must not mask these keys from a later warmup()
         self._warmed.add(dkey)
         self._warmed.add(vkey)
-        self.stats["decode_calls"] += 1
+        self._count_decode_call()
         stamped = self._collect_firsts()
         with phase("serve.chunk_sync", self.stats):
             pk = np.asarray(parts[0] if steps == 1
@@ -2182,7 +2240,7 @@ class BatchDecodeEngine:
         # post-success: a failed first chunk must not mask the key from a
         # later warmup()
         self._warmed.add("decode")
-        self.stats["decode_calls"] += 1
+        self._count_decode_call()
         self._collect_firsts()
         with phase("serve.chunk_sync", self.stats):
             pk = np.asarray(packed)             # the ONE sync per chunk

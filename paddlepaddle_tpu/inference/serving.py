@@ -67,7 +67,7 @@ import numpy as np
 from ..core import flags as _flags
 from ..observability.recorder import phase, phase_counters
 from ..resilience.chaos import chaos_point
-from .decode_engine import ADMIT_COUNTERS, CHUNK_PHASES
+from .decode_engine import ADMIT_COUNTERS, CHUNK_PHASES, SAMPLE_COUNTERS
 from .kv_pool import pages_needed
 from .robustness import (
     CircuitBreaker,
@@ -99,7 +99,8 @@ LOOP_PHASES = ("serve.sweep", "serve.wait_request", "serve.admit",
                *CHUNK_PHASES)
 # what the decode engine counts for itself and the loop copies after a chunk
 _ENGINE_SPAN_KEYS = ("turnaround_s", "turnaround_n", "decode_view_pages",
-                     "decode_table_pages", *ADMIT_COUNTERS,
+                     "decode_table_pages", "decode_calls", *ADMIT_COUNTERS,
+                     *SAMPLE_COUNTERS,
                      *phase_counters(CHUNK_PHASES))
 
 # process-wide request ids: the join key across SLO metrics, trace spans
@@ -482,7 +483,9 @@ class ServingEngine:
                       # pages the decode steps gathered, over the table's
                       "decode_view_pages": 0, "decode_table_pages": 0,
                       # admissions by kind, tokens computed and taken cached
-                      **dict.fromkeys(ADMIT_COUNTERS, 0)}
+                      **dict.fromkeys(ADMIT_COUNTERS, 0),
+                      # decode calls, and by the sampler branch they asked for
+                      "decode_calls": 0, **dict.fromkeys(SAMPLE_COUNTERS, 0)}
         # robustness limits: explicit args win, else FLAGS_serving_* (whose
         # 0 default means "off"), so a fleet can arm them by env alone
         self.max_queue = _flag_or(max_queue, "serving_max_queue")

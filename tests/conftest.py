@@ -57,9 +57,9 @@ def can_serialize_executables() -> bool:
     an engine that has served — what AOT serving bundles are made of. The
     installed CPU client answers UNIMPLEMENTED ("`LessThan` is not
     serializable") for an executable that has already RUN a
-    sort-by-comparator, and every engine program samples through a
-    full-width ``lax.top_k``; the TPU client round-trips them, persistent-
-    cache hits included (PR 21, chip run)."""
+    sort-by-comparator, and every engine program holds a full-width
+    ``lax.top_k`` (the sampler's filtering branch); the TPU client
+    round-trips them, persistent-cache hits included (PR 21, chip run)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import serialize_executable

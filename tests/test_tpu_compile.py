@@ -87,3 +87,33 @@ def test_the_latent_prefill_kernel_compiles_for_v5e_at_the_longdoc_cells_shapes(
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     rows = -(-queries // 512) * 512
     assert re.search(rf'= bf16\[64,{rows},128\]\S* custom-call\(.*custom_call_target="tpu_custom_call"', text)
+
+
+def _reaches(text, name):
+    """The text of the HLO computation ``name`` and of every computation it calls (a conditional's branches are not followed)."""
+    body = re.search(rf"^(?:ENTRY )?%{re.escape(name)} .*?^}}", text, re.M | re.S).group(0)
+    called = set(re.findall(r"(?:calls|to_apply|body|condition|called_computations)=\{?%([\w.\-]+)", body))
+    return body + "".join(_reaches(text, c) for c in sorted(called))
+
+
+@pytest.mark.parametrize("slots, vocab", [(32, 32768), (128, 16384), (32, 20480)])   # chat and docqa, agent, longdoc
+def test_the_sampler_stays_a_conditional_on_v5e_and_its_greedy_branch_is_the_argmax_alone(one_chip, slots, vocab):
+    """The compiler for the chip keeps ``_sample``'s three branches as one real conditional (not every branch
+    and a select), and the branch an all-greedy step takes neither sorts nor draws. The logits reach the branches as
+    the head rounds them (bfloat16 in every cell): a float32 operand would be fused into the head's matmul unrounded."""
+    from paddlepaddle_tpu.inference.decode_engine import BatchDecodeEngine
+
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (shape((slots, vocab), jnp.bfloat16), shape((slots,), jnp.float32), shape((slots,), jnp.int32),
+            shape((2,), jnp.uint32), shape((slots,), jnp.bool_))
+    text = jax.jit(BatchDecodeEngine._sample).lower(*args).compile().as_text()
+    conds = re.findall(r" conditional\(.*branch_computations=\{%([\w.\-]+), %([\w.\-]+), %([\w.\-]+)\}", text)
+    assert len(conds) == 1, conds
+    greedy, draw, filtered = (_reaches(text, name) for name in conds[0])
+    sorts, draws = re.compile(r" sort\(|TopK"), re.compile(r"_gumbel|threefry|rng")    # by the operations' own names
+    assert not sorts.search(greedy) and not draws.search(greedy) and f"bf16[{slots},{vocab}]" in greedy
+    assert draws.search(draw) and not sorts.search(draw)
+    assert sorts.search(filtered) and draws.search(filtered)
+    # ... and nothing of the kind is left outside the branches
+    outside = _reaches(text, re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1))
+    assert " conditional(" in outside and not sorts.search(outside) and not draws.search(outside)
