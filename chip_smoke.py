@@ -83,6 +83,7 @@ class Size:
     latent: Dict[str, int]            # slots, page, heads, rank, rope, pages_per_slot
     gqa: Dict[str, int]               # slots, page, heads, kv_heads, head_dim, pages_per_slot
     prefill: Dict[str, int]           # heads, nope, rope, v, rank, queries, start
+    held: Dict[str, Dict[str, int]]   # cell -> rows, d, width, held, topk, experts
 
 
 # bench.py's primary config (254M) and the serving/kernel shapes it implies
@@ -112,6 +113,13 @@ FULL = Size(
     # queries behind 1,000 cached rows (blocks of 512 with both ragged ends)
     prefill=dict(heads=64, nope=128, rope=64, v=128, rank=512, queries=1500,
                  start=1000),
+    # a decode step's held experts in the two expert cells: longdoc's 32 slots
+    # over 12 held of Kimi-K2's 384 experts (8 picks), agent's 128 slots over
+    # 16 held of LongCat's 768 router outputs (12 picks)
+    held={"longdoc": dict(rows=32, d=7168, width=2048, held=12, topk=8,
+                          experts=384),
+          "agent": dict(rows=128, d=6144, width=2048, held=16, topk=12,
+                        experts=768)},
 )
 
 # the tier-1 width: same code, seconds on the CPU
@@ -129,6 +137,8 @@ TINY = Size(
     gqa=dict(slots=4, page=8, heads=4, kv_heads=2, head_dim=128,
              pages_per_slot=4),
     prefill=dict(heads=4, nope=8, rope=8, v=8, rank=16, queries=21, start=9),
+    held={"longdoc": dict(rows=5, d=32, width=64, held=4, topk=3, experts=8),
+          "agent": dict(rows=20, d=32, width=64, held=6, topk=3, experts=24)},
 )
 
 
@@ -624,6 +634,49 @@ def _kernel_paged_gqa(size: Size, interpret: bool) -> Dict[str, object]:
     return row
 
 
+def _kernel_held_experts(size: Size, cell: str,
+                         interpret: bool) -> Dict[str, object]:
+    """A decode step's held experts through the kernel that reads the touched
+    experts alone, against the dense form it takes the place of
+    (``moe._held_experts_dense`` over every held expert, in float32), on
+    picks drawn like a router's: each row ``topk`` distinct experts of
+    ``experts``, the first ``held`` of them this chip's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddlepaddle_tpu.ops.kernels.held_experts import (
+        held_experts_decode, picked_experts)
+    from paddlepaddle_tpu.parallel import moe
+
+    p = size.held[cell]
+    T, d, f, E = p["rows"], p["d"], p["width"], p["held"]
+    rng = np.random.default_rng(9)
+    ids = np.stack([rng.choice(p["experts"], p["topk"], replace=False)
+                    for _ in range(T)])
+    picks = jnp.asarray(np.where(ids < E, ids, E + 1), jnp.int32)
+    w_local = np.zeros((T, E), np.float32)     # a row's weight on each pick
+    t, k = np.nonzero(ids < E)
+    w_local[t, ids[t, k]] = rng.random(len(t))
+    w_local = jnp.asarray(w_local)
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    x = jax.random.normal(ks[0], (T, d), jnp.bfloat16)
+    wg = jax.random.normal(ks[1], (E, d, f), jnp.bfloat16) * d ** -0.5
+    wu = jax.random.normal(ks[2], (E, d, f), jnp.bfloat16) * d ** -0.5
+    wd = jax.random.normal(ks[3], (E, f, d), jnp.bfloat16) * f ** -0.5
+    picked, n = picked_experts(picks, E)
+
+    got = _mosaic("held_experts", lambda x, wl, i, n, wg, wu, wd:
+                  held_experts_decode(x, wl, i, n, wg, wu, wd,
+                                      interpret=interpret),
+                  interpret)(x, w_local, picked, n, wg, wu, wd)
+    want = jax.jit(moe._held_experts_dense)(
+        *_f32(x), w_local, *_f32(wg, wu, wd))
+    row = _compare(f"held_experts ({cell})", got, want, interpret)
+    row["touched"], row["held"] = int(n[0]), E
+    return row
+
+
 def _kernel_gather_gemm(size: Size, interpret: bool) -> Dict[str, object]:
     import jax
     import jax.numpy as jnp
@@ -665,6 +718,9 @@ def kernels_leg(size: Size, interpret: bool) -> Dict[str, object]:
     table["paged_gqa_attention"] = _kernel_paged_gqa(size, interpret)
     table["latent_prefill_attention"] = _kernel_latent_prefill(size, interpret)
     table["gather_gemm"] = _kernel_gather_gemm(size, interpret)
+    for cell in size.held:
+        table[f"held_experts.{cell}"] = _kernel_held_experts(size, cell,
+                                                             interpret)
     return {"status": "ok", "tolerance": f"{KERNEL_TOL} * max|reference|",
             "table": table}
 

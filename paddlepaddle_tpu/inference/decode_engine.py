@@ -741,8 +741,8 @@ class BatchDecodeEngine:
         self._experts_held = shares[0].count if shares else 0
         picks = {} if not shares else dict(
             moe_picks_zero=0, moe_picks_held=0, moe_picks_absent=0,
-            moe_picks_total=0, moe_experts_touched=0, moe_layer_steps=0,
-            moe_experts_held=self._experts_held,
+            moe_picks_total=0, moe_experts_touched=0, moe_experts_read=0,
+            moe_layer_steps=0, moe_experts_held=self._experts_held,
             moe_expert_pairs=(0,) * self._experts_held)
         self.stats.update(picks)
         self.pick_stat_keys = tuple(picks)     # the serving loop copies these
@@ -991,7 +991,8 @@ class BatchDecodeEngine:
         st["moe_picks_absent"] += int(v[E + 1])
         st["moe_picks_total"] += int(v[:E + 2].sum())
         st["moe_experts_touched"] += int(v[E + 2])
-        st["moe_layer_steps"] += int(v[E + 3])
+        st["moe_experts_read"] += int(v[E + 3])
+        st["moe_layer_steps"] += int(v[E + 4])
 
     def _forward_paged(self, params, toks, pools, page_table, lens, rung,
                        tap=None, walk=None):

@@ -9,8 +9,11 @@ FLAGS_use_pallas_kernels=0.
 gather_gemm.py (the MoE dispatch of ``MoELayer(dispatch_mode="fused")``),
 paged_latent_attention.py and paged_gqa_attention.py (a decode step's
 attention over a latent cache row and over a K/V pair, behind the serving
-engine's paged view: docs/kernels.md) additionally run in Pallas INTERPRET
-mode on CPU, so parity is test-pinned in the tier-1 environment.
+engine's paged view: docs/kernels.md), latent_prefill_attention.py (a long
+prefill's latent attention) and held_experts.py (an expert share's held
+experts for at most ``parallel.moe.HELD_KERNEL_MAX_TOKENS`` rows, reading
+only the experts the rows picked) additionally run in Pallas INTERPRET mode
+on CPU, so parity is test-pinned in the tier-1 environment.
 
 Every kernel here is compiled by Mosaic and compared with its
 ``jax.numpy`` reference by ``chip_smoke.py``'s kernels leg on the chip;

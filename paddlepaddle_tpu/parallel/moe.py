@@ -27,6 +27,8 @@ from ..core.dispatch import apply_op
 from ..core.tensor import Tensor
 from ..nn.initializer import XavierNormal
 from ..nn.layer import Layer
+from ..ops.kernels import current_partition
+from ..ops.kernels.held_experts import held_experts_decode, picked_experts
 from .mpu import mark_placement
 
 
@@ -556,6 +558,13 @@ def _dropless_moe_ffn(x, logits, wg, wu, wd, topk, align=1):
 # and needs no sort. Above it the sorted, grouped form does the work of the
 # pairs that exist.
 GROUPED_ABOVE_TOKENS = 512
+# Up to this many tokens the held experts run through a Pallas kernel that
+# reads only the experts the tokens picked (ops/kernels/held_experts.py): the
+# dense form does T operations a weight byte, and below the v5e's ridge (197
+# TFLOP/s over 819 GB/s, 240) the weight bytes set its time, so the bytes of
+# experts nobody picked are time lost (a decode step: 32 or 128 rows). Above
+# it only admissions remain, whose rows touch nearly every held expert.
+HELD_KERNEL_MAX_TOKENS = 128
 # Up to this many tokens the grouped form sorts and runs EVERY pair in one call
 # of each matmul, held or not; beyond it the held pairs alone run, a block of
 # sorted rows at a time (:func:`_held_experts_row_blocks`): the one-call form
@@ -670,6 +679,28 @@ def _held_experts_row_blocks(x, weights, local, count, wg, wu, wd):
                              jnp.zeros((T, x.shape[1]), jnp.float32))
 
 
+def held_form(rows: int) -> str:
+    """The form of the held experts for a call of ``rows`` tokens, from the
+    count alone: ``"kernel"`` (:data:`HELD_KERNEL_MAX_TOKENS`; the dense form
+    where a sharded step declared a kernel partition, since GSPMD cannot
+    partition a Mosaic call), ``"dense"`` (:data:`GROUPED_ABOVE_TOKENS`),
+    ``"grouped"`` (:data:`GROUPED_ALL_PAIRS_TOKENS`) or ``"row_blocks"``."""
+    if rows <= HELD_KERNEL_MAX_TOKENS and current_partition() is None:
+        return "kernel"
+    if rows <= GROUPED_ABOVE_TOKENS:
+        return "dense"
+    return "grouped" if rows <= GROUPED_ALL_PAIRS_TOKENS else "row_blocks"
+
+
+def experts_read(picks, count):
+    """The held experts whose weights a call of :func:`expert_share_ffn` with
+    these ``picks [T, topk]`` reads (int32): those the rows touched where the
+    kernel runs, every one of the ``count`` in any other form."""
+    if held_form(picks.shape[0]) == "kernel":
+        return picked_experts(picks, count)[1][0]
+    return jnp.int32(count)
+
+
 def expert_share_ffn(x, router, bias, wg, wu, wd, *, topk, scale, num_routed,
                      first, routing="softmax"):
     """What ONE chip adds to an expert layer's result for tokens ``x [T, d]``:
@@ -681,20 +712,25 @@ def expert_share_ffn(x, router, bias, wg, wu, wd, *, topk, scale, num_routed,
     ``sum over held picks of w * E(x) + x * sum of the picked identity
     weights`` in float32, and ``picks [T, topk]``: the held expert's local
     index, ``count`` for an identity pick, ``count + 1`` for an expert that
-    lives on another chip (its part is left out)."""
+    lives on another chip (its part is left out). The held experts' form
+    follows from ``T`` alone (:func:`held_form`)."""
     count = wg.shape[0]
     weights, ids = ROUTINGS[routing](x, router, bias, topk, scale)
     local = ids - first
     is_held = (local >= 0) & (local < count)
     is_zero = ids >= num_routed
     picks = jnp.where(is_held, local, jnp.where(is_zero, count, count + 1))
-    if x.shape[0] <= GROUPED_ABOVE_TOKENS:
+    form = held_form(x.shape[0])
+    if form in ("kernel", "dense"):
         w_local = jnp.sum(jnp.where(is_held[..., None], weights[..., None], 0.0)
                           * jax.nn.one_hot(picks, count, dtype=jnp.float32), axis=1)
-        y = _held_experts_dense(x, w_local, wg, wu, wd)
+        if form == "kernel":
+            ids_, n = picked_experts(picks, count)
+            y = held_experts_decode(x, w_local, ids_, n, wg, wu, wd)
+        else:
+            y = _held_experts_dense(x, w_local, wg, wu, wd)
     else:
-        grouped = (_held_experts_grouped
-                   if x.shape[0] <= GROUPED_ALL_PAIRS_TOKENS
+        grouped = (_held_experts_grouped if form == "grouped"
                    else _held_experts_row_blocks)
         y = grouped(x, weights, jnp.where(is_held, local, count), count, wg,
                     wu, wd)
@@ -725,7 +761,8 @@ class PickTap:
     _active = None
 
     def __init__(self):
-        self.picks = []          # (picks [T, topk], count) per expert share run
+        # (picks [T, topk], count, experts read) per expert share run
+        self.picks = []
 
     def __enter__(self):
         self._outer, PickTap._active = PickTap._active, self
@@ -735,16 +772,22 @@ class PickTap:
         PickTap._active = self._outer
 
     def counts(self, mask=None):
-        """Sum of :func:`pick_counts` over the calls collected (all shares
-        hold the same ``count``), or None where there was none."""
+        """Sum over the calls collected (all shares hold the same ``count``)
+        of :func:`pick_counts` followed by the held experts whose weights the
+        call read (:func:`experts_read`, every row's picks: a masked row is
+        computed all the same; 0 where ``mask`` leaves every row out), int32
+        ``[count + 4]``; None where there was no call."""
         if not self.picks:
             return None
-        return sum(pick_counts(p, c, mask) for p, c in self.picks)
+        live = 1 if mask is None else jnp.any(mask).astype(jnp.int32)
+        return sum(jnp.concatenate([pick_counts(p, c, mask), (read * live)[None]])
+                   for p, c, read in self.picks)
 
 
 def record_picks(picks, count):
     if PickTap._active is not None:
-        PickTap._active.picks.append((picks, count))
+        PickTap._active.picks.append((picks, count,
+                                      experts_read(picks, count)))
 
 
 class ExpertShareLayer(Layer):

@@ -118,6 +118,8 @@ def test_prefill_then_paged_decode_through_the_serving_engine_follows_the_refere
     assert stats["admit_tokens_cached"] == 48
     assert stats["moe_picks_total"] == stats["moe_picks_held"] + stats["moe_picks_absent"] > 0 == stats["moe_picks_zero"]
     assert stats["moe_layer_steps"] % 2 == 0        # two expert layers a live step: the dense layer counts none
+    # the kernel reads what any row touched, idle lanes' picks included: at least what live rows touched
+    assert stats["moe_experts_touched"] <= stats["moe_experts_read"] <= 4 * stats["moe_layer_steps"]
 
 
 # -- (c) ----------------------------------------------------------------------------
@@ -182,10 +184,13 @@ def test_yarn_tables_against_the_formula_beyond_the_original_length():
 
 # -- (e) ----------------------------------------------------------------------------
 
-@pytest.mark.parametrize("form", ["dense", "grouped", "row_blocks"])
+@pytest.mark.parametrize("form", ["kernel", "dense", "grouped", "row_blocks"])
 def test_the_shares_add_up_with_the_shared_expert_counted_once(form, monkeypatch):
     """All four shares of eight experts: their routed parts plus the shared expert's, counted once, are the uncut layer."""
-    if form != "dense":
+    assert moe.held_form(42) == "kernel"
+    if form != "kernel":
+        monkeypatch.setattr(moe, "HELD_KERNEL_MAX_TOKENS", 8)
+    if form not in ("kernel", "dense"):
         monkeypatch.setattr(moe, "GROUPED_ABOVE_TOKENS", 8)
     if form == "row_blocks":                                       # 126 pairs in blocks of 16: several blocks an expert
         monkeypatch.setattr(moe, "GROUPED_ALL_PAIRS_TOKENS", 16)
@@ -275,6 +280,20 @@ def test_no_prompt_makes_the_prefill_hold_more_scores_than_the_bound(monkeypatch
 def test_what_the_latent_row_cannot_do_yet_refuses_for_this_model_too(kwargs, reason):
     with pytest.raises(ValueError, match="latent.*" + reason):
         BatchDecodeEngine(_model(held=(0, 4)), max_slots=2, max_len=64, chunk=2, page_size=16, num_pages=12, **kwargs)
+
+
+@pytest.mark.parametrize("form", ["kernel", "dense"])
+def test_the_experts_read_counter_follows_the_form_of_the_held_experts(form, monkeypatch):
+    """One slot, so every row is live: on the kernel's rung the experts read a layer-step are those it touched, on
+    the dense rung all four held."""
+    if form == "dense":
+        monkeypatch.setattr(moe, "HELD_KERNEL_MAX_TOKENS", 0)
+    eng = BatchDecodeEngine(_model(held=(0, 4), seed=7), max_slots=1, max_len=64, chunk=4, page_size=16, num_pages=12)
+    req = paddle.inference.serving.GenerationRequest(_ids(11, seed=3), 9, 0.0, 0, None)
+    eng.serve([req], timeout=240)
+    st = eng.stats
+    assert st["moe_layer_steps"] == 2 * 8 and 0 < st["moe_experts_touched"] <= 4 * 16
+    assert st["moe_experts_read"] == (st["moe_experts_touched"] if form == "kernel" else 4 * 16)
 
 
 def test_the_cache_spec_is_one_latent_block_a_layer():

@@ -155,11 +155,14 @@ def test_a_held_share_of_the_model_equals_the_reference_given_the_same_share():
 
 # -- (b) --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("grouped", [False, True])
-def test_the_shares_add_up(grouped, monkeypatch):
+@pytest.mark.parametrize("form", ["kernel", "dense", "grouped"])
+def test_the_shares_add_up(form, monkeypatch):
     """8 routed + 4 zero-compute experts, top 3, cut 4 ways: the four chips'
-    routed parts plus the identity part counted once are the uncut layer."""
-    if grouped:
+    routed parts plus the identity part counted once are the uncut layer, in
+    each form of the held experts that 48 tokens can be given."""
+    if form != "kernel":
+        monkeypatch.setattr(moe, "HELD_KERNEL_MAX_TOKENS", 8)
+    if form == "grouped":
         monkeypatch.setattr(moe, "GROUPED_ABOVE_TOKENS", 8)
     cfg = LongcatFlashConfig.tiny()
     paddle.seed(5)
@@ -299,6 +302,28 @@ def test_the_decode_counters_reach_engine_stats_and_follow_the_reference():
     assert st["moe_picks_zero"] + st["moe_picks_held"] + st["moe_picks_absent"] == picked.size
     assert st["moe_experts_touched"] == sum(
         len({int(e) for e in picked[l, t] if 2 <= e < 6}) for l in range(cfg.num_layers) for t in range(8))
+
+
+@pytest.mark.parametrize("form", ["kernel", "dense"])
+def test_the_experts_read_counter_follows_the_form_of_the_held_experts(form, monkeypatch):
+    """``moe_experts_read`` through decode calls of one slot (so the reference's own picks are every row's): on the
+    kernel's rung the held experts a layer-step touched, on the dense rung every held expert of every layer-step."""
+    if form == "dense":
+        monkeypatch.setattr(moe, "HELD_KERNEL_MAX_TOKENS", 0)
+    m = _model(held=(2, 4), seed=7)
+    cfg, w = m.config, _weights(m)
+    eng = _engine(m, chunk=4, max_slots=1)
+    prompt = _ids(18, seed=41)
+    r = _req(prompt, 9)
+    eng.serve([r], timeout=240)
+    out = np.asarray(r.result.result(5))
+    routing = []
+    ref_logits(cfg, w, out[:-1], held=(2, 4), routing=routing)
+    picked = np.stack(routing)[:, len(prompt):]
+    touched = [len({int(e) for e in picked[l, t] if 2 <= e < 6}) for l in range(cfg.num_layers) for t in range(8)]
+    st = eng.stats
+    assert st["moe_layer_steps"] == len(touched) and st["moe_experts_touched"] == sum(touched)
+    assert st["moe_experts_read"] == (sum(touched) if form == "kernel" else 4 * len(touched))
 
 
 def test_serving_engine_copies_the_counters_and_a_dense_model_has_none():

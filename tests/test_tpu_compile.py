@@ -117,3 +117,20 @@ def test_the_sampler_stays_a_conditional_on_v5e_and_its_greedy_branch_is_the_arg
     # ... and nothing of the kind is left outside the branches
     outside = _reaches(text, re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1))
     assert " conditional(" in outside and not sorts.search(outside) and not draws.search(outside)
+
+
+@pytest.mark.parametrize("rows, d, width, held", [(32, 7168, 2048, 12), (128, 6144, 2048, 16)])   # longdoc, agent
+def test_the_held_experts_kernel_compiles_for_v5e_at_both_expert_cells_shapes(one_chip, rows, d, width, held):
+    """A decode step's held experts through the kernel that reads the touched experts alone: one Mosaic call, and the
+    stacked weights reach it as they are (no operation of the program has a result of the weight stack's size: a
+    gather of the touched experts' weights would write what the kernel saves)."""
+    from paddlepaddle_tpu.ops.kernels.held_experts import held_experts_decode
+
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (shape((rows, d)), shape((rows, held), jnp.float32), shape((held,), jnp.int32), shape((1,), jnp.int32),
+            shape((held, d, width)), shape((held, d, width)), shape((held, width, d)))
+    fn = lambda *a: held_experts_decode(*a, interpret=False)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    moved = re.findall(rf"= bf16\[{held},[^ ]* (?!bitcast|parameter)\w+\(", text)
+    assert not moved, moved
