@@ -68,6 +68,7 @@ import numpy as np
 from ..core import autograd as _ag
 from ..core.dispatch import unwrap
 from ..observability.recorder import phase, phase_counters
+from ..ops.kernels import traced_by
 from ..ops.kernels.paged_gqa_attention import (paged_gqa_attention,
                                                reads_in_place)
 from ..ops.kernels.paged_latent_attention import (lane_whole,
@@ -1127,6 +1128,7 @@ class BatchDecodeEngine:
         return [tuple(jnp.zeros((1, length, *p.row), dtype) for p in layer)
                 for layer in self.cache_spec]
 
+    @traced_by("admit")
     def _admit_impl(self, params, caches, lens, tokens, active, temps,
                     eos_ids, budgets, top_ks, ids, plen, slot, temp, eos,
                     budget, top_k, key):
@@ -1151,6 +1153,7 @@ class BatchDecodeEngine:
                                     eos_ids, budgets, top_ks, key, slot,
                                     plen, temp, eos, budget, top_k, first)
 
+    @traced_by("admit")
     def _admit_paged_impl(self, params, pools, page_table, lens, tokens,
                           active, temps, eos_ids, budgets, top_ks, ids, plen,
                           slot, temp, eos, budget, top_k, key):
@@ -1215,6 +1218,7 @@ class BatchDecodeEngine:
         npg_tail = pages_needed(tail_bucket, ps)
         pad = npg_tail * ps - tail_bucket
 
+        @traced_by("admit")
         def impl(params, pools, page_table, lens, tokens, active, temps,
                  eos_ids, budgets, top_ks, ids, tail_plen, slot, temp, eos,
                  budget, top_k, key):
@@ -1304,6 +1308,7 @@ class BatchDecodeEngine:
                 & (budgets > 0)
             return caches, nxt, lens, active, budgets, key, (emitted, picks)
 
+        @traced_by("decode")
         def run(params, caches, page_table, tokens, lens, active, temps,
                 eos_ids, budgets, top_ks, key):
             # one rung for the whole call, from where the longest live

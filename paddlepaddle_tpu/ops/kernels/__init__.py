@@ -18,6 +18,12 @@ on CPU, so parity is test-pinned in the tier-1 environment.
 Every kernel here is compiled by Mosaic and compared with its
 ``jax.numpy`` reference by ``chip_smoke.py``'s kernels leg on the chip;
 the interpreter alone proves the program, not that the chip accepts it.
+
+Every kernel but the flash family names its ``pallas_call`` by
+:func:`call_name`, so a device trace shows the call as
+``%<kernel>_<program>.N``. The flash kernels keep the names of the
+transformations around them (``jvp``, ``transpose(jvp)``), which the train
+benchmark's readers match.
 """
 
 import contextlib
@@ -82,3 +88,30 @@ def partition_over(mesh, batch_axes):
 
 def current_partition() -> Optional[KernelPartition]:
     return _partition.get()
+
+
+_program: contextvars.ContextVar = contextvars.ContextVar(
+    "kernel_program", default=None)
+
+
+@contextlib.contextmanager
+def traced_by(program: str):
+    """Entered by an engine's program while jax traces it (``decode``,
+    ``admit``): a kernel called inside names its Mosaic call after the
+    program too (:func:`call_name`), so a device trace tells a decode step's
+    call from an admission's where one kernel runs in both. As a decorator it
+    keeps the function's ``__name__``, which names the XLA module a jitted
+    program becomes (``jit_run``)."""
+    token = _program.set(program)
+    try:
+        yield
+    finally:
+        _program.reset(token)
+
+
+def call_name(kernel: str) -> str:
+    """The name a kernel gives its ``pallas_call``, and so its HLO
+    instruction: the kernel's module name, ``_<program>`` appended inside
+    :func:`traced_by`."""
+    program = _program.get()
+    return f"{kernel}_{program}" if program else kernel

@@ -47,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.flags import flag_value
-from . import interpret_mode
+from . import call_name, interpret_mode
 
 
 def gather_gemm_supported(*, d_model: int, d_hidden: int) -> tuple:
@@ -169,5 +169,6 @@ def gather_gemm_ffn(x, slot_entry, wgu, wd, *, capacity, interpret=None):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((E, C_pad, d), x.dtype),
             interpret=interpret,
+            name=call_name("gather_gemm"),
         )(se, x3, wgu, wd)
     return out[:, :C, :].reshape(E * C, d)

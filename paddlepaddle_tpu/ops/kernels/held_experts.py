@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from . import call_name, interpret_mode
 
 LANES = 128
 SUBLANES = 16            # rows of a bf16 tile
@@ -111,19 +111,20 @@ def held_experts_decode(x, w_local, picked_ids, n_picked, wg, wu, wd, *,
     if interpret is None:
         interpret = interpret_mode()
     return _forward_only(x, w_local, picked_ids, n_picked, wg, wu, wd,
-                         int(tile), bool(interpret))
+                         int(tile), bool(interpret), call_name("held_experts"))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _forward_only(x, w_local, ids, n, wg, wu, wd, tf, interpret):
-    return _program(tf, interpret)(x, w_local, ids, n, wg, wu, wd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _forward_only(x, w_local, ids, n, wg, wu, wd, tf, interpret, name):
+    return _program(tf, interpret, name)(x, w_local, ids, n, wg, wu, wd)
 
 
-def _forward_only_fwd(x, w_local, ids, n, wg, wu, wd, tf, interpret):
-    return _forward_only(x, w_local, ids, n, wg, wu, wd, tf, interpret), None
+def _forward_only_fwd(x, w_local, ids, n, wg, wu, wd, tf, interpret, name):
+    return _forward_only(x, w_local, ids, n, wg, wu, wd, tf, interpret,
+                         name), None
 
 
-def _forward_only_bwd(tf, interpret, res, g):
+def _forward_only_bwd(tf, interpret, name, res, g):
     raise NotImplementedError(
         "held_experts_decode is forward-only: the expert shares it serves are "
         "not trained, and the kernel has no backward pass")
@@ -133,14 +134,15 @@ _forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
 
 
 @functools.lru_cache(maxsize=None)
-def _program(tf: int, interpret: bool):
-    """The jitted call for one static choice: the SAME callable for every
-    expert layer, so a decode program traces the kernel once, not once a
-    layer (as ``paged_latent_attention._program``)."""
-    return jax.jit(functools.partial(_call, tf=tf, interpret=interpret))
+def _program(tf: int, interpret: bool, name: str):
+    """The jitted call for one static choice and call name: the SAME
+    callable for every expert layer, so a decode program traces the kernel
+    once, not once a layer (as ``paged_latent_attention._program``)."""
+    return jax.jit(functools.partial(_call, tf=tf, interpret=interpret,
+                                     name=name))
 
 
-def _call(x, w_local, ids, n, wg, wu, wd, *, tf, interpret):
+def _call(x, w_local, ids, n, wg, wu, wd, *, tf, interpret, name):
     T, d = x.shape
     count, _, width = wg.shape
     nf = width // tf
@@ -184,6 +186,7 @@ def _call(x, w_local, ids, n, wg, wu, wd, *, tf, interpret):
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=int(need) + (16 << 20)),
             interpret=interpret,
+            name=name,
         )(jnp.asarray(ids, jnp.int32), jnp.asarray(n, jnp.int32), x, w_local,
           wg, wu, wd)
     return out[:T]

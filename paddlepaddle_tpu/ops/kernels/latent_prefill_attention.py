@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from . import call_name, interpret_mode
 
 NEG_INF = -1e30
 # queries and keys a block: a 512 x 512 float32 tile of scores is 1 MiB, and the
@@ -85,19 +85,21 @@ def latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *, scale,
     blocks of a tiny prompt with it)."""
     if interpret is None:
         interpret = interpret_mode()
-    return _program(float(scale), int(block or BLOCK), bool(interpret))(
+    return _program(float(scale), int(block or BLOCK), bool(interpret),
+                    call_name("latent_prefill_attention"))(
         q_nope, q_rope, k_nope, k_rope, v, start)
 
 
 @functools.lru_cache(maxsize=None)
-def _program(scale: float, block: int, interpret: bool):
-    """The jitted call for one static choice: the same callable for every layer,
-    so an admission traces and lowers the kernel once."""
+def _program(scale: float, block: int, interpret: bool, name: str):
+    """The jitted call for one static choice and call name: the same callable for
+    every layer, so an admission traces and lowers the kernel once."""
     return jax.jit(functools.partial(_attend, scale=scale, block=block,
-                                     interpret=interpret))
+                                     interpret=interpret, name=name))
 
 
-def _attend(q_nope, q_rope, k_nope, k_rope, v, start, *, scale, block, interpret):
+def _attend(q_nope, q_rope, k_nope, k_rope, v, start, *, scale, block, interpret,
+            name):
     b, s, H, nope = q_nope.shape
     L, rope, vd = k_nope.shape[1], q_rope.shape[-1], v.shape[-1]
     dtype = k_nope.dtype
@@ -140,6 +142,7 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, start, *, scale, block, interpret
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=min(resident + (24 << 20), 100 << 20)),
             interpret=interpret,
+            name=name,
         )(jnp.asarray(start, jnp.int32).reshape(b), rows(q_nope, nq * bq),
           rows(q_rope, nq * bq), rows(k_nope, nk * bk), rows(k_rope, nk * bk),
           rows(v, nk * bk))

@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from . import call_name, interpret_mode
 from .paged_latent_attention import LANES, NEG_INF
 
 # tokens a loop step attends: K = CHUNK_TOKENS / page_size pages. Read on a
@@ -213,22 +213,23 @@ def paged_gqa_attention(q, k_new, v_new, k_pool, v_pool, page_table, lens, *,
          if chunk_pages is None else chunk_pages)
     if interpret is None:
         interpret = interpret_mode()
-    return _program(float(scale), int(K), bool(interpret))(
+    return _program(float(scale), int(K), bool(interpret),
+                    call_name("paged_gqa_attention"))(
         q, k_new, v_new, k_pool, v_pool, page_table, lens)
 
 
 @functools.lru_cache(maxsize=None)
-def _program(scale: float, K: int, interpret: bool):
-    """The jitted call for one static choice. The SAME callable comes back
-    for every layer, so a decode program traces and lowers the kernel once
-    and calls it, not once a layer (seconds of every start-up otherwise, as
-    for ``decode_engine._view_branches``)."""
+def _program(scale: float, K: int, interpret: bool, name: str):
+    """The jitted call for one static choice and call name. The SAME
+    callable comes back for every layer, so a decode program traces and
+    lowers the kernel once and calls it, not once a layer (seconds of every
+    start-up otherwise, as for ``decode_engine._view_branches``)."""
     return jax.jit(functools.partial(_attend, scale=scale, K=K,
-                                     interpret=interpret))
+                                     interpret=interpret, name=name))
 
 
 def _attend(q, k_new, v_new, k_pool, v_pool, page_table, lens, *, scale, K,
-            interpret):
+            interpret, name):
     S, H, hd = q.shape
     pages, ps, kvh, _ = k_pool.shape
     rep, P = H // kvh, page_table.shape[1]
@@ -271,6 +272,7 @@ def _attend(q, k_new, v_new, k_pool, v_pool, page_table, lens, *, scale, K,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
+            name=name,
         )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lens, jnp.int32),
           q.astype(dtype), per_head(k_new), per_head(v_new),
           k_pool.reshape(pages, ps * kvh, hd),

@@ -65,7 +65,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from . import call_name, interpret_mode
 
 NEG_INF = -1e30
 LANES = 128
@@ -223,22 +223,24 @@ def paged_latent_attention(q_abs, q_rope, c_new, r_new, c_pool, r_pool,
          if chunk_pages is None else chunk_pages)
     if interpret is None:
         interpret = interpret_mode()
-    return _program(float(scale), int(K), bool(interpret))(
+    return _program(float(scale), int(K), bool(interpret),
+                    call_name("paged_latent_attention"))(
         q_abs, q_rope, c_new, r_new, c_pool, r_pool, page_table, lens)
 
 
 @functools.lru_cache(maxsize=None)
-def _program(scale: float, K: int, interpret: bool):
-    """The jitted call for one static choice. The SAME callable comes back
-    for every block of every layer, so a decode program traces and lowers
-    the kernel once and calls it, not once a block (seconds of every
-    start-up otherwise, as for ``decode_engine._view_branches``)."""
+def _program(scale: float, K: int, interpret: bool, name: str):
+    """The jitted call for one static choice and call name. The SAME
+    callable comes back for every block of every layer, so a decode program
+    traces and lowers the kernel once and calls it, not once a block
+    (seconds of every start-up otherwise, as for
+    ``decode_engine._view_branches``)."""
     return jax.jit(functools.partial(_attend, scale=scale, K=K,
-                                     interpret=interpret))
+                                     interpret=interpret, name=name))
 
 
 def _attend(q_abs, q_rope, c_new, r_new, c_pool, r_pool, page_table, lens, *,
-            scale, K, interpret):
+            scale, K, interpret, name):
     S, H, rank = q_abs.shape
     ps, P = c_pool.shape[1], page_table.shape[1]
     span = K * ps
@@ -281,6 +283,7 @@ def _attend(q_abs, q_rope, c_new, r_new, c_pool, r_pool, page_table, lens, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
+            name=name,
         )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lens, jnp.int32),
           q_abs.astype(dtype), q_rope.astype(dtype),
           c_new.astype(dtype)[:, None], r_new.astype(dtype)[:, None],

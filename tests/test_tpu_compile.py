@@ -134,3 +134,128 @@ def test_the_held_experts_kernel_compiles_for_v5e_at_both_expert_cells_shapes(on
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     moved = re.findall(rf"= bf16\[{held},[^ ]* (?!bitcast|parameter)\w+\(", text)
     assert not moved, moved
+
+
+# -- the names a device trace shows, and the readers that match them ------------------------------------------------
+
+def _reader_module(name):
+    """The benchmark's reader of per-layer metric ``name`` as a module (its ``PATTERN`` with it)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "metrics",
+                        name + ".py")
+    spec = importlib.util.spec_from_file_location("reader_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _mosaic_calls(text):
+    """Each Mosaic call's HLO text as a device trace names the operation."""
+    return [l.strip().removeprefix("ROOT ") for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def _kernel_call(kernel, one_chip):
+    """(function, abstract arguments) of one call of ``kernel`` at a small shape Mosaic takes."""
+    from paddlepaddle_tpu.ops.kernels import (gather_gemm, held_experts, latent_prefill_attention,
+                                              paged_gqa_attention, paged_latent_attention)
+
+    bf = jnp.bfloat16
+    shape = lambda s, dt=bf: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    i32 = jnp.int32
+    if kernel == "paged_gqa_attention":
+        return (lambda *a: paged_gqa_attention.paged_gqa_attention(*a, scale=0.088, interpret=False),
+                (shape((8, 8, 128)), shape((8, 2, 128)), shape((8, 2, 128)), shape((64, 64, 2, 128)),
+                 shape((64, 64, 2, 128)), shape((8, 8), i32), shape((8,), i32)))
+    if kernel == "paged_latent_attention":
+        return (lambda *a: paged_latent_attention.paged_latent_attention(*a, scale=0.072, interpret=False),
+                (shape((8, 16, RANK)), shape((8, 16, ROPE)), shape((8, RANK)), shape((8, ROPE)),
+                 shape((64, PS, RANK)), shape((64, PS, ROPE)), shape((8, 8), i32), shape((8,), i32)))
+    if kernel == "held_experts":
+        return (lambda *a: held_experts.held_experts_decode(*a, interpret=False),
+                (shape((32, 512)), shape((32, 4), jnp.float32), shape((4,), i32), shape((1,), i32),
+                 shape((4, 512, 512)), shape((4, 512, 512)), shape((4, 512, 512))))
+    if kernel == "latent_prefill_attention":
+        return (lambda *a: latent_prefill_attention.latent_prefill_attention(*a, scale=0.13, interpret=False),
+                (shape((1, 512, 4, 128)), shape((1, 512, 4, 64)), shape((1, 512, 4, 128)), shape((1, 512, 64)),
+                 shape((1, 512, 4, 128)), shape((1,), i32)))
+    return (lambda x, se, wgu, wd: gather_gemm.gather_gemm_ffn(x, se, wgu, wd, capacity=16, interpret=False),
+            (shape((64, 256)), shape((4 * 16,), i32), shape((4, 256, 512)), shape((4, 256, 256))))
+
+
+# (kernel, the engine program that traces it, the reader of its calls there); every kernel of the package but the flash
+# family, which keeps its transformations' names below
+NAMED = [("paged_gqa_attention", "decode", "decode.attention_ms_per_step"),
+         ("paged_latent_attention", "decode", "decode.attention_ms_per_step"),
+         ("held_experts", "decode", "decode.experts_ms_per_step"),
+         ("held_experts", "admit", None),
+         ("held_experts", None, None),
+         ("latent_prefill_attention", "admit", "prefill.attention_ms_per_request.saturated"),
+         ("gather_gemm", None, None)]
+
+
+@pytest.mark.parametrize("kernel, program, reader", NAMED)
+def test_each_kernel_names_its_call_after_itself_and_the_program_that_traced_it(one_chip, kernel, program, reader):
+    """The call's HLO instruction, the name a device trace and the benchmark's breakdown show, begins with the kernel's
+    module name, and carries the engine program that traced it where one declared itself (``kernels.traced_by``):
+    the held experts' kernel runs in a decode step and in a short admission at one shape, and only the name tells the
+    two apart. The reader of the kernel's part of that program matches the call; no reader matches another program's."""
+    from paddlepaddle_tpu.ops import kernels
+
+    fn, args = _kernel_call(kernel, one_chip)
+    if program is not None:
+        fn = kernels.traced_by(program)(fn)
+    calls = _mosaic_calls(jax.jit(fn).lower(*args).compile().as_text())
+    want = kernel if program is None else f"{kernel}_{program}"
+    assert len(calls) == 1 and re.match(rf"%{want}(\.\d+)? = ", calls[0]), calls
+    patterns = {name: _reader_module(name).PATTERN for name in (
+        "decode.attention_ms_per_step", "decode.attention_ms_per_step.steady", "decode.experts_ms_per_step",
+        "prefill.attention_ms_per_request.saturated")}
+    matched = {name for name, pattern in patterns.items() if re.search(pattern, calls[0])}
+    assert matched == ({reader} | ({"decode.attention_ms_per_step.steady"} if reader == "decode.attention_ms_per_step"
+                                   else set()) if reader else set())
+
+
+def test_the_flash_kernels_keep_the_names_the_train_readers_match(one_chip, monkeypatch):
+    """A train step's flash forward and backward, through the functional entry the models call and under
+    ``jax.value_and_grad``: the Mosaic calls are still named after the transformations around them, as
+    ``flash_fwd_roofline`` and ``flash_bwd_roofline`` match them (one forward and two backward kernels a layer)."""
+    from paddlepaddle_tpu.core.dispatch import unwrap, wrap
+    from paddlepaddle_tpu.nn import functional as F
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")      # the route the chip takes
+    shape = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, _ = F.flash_attention(wrap(q), wrap(k), wrap(v), causal=True)
+        return jnp.sum(unwrap(out).astype(jnp.float32))
+
+    calls = _mosaic_calls(jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(shape, shape, shape)
+                          .compile().as_text())
+    fwd = [c for c in calls if re.search(_reader_module("flash_fwd_roofline").PATTERN, c)]
+    bwd = [c for c in calls if re.search(_reader_module("flash_bwd_roofline").PATTERN, c)]
+    assert (len(fwd), len(bwd), len(calls)) == (1, 2, 3), calls
+
+
+def test_the_engines_programs_keep_their_module_names_and_name_their_kernels_calls():
+    """On the CPU, where the kernels run under the interpreter: a latent model's decode program, whole-prompt
+    admission and prefix-hit admission lower to the XLA modules ``jit_run``, ``jit__admit_paged_impl`` and
+    ``jit_impl`` (the names the serving readers match), and each names the kernel calls it traces after itself."""
+    import paddlepaddle_tpu as paddle
+    from paddlepaddle_tpu.inference import compile_plan as cp
+    from paddlepaddle_tpu.inference.decode_engine import BatchDecodeEngine
+    from paddlepaddle_tpu.models import LongcatFlashConfig, LongcatFlashForCausalLM
+
+    paddle.seed(0)
+    eng = BatchDecodeEngine(LongcatFlashForCausalLM(LongcatFlashConfig.tiny()), max_slots=4, max_len=128, chunk=2,
+                            page_size=16, num_pages=40)
+    for key, module, named, other in (
+            (cp.decode_key(), "jit_run", ("paged_latent_attention_decode", "held_experts_decode"), "_admit"),
+            (cp.admit_key(32), "jit__admit_paged_impl", ("held_experts_admit",), "_decode"),
+            (cp.prefix_admit_key(1, 16), "jit_impl", ("held_experts_admit",), "_decode")):
+        lowered = eng._build_program(key).lower(*eng._example_args(key))
+        assert re.search(r"^module @(\S+)", lowered.as_text(), re.M).group(1) == module
+        # a named call's operations stand under its name in the locations
+        calls = set(re.findall(r'loc\("(\w+)/pallas_call"', lowered.as_text(debug_info=True)))
+        assert set(named) <= calls and not any(c.endswith(other) for c in calls), (key, calls)
