@@ -99,6 +99,15 @@ SAMPLE_COUNTERS = ("sample_calls.greedy", "sample_calls.draw",
                    "sample_calls.filter")
 
 
+def _head(model, hidden):
+    """``model``'s output head over final hidden rows ``[..., hidden]``:
+    ``lm_head``, or the tied embedding where there is none. Called inside
+    the step's ``bind_state``."""
+    if model.lm_head is None:
+        return unwrap(hidden) @ unwrap(model.model.embed_tokens.weight).T
+    return unwrap(model.lm_head(hidden))
+
+
 def _bucket(n: int, q: int = 128) -> int:
     return -(-n // q) * q
 
@@ -916,15 +925,25 @@ class BatchDecodeEngine:
         return {"enabled": False} if self.spec is None else self.spec.info()
 
     # -- compiled pieces ----------------------------------------------------
-    def _forward(self, params, toks, caches, pos):
-        """One model step: toks [b, s] -> (logits, caches')."""
+    def _forward(self, params, toks, caches, pos, last=None):
+        """One model step: toks [b, s] -> (logits, caches'). An admission
+        passes ``last``: its logits are then those of hidden row ``last``
+        of its one prompt alone, ``[1, vocab]``, the only row it samples
+        (the final norm is per row, so the row is the one the whole bucket
+        would give). That row enters the head widened to float32 and its
+        logits leave in the row's dtype: XLA rewrites a one-row matmul as
+        a multiply and a reduce in the operands' dtype, which in bfloat16
+        rounds every product and sums the rounded ones, where the matmul
+        over many rows sums exact products in float32."""
         with _ag.no_grad(), self.model.bind_state(params):
             hidden, new_caches = self.model.model(toks, caches=caches, pos=pos)
-            if self.model.lm_head is None:
-                logits = unwrap(hidden) @ unwrap(
-                    self.model.model.embed_tokens.weight).T
+            if last is None:
+                logits = _head(self.model, hidden)
             else:
-                logits = unwrap(self.model.lm_head(hidden))
+                row = jax.lax.dynamic_index_in_dim(unwrap(hidden)[0], last,
+                                                   keepdims=True)
+                logits = _head(self.model,
+                               row.astype(jnp.float32)).astype(row.dtype)
         return logits, [tuple(unwrap(c) for c in nc) for nc in new_caches]
 
     def _view_rung(self, lens, active, span: int):
@@ -1048,11 +1067,7 @@ class BatchDecodeEngine:
                     # switch: the donated pool is updated in place, never
                     # carried through a branch
                     new_pools.append(view.stored(kept))
-            hidden = mdl.norm(x)
-            if self.model.lm_head is None:
-                logits = unwrap(hidden) @ unwrap(mdl.embed_tokens.weight).T
-            else:
-                logits = unwrap(self.model.lm_head(hidden))
+            logits = _head(self.model, mdl.norm(x))
         return logits, new_pools
 
     # static bound of the in-graph per-slot top-k filter: one lax.top_k of
@@ -1138,11 +1153,10 @@ class BatchDecodeEngine:
         No host syncs."""
         bucket = ids.shape[1]
         scratch = self._scratch(bucket, caches[0][0].dtype)
-        logits, scratch = self._forward(params, ids, scratch, jnp.int32(0))
-        row = logits[0, plen - 1]
+        row, scratch = self._forward(params, ids, scratch, jnp.int32(0),
+                                     last=plen - 1)
         key, sub = jax.random.split(key)
-        first = self._sample(row[None], temp[None], top_k[None], sub,
-                             True)[0]
+        first = self._sample(row, temp[None], top_k[None], sub, True)[0]
         zero = jnp.int32(0)
         out_caches = [
             tuple(jax.lax.dynamic_update_slice(
@@ -1166,11 +1180,10 @@ class BatchDecodeEngine:
         npg = pages_needed(bucket, ps)
         pad = npg * ps - bucket
         scratch = self._scratch(bucket, self._kv_dtype)
-        logits, scratch = self._forward(params, ids, scratch, jnp.int32(0))
-        row = logits[0, plen - 1]
+        row, scratch = self._forward(params, ids, scratch, jnp.int32(0),
+                                     last=plen - 1)
         key, sub = jax.random.split(key)
-        first = self._sample(row[None], temp[None], top_k[None], sub,
-                             True)[0]
+        first = self._sample(row, temp[None], top_k[None], sub, True)[0]
         dest = jax.lax.dynamic_slice(page_table, (slot, jnp.int32(0)),
                                      (1, npg))[0]
         # positions past the prompt hold prefill activations for the
@@ -1240,12 +1253,11 @@ class BatchDecodeEngine:
                          jnp.zeros((1, tail_bucket, *c.shape[2:]), dtype)],
                         axis=1)
                     for c in cached))
-            logits, scratch = self._forward(params, ids, scratch,
-                                            jnp.int32(aligned))
-            row = logits[0, tail_plen - 1]
+            row, scratch = self._forward(params, ids, scratch,
+                                         jnp.int32(aligned),
+                                         last=tail_plen - 1)
             key2, sub = jax.random.split(key)
-            first = self._sample(row[None], temp[None], top_k[None], sub,
-                                 True)[0]
+            first = self._sample(row, temp[None], top_k[None], sub, True)[0]
             dest = row_pages[n_pfx:n_pfx + npg_tail]
             valid = (jnp.arange(npg_tail * ps, dtype=jnp.int32)
                      < tail_plen).reshape(npg_tail, ps)[:, :, None, None]

@@ -65,6 +65,7 @@ import numpy as np
 
 from ..core import autograd as _ag
 from ..core.dispatch import unwrap
+from .decode_engine import _head
 
 __all__ = ["SpeculativeDecoder", "resolve_draft"]
 
@@ -75,11 +76,7 @@ def _model_forward(model, params, toks, caches, pos):
     ``BatchDecodeEngine._forward``."""
     with _ag.no_grad(), model.bind_state(params):
         hidden, new_caches = model.model(toks, caches=caches, pos=pos)
-        if model.lm_head is None:
-            logits = unwrap(hidden) @ unwrap(
-                model.model.embed_tokens.weight).T
-        else:
-            logits = unwrap(model.lm_head(hidden))
+        logits = _head(model, hidden)
     return logits, [(unwrap(k), unwrap(v)) for k, v in new_caches]
 
 

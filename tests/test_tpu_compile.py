@@ -6,7 +6,9 @@ may load the TPU's library; every xdist worker imports this file), and every
 such compile lives in this one file. Nothing runs: no number here is a time.
 """
 
+import contextlib
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -117,6 +119,55 @@ def test_the_sampler_stays_a_conditional_on_v5e_and_its_greedy_branch_is_the_arg
     # ... and nothing of the kind is left outside the branches
     outside = _reaches(text, re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1))
     assert " conditional(" in outside and not sorts.search(outside) and not draws.search(outside)
+
+
+class _HeadOnly:
+    """A stand-in for a model at a cell's head shape, so that ``BatchDecodeEngine._forward`` compiles its admission
+    head alone: the body hands its input through as the final hidden states, and the bound state is the head's weight,
+    through ``lm_head`` (``[hidden, vocab]``) or tied (the embedding, ``[vocab, hidden]``)."""
+
+    def __init__(self, tied):
+        self.tied, self.model = tied, self
+
+    @contextlib.contextmanager
+    def bind_state(self, weight):
+        from paddlepaddle_tpu.nn import functional as F
+
+        self.lm_head = None if self.tied else (lambda h: F.linear(h, weight))
+        self.embed_tokens = types.SimpleNamespace(weight=weight)
+        yield
+
+    def __call__(self, hidden, caches, pos):
+        return hidden, []
+
+
+@pytest.mark.parametrize("hidden, vocab, tied", [(7168, 20480, False), (6144, 16384, False), (4096, 32768, False),
+                                                 (4096, 32768, True)])   # longdoc, agent, chat and docqa; tied
+def test_an_admissions_one_row_head_multiplies_and_sums_in_float32_on_v5e(one_chip, hidden, vocab, tied):
+    """An admission's head over its one sampled row, at a cell's head shape: XLA:TPU rewrites a one-row matmul as a
+    multiply and a reduce, and these must run in float32 (in bfloat16 every product would be rounded and the rounded
+    ones summed), reading the bfloat16 weight where it lies: no float32 copy of it is materialised outside the
+    fusion."""
+    from paddlepaddle_tpu.inference.decode_engine import BatchDecodeEngine
+
+    engine = types.SimpleNamespace(model=_HeadOnly(tied))
+    fn = lambda w, h, last: BatchDecodeEngine._forward(engine, w, h, [], 0, last=last)[0]
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (shape((vocab, hidden) if tied else (hidden, vocab), jnp.bfloat16), shape((1, 8448, hidden), jnp.bfloat16),
+            shape((), jnp.int32))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    entry_name = re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1)
+    entry = text[text.index(f"ENTRY %{entry_name} "):]
+    entry = entry[:entry.index("\n}\n")]
+    assert f"-> bf16[1,{vocab}]" in entry                                  # the logits leave in the weight's dtype
+    weight = rf"\[({hidden},{vocab}|{vocab},{hidden})\]"
+    assert not re.search(r"= f32" + weight, entry), entry                  # no float32 copy of the weight
+    fusions = re.findall(r"= \S+ fusion\(%w[\w.]*,.*?calls=%([\w.\-]+)", entry)
+    assert len(fusions) == 1, entry                                        # the weight enters one fusion as it is
+    body = _reaches(text, fusions[0])
+    assert re.search(r"= f32" + weight + r"\S* multiply\(", body), body    # float32 products ...
+    assert " reduce(" in body and re.search(r"= f32\[\]\S* add\(", body) # ... summed in float32
+    assert not re.search(r"= bf16" + weight + r"\S* multiply\(", body) and not re.search(r"= bf16\[\]\S* add\(", body)
 
 
 @pytest.mark.parametrize("rows, d, width, held", [(32, 7168, 2048, 12), (128, 6144, 2048, 16)])   # longdoc, agent
