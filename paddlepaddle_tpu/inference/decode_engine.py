@@ -70,14 +70,15 @@ from ..core.dispatch import unwrap
 from ..observability.recorder import phase, phase_counters
 from ..ops.kernels import traced_by
 from ..ops.kernels.paged_gqa_attention import (paged_gqa_attention,
-                                               reads_in_place)
+                                               reads_in_place, token_rows)
 from ..ops.kernels.paged_latent_attention import (lane_whole,
                                                   paged_latent_attention,
                                                   pages_walked)
 from ..parallel.moe import ExpertShareLayer, PickTap
 from . import compile_plan as _cp
-from .kv_pool import (PagePool, PrefixCache, cache_spec_of, pages_needed,
-                      prefix_hash, spec_bytes_per_token)
+from .kv_pool import (PagePool, PrefixCache, cache_spec_of, page_classes,
+                      pages_needed, prefix_hash, ring_pages,
+                      spec_bytes_per_token)
 from .robustness import KVCapacityError
 from .robustness import safe_inc as _safe_inc
 from .robustness import safe_set as _safe_set
@@ -415,15 +416,17 @@ class _PagedView:
     sharding plan, whose program GSPMD partitions)."""
 
     __slots__ = ("ladder", "page_size", "kv_dtype", "pools", "page_table",
-                 "rung", "phys", "off", "walk")
+                 "rung", "phys", "off", "walk", "window")
 
-    def __init__(self, eng, pools, page_table, rung, phys, off, walk=None):
+    def __init__(self, eng, pools, page_table, rung, phys, off, walk=None,
+                 window=0):
         self.ladder, self.page_size = eng._ladder, eng.page_size
         self.kv_dtype = eng._kv_dtype
         self.pools, self.page_table, self.rung = pools, page_table, rung
         self.phys, self.off, self.walk = phys, off, walk
+        self.window = window      # > 0: ``page_table`` is the class's ring
 
-    def attend(self, q, k_new, v_new, pos, n_rep, scale):
+    def attend(self, q, k_new, v_new, pos, n_rep, scale, sink=None):
         """(out, K rows, V rows), the new rows in the pool's dtype. A decode
         step (one query row a head) over plain pools whose rows are whole
         lane tiles IS the kernel that walks each slot's pages in place
@@ -435,7 +438,11 @@ class _PagedView:
         FIRST, outside the switch (the donated pool is never carried through
         a branch), so that the attention reads the bytes the next step will
         read (:func:`_attend_view_int8`), and the updated pairs come back in
-        place of rows."""
+        place of rows. A layer of a window class (``window``: its table is
+        the class's ring), a ``sink`` (``[H]`` logits) and pools of one row
+        a token (``paged_gqa_attention.token_rows``, rows come back as the
+        pool holds them) are the kernel's alone: the decode step's one query
+        row with a walk, nothing else."""
         kp, vp = self.pools
         if isinstance(kp, tuple):
             kq, ksc = _kv_quant_scatter(*kp, k_new.astype(self.kv_dtype),
@@ -448,6 +455,19 @@ class _PagedView:
                                self.page_size, self.kv_dtype, n_rep, scale),
                 q, kq, ksc, vq, vsc, self.page_table, pos)
             return (out, (kq, ksc), (vq, vsc))
+        if self.window or sink is not None or token_rows(kp):
+            if self.walk is None or q.shape[1] != 1:
+                raise NotImplementedError(
+                    "a window, a sink or pools of one row a token are read "
+                    "by the decode step's page walk alone (one query row, "
+                    "walk lengths given)")
+            out = paged_gqa_attention(
+                q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp, self.page_table,
+                self.walk, scale=scale, window=self.window,
+                sink=sink)[:, None]
+            S, W = k_new.shape[:2]
+            return (out, k_new.reshape(S, W, *kp.shape[2:]).astype(kp.dtype),
+                    v_new.reshape(S, W, *vp.shape[2:]).astype(vp.dtype))
         if self.walk is not None and q.shape[1] == 1 and reads_in_place(kp):
             out = paged_gqa_attention(
                 q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp, self.page_table,
@@ -494,6 +514,35 @@ class _PagedView:
             for p, k in zip(self.pools, kept))
 
 
+class _WindowClass:
+    """The pages of the layers whose cache declares one window
+    (``kv_pool.page_classes``): a pool of their own, ``ring`` pages a slot
+    (``kv_pool.ring_pages``), and a ``[slots, ring]`` table whose row is a
+    ring: a slot's logical page ``j`` lives in entry ``j % ring``. A slot's
+    pages are taken at admission and given back at retirement; in between
+    the ring hands the entry of a page that fell wholly behind the window to
+    the page a new position opens."""
+
+    __slots__ = ("window", "ring", "layers", "pool", "table", "slot_pages")
+
+    def __init__(self, window, ring, layers, slots, page_size):
+        self.window, self.ring, self.layers = int(window), int(ring), layers
+        self.pool = PagePool(slots * self.ring + 1, page_size)
+        self.table = jnp.zeros((slots, self.ring), jnp.int32)
+        self.slot_pages: List[List[int]] = [[] for _ in range(slots)]
+
+    def first_page(self, held, page_size: int):
+        """The logical page of the first key a query reads after ``held``
+        keys: that of position ``held - window + 1``."""
+        return jnp.maximum(held - self.window + 1, 0) // page_size
+
+    def pages_read(self, held, page_size: int):
+        """Pages of a slot's ring that hold a key the query after ``held``
+        keys reads (0 for an empty slot)."""
+        return jnp.where(held > 0, -(-held // page_size)
+                         - self.first_page(held, page_size), 0)
+
+
 class _Slot:
     __slots__ = ("req", "emitted", "budget", "temp", "top_k", "spec_steps",
                  "spec_accepted")
@@ -534,11 +583,24 @@ class BatchDecodeEngine:
         self.cache_spec = cache_spec_of(model)
         self._latent = any(p.role == "latent"
                            for layer in self.cache_spec for p in layer)
+        self._windowed = any(p.window for layer in self.cache_spec
+                             for p in layer)
         if mesh is not None or plan is not None:
             self._refuse_latent(
                 "a tensor-parallel plan or mesh",
                 "the plan shards a pool on its kv heads and a latent row has "
                 "one; sharding the up-projections by head is not built")
+            self._refuse_window(
+                "a tensor-parallel plan or mesh",
+                "GSPMD cannot partition the page walk that is the window "
+                "class's only reader, and the gathered view reads no ring")
+        if prefix_cache and kv_layout == "paged":
+            self._refuse_window(
+                "prefix sharing (prefix_cache=True)",
+                "a prefix's window-class pages are the ring of the slot that "
+                "computed them, overwritten as it decodes; keeping a "
+                "prefix's window tail with its entry is not built: pass "
+                "prefix_cache=False")
         self.S = int(max_slots)
         self.L = int(max_len or cfg.max_position_embeddings)
         self.chunk = int(chunk)
@@ -600,6 +662,9 @@ class BatchDecodeEngine:
         if kv_quant in ("", "off"):
             kv_quant = None
         if kv_quant is not None:
+            self._refuse_window(
+                "kv_quant", "the int8 pages keep one layout of [kv heads, "
+                "head size] rows and one table; a ring of them is not built")
             self._refuse_latent(
                 "kv_quant",
                 "an int8 page carries one scale a kv head, and a latent row "
@@ -652,6 +717,11 @@ class BatchDecodeEngine:
             self.prefix_enabled = bool(prefix_cache)
             self.page_table = jnp.zeros((self.S, self.P), jnp.int32)
             self._ladder = _view_ladder(self.P)
+            self.window_classes = [
+                _WindowClass(w, min(ring_pages(w, self.page_size), self.P),
+                             layers, self.S, self.page_size)
+                for w, layers in sorted(page_classes(self.cache_spec).items())
+                if w]
             if self.kv_quant == "int8":
                 # each pool entry is (codes int8, scale f32 [pages, kvh]):
                 # a nested pytree, so program args / scan carries /
@@ -666,10 +736,13 @@ class BatchDecodeEngine:
                       jnp.zeros((n_pages, kvh), jnp.float32)))
                     for _ in range(cfg.num_hidden_layers)]
             else:
+                pages_of = {i: wc.pool.num_pages for wc in self.window_classes
+                            for i in wc.layers}
                 self.caches = [
-                    tuple(jnp.zeros((n_pages, self.page_size, *p.row), dtype)
+                    tuple(jnp.zeros((pages_of.get(i, n_pages),
+                                     self.page_size, *p.row), dtype)
                           for p in layer)
-                    for layer in self.cache_spec]
+                    for i, layer in enumerate(self.cache_spec)]
             if kv_host_bytes is None:
                 kv_host_bytes = int(
                     _flag_value("serving_kv_host_bytes") or 0)
@@ -697,6 +770,8 @@ class BatchDecodeEngine:
             self.prefix_enabled = False
             self.page_table = None
             self._ladder = ()
+            # a window's layers mask their dense caches themselves
+            self.window_classes = []
             self.caches = [
                 tuple(jnp.zeros((self.S, self.L, *p.row), dtype)
                       for p in layer)
@@ -707,6 +782,12 @@ class BatchDecodeEngine:
         self._walks_pairs = (
             kv_layout == "paged" and not self._latent and self.plan is None
             and all(reads_in_place(p) for p in self.caches[0]))
+        if self.window_classes and not all(
+                reads_in_place(p) for layer in self.caches for p in layer):
+            raise ValueError(
+                "a window class's pools must be read in place by the page "
+                "walk (rows of whole lane tiles: kv_pool.PoolSpec); the "
+                "gathered view reads no ring")
         if self.plan is not None:
             # commit the pools (kv heads on "mp") and every host-rebuilt
             # array (replicated): deterministic placements, so the jitted
@@ -755,7 +836,14 @@ class BatchDecodeEngine:
             moe_layer_steps=0, moe_experts_held=self._experts_held,
             moe_expert_pairs=(0,) * self._experts_held)
         self.stats.update(picks)
-        self.pick_stat_keys = tuple(picks)     # the serving loop copies these
+        # a window class's counters (:meth:`_window_columns`) ride the same
+        # payload
+        windows = {} if not self.window_classes else dict(
+            window_pages_walked_max=0, window_pages_live_peak=0,
+            window_pages_released=0)
+        self.stats.update(windows)
+        # the serving loop copies these
+        self.pick_stat_keys = tuple(picks) + tuple(windows)
         # perf_counter at the return of the last chunk's sync, until the
         # next compiled program is called (or the loop waits for work)
         self._t_synced: Optional[float] = None
@@ -766,6 +854,10 @@ class BatchDecodeEngine:
         # at any nonzero acceptance rate. See inference/speculative.py.
         self.spec = None
         if draft is not None or spec_k:
+            self._refuse_window(
+                "speculative drafts (draft=/spec_k=)",
+                "the verify's W-wide call reads a gathered view, and the "
+                "window class is read by the page walk alone")
             self._refuse_latent(
                 "speculative drafts (draft=/spec_k=)",
                 "the draft decoder builds K/V pair caches from its own kv "
@@ -797,6 +889,12 @@ class BatchDecodeEngine:
             # the lazy build path — a deploy with a bad artifact serves
             # slow, it does not crash-loop
             self.load_serving_bundle(bundle)
+
+    def _refuse_window(self, what: str, why: str) -> None:
+        """What a window class of pages cannot do yet refuses at
+        construction, with its reason, as :meth:`_refuse_latent` does."""
+        if self._windowed:
+            raise ValueError(f"{what} with a window class of KV pages: {why}")
 
     def _refuse_latent(self, what: str, why: str) -> None:
         """What a latent cache row cannot do yet refuses at construction,
@@ -869,7 +967,14 @@ class BatchDecodeEngine:
         byte; their page scales are counted in ``page_bytes``)."""
         itemsize = (1 if self.kv_quant == "int8"
                     else np.dtype(self._kv_dtype).itemsize)
-        return spec_bytes_per_token(self.cache_spec, itemsize)
+        return spec_bytes_per_token(self._class_spec(0), itemsize)
+
+    def _class_spec(self, window: int):
+        """The cache spec's layers of one class of pages (0: the full one)."""
+        if not self.window_classes:
+            return self.cache_spec
+        return [layer for layer in self.cache_spec
+                if layer[0].window == window]
 
     def kv_stats(self) -> Dict[str, object]:
         """KV-pool snapshot for ``health()``/``/healthz`` and the serving
@@ -916,7 +1021,22 @@ class BatchDecodeEngine:
                 "evictions": pfx.evictions,
             },
             "host": host,
+            **({} if not self.window_classes else
+               {"window_classes": [self._window_stats(wc)
+                                   for wc in self.window_classes]}),
         }
+
+    def _window_stats(self, wc) -> Dict[str, object]:
+        """One window class's block of :meth:`kv_stats`: its pool's pages,
+        a slot's ring and what a page of it holds (``pages_peak`` counts pages
+        taken for rings; ``stats`` carries the walk's counters)."""
+        itemsize = np.dtype(self._kv_dtype).itemsize
+        per_tok = spec_bytes_per_token(self._class_spec(wc.window), itemsize)
+        return {"window": wc.window, "layers": list(wc.layers),
+                "ring_pages": wc.ring, "pages_total": wc.pool.usable,
+                "pages_used": wc.pool.used, "pages_peak": wc.pool.peak_used,
+                "bytes_per_token": per_tok,
+                "kv_bytes": int(wc.pool.num_pages * self.page_size * per_tok)}
 
     def spec_info(self) -> Dict[str, object]:
         """The ``spec`` block of ``health()``/``/healthz``: draft config,
@@ -987,6 +1107,35 @@ class BatchDecodeEngine:
         pages = pages_walked(lens + span, self.page_size, self.P)
         return jnp.where(active, pages, 0).astype(jnp.int32)[:, None]
 
+    def _window_columns(self, wc, lens, lens_, active, active_,
+                        n_steps: int):
+        """A window class's ``[S, 3]`` columns of a decode call's payload,
+        per slot: the most pages of its ring one step's walk read (over the
+        steps it was live: held keys ``lens .. lens_ - 1``), the pages that
+        hold a key the next query reads (0 where the slot ended), and the
+        pages that fell wholly behind the window during the call (the ring
+        hands their entries on)."""
+        ps = self.page_size
+        held = lens[:, None] + jnp.arange(n_steps, dtype=jnp.int32)[None, :]
+        stepped = held < lens_[:, None]
+        walked = jnp.max(jnp.where(stepped, wc.pages_read(held, ps), 0),
+                         axis=1)
+        live = jnp.where(active_, wc.pages_read(lens_, ps), 0)
+        released = jnp.where(active, wc.first_page(lens_, ps)
+                             - wc.first_page(lens, ps), 0)
+        return jnp.stack([walked, live, released], axis=1).astype(jnp.int32)
+
+    def _count_windows(self, columns) -> None:
+        """Host half of :meth:`_window_columns`, every class's three."""
+        st = self.stats
+        for c in range(len(self.window_classes)):
+            walked, live, released = columns[:, 3 * c:3 * c + 3].T
+            st["window_pages_walked_max"] = max(
+                st["window_pages_walked_max"], int(walked.max()))
+            st["window_pages_live_peak"] = max(
+                st["window_pages_live_peak"], int(live.sum()))
+            st["window_pages_released"] += int(released.sum())
+
     def _pick_columns(self, counts):
         """The expert shares' counters of a decode call (``pick_counts`` and
         the live layer-steps, summed over its steps) as whole ``[S, n]``
@@ -1045,6 +1194,9 @@ class BatchDecodeEngine:
         the pool takes the new rows as projected, not read back from the
         view."""
         S, ps, P, L = self.S, self.page_size, self.P, self.L
+        rings = ()
+        if self.window_classes:      # (the full table, each class's ring)
+            page_table, rings = page_table
         W = toks.shape[1]
         rows = jnp.arange(S, dtype=jnp.int32)[:, None]         # [S, 1]
         pos = lens[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
@@ -1053,15 +1205,22 @@ class BatchDecodeEngine:
             pos < L,
             page_table[jnp.broadcast_to(rows, pos.shape), page_idx], 0)
         off = pos % ps
+        views = {}
+        for wc, ring in zip(self.window_classes, rings):
+            at = ring[jnp.broadcast_to(rows, pos.shape), (pos // ps) % wc.ring]
+            for i in wc.layers:
+                views[i] = (ring, jnp.where(pos < L, at, 0), wc.window)
         with _ag.no_grad(), self.model.bind_state(params):
             mdl = self.model.model
             x = mdl.embed_tokens(toks)
             cos, sin = mdl.rope_cos, mdl.rope_sin
             new_pools = []
             with tap if tap is not None else contextlib.nullcontext():
-                for layer, layer_pools in zip(mdl.layers, pools):
-                    view = _PagedView(self, layer_pools, page_table, rung,
-                                      phys, off, walk)
+                for i, (layer, layer_pools) in enumerate(zip(mdl.layers,
+                                                             pools)):
+                    table, at, window = views.get(i, (page_table, phys, 0))
+                    view = _PagedView(self, layer_pools, table, rung, at, off,
+                                      walk, window)
                     x, kept = layer(x, cos, sin, None, pos=lens, cache=view)
                     # the write to the physical pool stays outside the
                     # switch: the donated pool is updated in place, never
@@ -1175,6 +1334,9 @@ class BatchDecodeEngine:
         scattered PAGE-BY-PAGE to the physical pages the host wrote into
         this slot's page-table row before the call. Scratch positions past
         the slot's reservation hit row entries of 0 — the null page."""
+        rings = ()
+        if self.window_classes:      # (the full table, each class's ring)
+            page_table, rings = page_table
         bucket = ids.shape[1]
         ps = self.page_size
         npg = pages_needed(bucket, ps)
@@ -1191,11 +1353,40 @@ class BatchDecodeEngine:
         # attention mask already hides them; decode overwrites them)
         valid = (jnp.arange(npg * ps, dtype=jnp.int32)
                  < plen).reshape(npg, ps)[:, :, None, None]
+        ringed = {i: (wc, ring) for wc, ring in zip(self.window_classes, rings)
+                  for i in wc.layers}
         out_pools = [self._store_pages(layer, scr, dest, npg, pad, valid)
-                     for layer, scr in zip(pools, scratch)]
+                     if i not in ringed else
+                     self._store_window_tail(layer, scr, *ringed[i], slot,
+                                             plen)
+                     for i, (layer, scr) in enumerate(zip(pools, scratch))]
         return self._set_slot_state(out_pools, lens, tokens, active, temps,
                                     eos_ids, budgets, top_ks, key, slot,
                                     plen, temp, eos, budget, top_k, first)
+
+    def _store_window_tail(self, layer_pools, rows, wc, ring, slot, plen):
+        """One window layer's pools with the pages of a prefill's rows (per
+        pool ``[1, tokens, *row]``) that hold its last ``window`` positions,
+        each written to its entry of the slot's ring: logical page ``j`` to
+        entry ``j % ring``, the latest such page at or before the prompt's
+        last one. An entry whose page holds none of them (behind the window,
+        or before position 0) writes the null page."""
+        ps, B = self.page_size, wc.ring
+        with jax.named_scope("window_tail_store"):
+            last = (plen - 1) // ps
+            j = last - (last - jnp.arange(B, dtype=jnp.int32)) % B
+            keep = (j >= 0) & ((j + 1) * ps > plen - wc.window)
+            row = jax.lax.dynamic_slice(ring, (slot, jnp.int32(0)),
+                                        (1, B))[0]
+            dest = jnp.where(keep, row, 0)
+            out = []
+            for p, r in zip(layer_pools, rows):
+                n = pages_needed(r.shape[1], ps)
+                r = jnp.pad(r[0], ((0, n * ps - r.shape[1]),)
+                            + ((0, 0),) * (r.ndim - 2))
+                pages = r.reshape(n, ps, *r.shape[1:])
+                out.append(p.at[dest].set(pages[jnp.clip(j, 0, n - 1)]))
+            return tuple(out)
 
     def _store_pages(self, layer_pools, rows, dest, npg: int, pad: int,
                      valid):
@@ -1347,6 +1538,9 @@ class BatchDecodeEngine:
                 cols.append(self._walk_pages_column(lens, active, n_steps)
                             if latent or walks
                             else self._view_pages_column(rung))
+            for wc in self.window_classes:
+                cols.append(self._window_columns(wc, lens, lens_, active,
+                                                 active_, n_steps))
             if picks is not None:
                 cols.append(self._pick_columns(picks.sum(0)))
             packed = jnp.concatenate(cols, axis=1)  # [slots, n_steps+1(+1)]
@@ -1402,13 +1596,20 @@ class BatchDecodeEngine:
             self._programs[key] = fn
         return fn
 
+    def _tables(self):
+        """What the paged programs take as their page table: the table, or,
+        with window classes, ``(table, (each class's ring table, ...))``."""
+        if not self.window_classes:
+            return self.page_table
+        return (self.page_table, tuple(wc.table for wc in self.window_classes))
+
     def _decode_args(self) -> tuple:
         """THE decode program's argument tuple — shared by the serve path
         (_decode_chunk) and the plan seam (warmup/bundle lowering), so an
         AOT Compiled can never be specialized to avals the serve path
         doesn't pass."""
         if self.kv_layout == "paged":
-            return (self.params, self.caches, self.page_table, self.tokens,
+            return (self.params, self.caches, self._tables(), self.tokens,
                     self.lens, self.active, self.temps, self.eos_ids,
                     self.budgets, self.top_ks, self.key)
         return (self.params, self.caches, self.tokens, self.lens,
@@ -1426,7 +1627,7 @@ class BatchDecodeEngine:
         tail = (ids, jnp.int32(plen), jnp.int32(slot), jnp.float32(temp),
                 jnp.int32(eos), jnp.int32(budget), jnp.int32(top_k),
                 self.key)
-        head = ((self.params, self.caches, self.page_table)
+        head = ((self.params, self.caches, self._tables())
                 if kind == "prefix" or self.kv_layout == "paged"
                 else (self.params, self.caches))
         return head + state + tail
@@ -1561,6 +1762,11 @@ class BatchDecodeEngine:
                 for slot in range(self.S):
                     pt = pt.at[slot].set(zrow)
                 pt.block_until_ready()
+                for wc in self.window_classes:
+                    pt, zrow = wc.table, jnp.zeros((wc.ring,), jnp.int32)
+                    for slot in range(self.S):
+                        pt = pt.at[slot].set(zrow)
+                    pt.block_until_ready()
             act = self.active
             for slot in range(self.S):
                 act = act.at[slot].set(False)
@@ -1882,6 +2088,14 @@ class BatchDecodeEngine:
             else:
                 row[:len(private)] = private
             self.page_table = self.page_table.at[slot].set(jnp.asarray(row))
+            for wc in self.window_classes:
+                # a free slot always finds its ring: the pool holds one a slot
+                ring = wc.pool.alloc(min(wc.ring, pages_needed(
+                    plen + req.max_new_tokens, self.page_size)))
+                wc.slot_pages[slot] = ring
+                wrow = np.zeros((wc.ring,), np.int32)
+                wrow[:len(ring)] = ring
+                wc.table = wc.table.at[slot].set(jnp.asarray(wrow))
             self._kv_gauges()
         if self.kv_layout == "paged" and entry is not None:
             # HIT: prefill only the tail against the cached prefix pages
@@ -2038,6 +2252,13 @@ class BatchDecodeEngine:
         if h is not None:
             self.prefix.unref(h)
             self._slot_prefix[slot] = None
+        for wc in self.window_classes:
+            if wc.slot_pages[slot]:
+                wc.pool.free(wc.slot_pages[slot])
+                wc.slot_pages[slot] = []
+            if zero_row:
+                wc.table = wc.table.at[slot].set(
+                    jnp.zeros((wc.ring,), jnp.int32))
         if zero_row:
             self.page_table = self.page_table.at[slot].set(
                 jnp.zeros((self.P,), jnp.int32))
@@ -2120,6 +2341,8 @@ class BatchDecodeEngine:
                     self._release_kv(i, zero_row=False)
                 self.page_table = self._repl(
                     jnp.zeros((self.S, self.P), jnp.int32))
+                for wc in self.window_classes:
+                    wc.table = jnp.zeros((self.S, wc.ring), jnp.int32)
         else:
             for i in slots:
                 self.active = self.active.at[int(i)].set(False)
@@ -2271,8 +2494,11 @@ class BatchDecodeEngine:
             em, act = pk[:, :self.chunk], pk[:, self.chunk].astype(bool)
             if self.kv_layout == "paged":
                 self._count_view(pk[:, self.chunk + 1])
+                picks_at = self.chunk + 2 + 3 * len(self.window_classes)
+                if self.window_classes:
+                    self._count_windows(pk[:, self.chunk + 2:picks_at])
                 if self._experts_held:
-                    self._count_picks(pk[:, self.chunk + 2:])
+                    self._count_picks(pk[:, picks_at:])
             for slot, s in enumerate(self._host_slots):
                 if s.req is None:
                     continue

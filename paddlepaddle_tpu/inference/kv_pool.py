@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = ["PagePool", "PrefixCache", "PrefixEntry", "HostPrefixTier",
            "HostSlab", "PoolSpec", "cache_spec_of", "spec_bytes_per_token",
+           "layer_window", "page_classes", "ring_pages",
            "pages_needed", "prefix_hash",
            "serialize_page_slab", "deserialize_page_slab"]
 
@@ -43,10 +44,16 @@ class PoolSpec(NamedTuple):
     shared by every head, ``[width]``: a latent-attention block's row; a
     unit axis for the heads it does not have would sit in the pool's tiled
     minor pair, and the TPU's compiler then re-lays the whole pool out at
-    every program's entry and exit, a second copy of it in memory)."""
+    every program's entry and exit, a second copy of it in memory).
+    ``window`` > 0 declares that the layer's queries see only the last
+    ``window`` keys (their own included): the pool then belongs to the
+    window's class of pages, a ring of :func:`ring_pages` pages a slot with
+    its own table (:func:`page_classes`); 0 is the full class, every
+    position a slot holds."""
 
     role: str
     row: Tuple[int, ...]
+    window: int = 0
 
 
 def cache_spec_of(model) -> List[Tuple[PoolSpec, ...]]:
@@ -68,6 +75,37 @@ def cache_spec_of(model) -> List[Tuple[PoolSpec, ...]]:
 def spec_bytes_per_token(spec, itemsize: int) -> int:
     """Bytes one cached token takes over every pool of every layer."""
     return sum(math.prod(p.row) * itemsize for layer in spec for p in layer)
+
+
+def layer_window(layer) -> int:
+    """The window a layer's pools declare (0: the full class). A layer's
+    pools are of one class."""
+    windows = {p.window for p in layer}
+    if len(windows) != 1:
+        raise ValueError(f"one layer's pools declare windows {sorted(windows)}: "
+                         "a layer's pools are of one class")
+    return windows.pop()
+
+
+def page_classes(spec) -> Dict[int, List[int]]:
+    """The cache's classes of pages: window (0 for the full class) -> the
+    layers whose pools are of it, in layer order. A spec that declares no
+    window has the one full class."""
+    out: Dict[int, List[int]] = {}
+    for i, layer in enumerate(spec):
+        out.setdefault(layer_window(layer), []).append(i)
+    return out
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages a slot holds in a window's class: the pages ``window``
+    consecutive positions can touch, ``ceil((window - 1) / page_size) + 1``
+    (3 for 128 over pages of 64). Logical page ``j`` of a slot lives in
+    entry ``j % ring_pages`` of its row, so the page a new position opens
+    takes the entry of a page whose keys all lie behind the window: a decode
+    step reads its window, then writes its one new row, and the next step's
+    window starts past every key the write overwrote."""
+    return pages_needed(int(window) - 1, page_size) + 1
 
 
 def pages_needed(tokens: int, page_size: int) -> int:

@@ -12,6 +12,9 @@ define-by-run code runs eagerly and traces to one XLA program via
   shortcut-connected double layer; served with one chip's share of the experts
 * kimi_k2 — the same latent block (latent_attention.py), a dense leading layer,
   sigmoid-routed experts with a shared expert, YaRN positions; served likewise
+* mimo_v2 — window and full attention layers mixed (a class of KV pages each),
+  sink logits, 192-wide keys over 128-wide values, sigmoid-routed experts;
+  served likewise
 """
 
 from .bert import (  # noqa: F401
@@ -34,6 +37,10 @@ from .llama import (  # noqa: F401
     LlamaForCausalLM,
     LlamaModel,
     llama_sharding_rules,
+)
+from .mimo_v2 import (  # noqa: F401
+    MimoV2Config,
+    MimoV2ForCausalLM,
 )
 from .longcat_flash import (  # noqa: F401
     LongcatFlashConfig,

@@ -80,26 +80,58 @@ def pages_per_chunk(page_size: int, table_pages: int) -> int:
 def reads_in_place(pool) -> bool:
     """Whether the kernel can copy pages out of ``pool`` as it lies: a plain
     array (not an int8 ``(codes, scales)`` pair) whose rows are whole lane
-    tiles. The same answer on and off the chip, so what the CPU tests serve
-    through the kernel is what the chip serves through it."""
+    tiles, a row a (token, kv head) or a row a token (:func:`token_rows`).
+    The same answer on and off the chip, so what the CPU tests serve through
+    the kernel is what the chip serves through it."""
     return not isinstance(pool, tuple) and pool.shape[-1] % LANES == 0
 
 
-def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, first_ref, m_ref, l_ref, acc_ref, *, K, ps, kvh,
-            rep, scale, slots, table_pages):
+def token_rows(pool) -> bool:
+    """Whether ``pool`` keeps one row a token, ``[pages, page_size, kvh *
+    width]``, every kv head's side by side: how a model declares keys of a
+    width that is no whole number of lane tiles (192), whose ``[kvh, 192]``
+    rows the TPU would lay out with the kv heads padded to a tile and the
+    width to two (the kernel's view of a page as rows would then be a copy
+    of the whole pool at every call)."""
+    return pool.ndim == 3
+
+
+def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, *refs, K, ps, kvh,
+            rep, scale, slots, table_pages, window=0, sink=False,
+            tokens=False):
     """Grid (slot,): online softmax over the slot's live chunks of K pages,
-    then over the new row."""
+    then over the new row. With a ``window`` the table row is a ring and the
+    walk starts at the page of the window's first key; with a ``sink`` each
+    head's softmax starts from its sink logit (``m = b_h``, ``l = 1``);
+    with ``tokens`` a page is a row a token (:func:`token_rows`)."""
+    if sink:
+        sink_ref, *refs = refs
+    (k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, first_ref, m_ref, l_ref,
+     acc_ref) = refs
     s = pl.program_id(0)
     span = K * ps                         # tokens a chunk
-    page_rows = ps * kvh                  # (token, kv head) rows a page
+    page_rows = ps if tokens else ps * kvh    # rows a page
     held = lens_ref[s]                    # keys in the pool: positions < held
-    n = (held + span - 1) // span         # live chunks
+
+    def base(keys):
+        """Position of the first row of a slot's chunk 0: the page of the
+        window's first key (the query at ``keys`` sees ``keys - window + 1``
+        on)."""
+        return jnp.maximum(keys - window + 1, 0) // ps * ps
+
+    if window:
+        n = (held - base(held) + span - 1) // span
+    else:
+        n = (held + span - 1) // span     # live chunks
 
     def copies(slot, chunk, buf, k):
         """The two copies of page ``k`` of ``slot``'s ``chunk`` into its rows
         of buffer ``buf``."""
-        page = pt_ref[slot, jnp.minimum(chunk * K + k, table_pages - 1)]
+        if window:                        # a ring: logical page j in entry j % entries
+            page = pt_ref[slot, (base(lens_ref[slot]) // ps + chunk * K + k)
+                          % table_pages]
+        else:
+            page = pt_ref[slot, jnp.minimum(chunk * K + k, table_pages - 1)]
         rows = pl.ds(pl.multiple_of(k * page_rows, page_rows), page_rows)
         return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, rows],
                                       sem.at[0, buf]),
@@ -117,6 +149,8 @@ def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
 
         def run(slot, chunk, buf):
             keys = lens_ref[slot] - chunk * span
+            if window:
+                keys = keys - base(lens_ref[slot])
             jax.lax.fori_loop(0, jnp.minimum(K, (keys + ps - 1) // ps), page,
                               (slot, chunk, buf))
         return run
@@ -139,8 +173,12 @@ def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
     after = jnp.minimum(s + 1, slots - 1)
     after_live = (s + 1 < slots) & (lens_ref[after] > 0)
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if sink:
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def fold(scores, weigh):
@@ -155,12 +193,21 @@ def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
         m_ref[...] = m_new
 
     q = q_ref[0]                                              # [H, hd]
-    H = q.shape[0]
-    # row t * kvh + g of a chunk is token t's key for kv head g: a query head
-    # keeps the rows of its own kv head
-    row = jax.lax.broadcasted_iota(jnp.int32, (H, span * kvh), 1)
-    own = (row % kvh) == (jax.lax.broadcasted_iota(
-        jnp.int32, (H, span * kvh), 0) // rep)
+    H, hd = q.shape
+    if tokens:
+        # kv head g's key is lanes g * hd .. of a token's row: each kv head's
+        # query heads against its own lanes, one matmul a kv head
+        hv = vn_ref.shape[-1]
+        heads = [q_ref[0, g * rep:(g + 1) * rep, :] for g in range(kvh)]
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, span), 1)
+        own, per_row = None, 1
+    else:
+        # row t * kvh + g of a chunk is token t's key for kv head g: a query
+        # head keeps the rows of its own kv head
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, span * kvh), 1)
+        own = (row % kvh) == (jax.lax.broadcasted_iota(
+            jnp.int32, (H, span * kvh), 0) // rep)
+        per_row = kvh
 
     def chunk(i, carry):
         cur = (first + i) % 2
@@ -174,16 +221,37 @@ def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
             start(after, 0, 1 - cur)
 
         wait(s, i, cur)
-        k, v = kbuf[cur], vbuf[cur]                           # [span * kvh, hd]
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
+        k, v = kbuf[cur], vbuf[cur]      # [span * kvh, hd]; tokens: [span, kvh * hd]
+        if tokens:
+            sc = jnp.concatenate([jax.lax.dot_general(
+                heads[g], k[:, g * hd:(g + 1) * hd], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) for g in range(kvh)], 0)
+        else:
+            sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        sc = sc * scale
         # a chunk that holds a key holds its first: no head's row is all
         # masked, so every masked p is exp(-1e30 - m) = 0 exactly
-        live = own & (row < (held - i * span) * kvh)
-        fold(jnp.where(live, sc, NEG_INF),
-             lambda p: jax.lax.dot_general(
-                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                 preferred_element_type=jnp.float32))
+        if window:
+            at = base(held) + i * span    # position of the chunk's row 0
+            live = ((row < (held - at) * per_row)
+                    & (row >= (held - window + 1 - at) * per_row))
+        else:
+            live = row < (held - i * span) * per_row
+        if own is not None:
+            live = own & live
+
+        def weigh(p):
+            if not tokens:
+                return jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            return jnp.concatenate([jax.lax.dot_general(
+                p[g * rep:(g + 1) * rep].astype(v.dtype),
+                v[:, g * hv:(g + 1) * hv], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) for g in range(kvh)], 0)
+
+        fold(jnp.where(live, sc, NEG_INF), weigh)
         return carry
 
     jax.lax.fori_loop(0, n, chunk, 0)
@@ -196,42 +264,57 @@ def _kernel(pt_ref, lens_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
     # the step's own row, the last key: one row a head, so products and sums
     # on the vector unit; never masked, so l > 0
     f32 = jnp.float32
-    kn, vn = kn_ref[0].astype(f32), vn_ref[0].astype(f32)     # [H, hd]
+    kn, vn = kn_ref[0].astype(f32), vn_ref[0].astype(f32)     # [H, hd], [H, hv]
     sc = jnp.sum(q.astype(f32) * kn, axis=-1, keepdims=True) * scale
     fold(sc, lambda p: p.astype(vn_ref.dtype).astype(f32) * vn)
     o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_gqa_attention(q, k_new, v_new, k_pool, v_pool, page_table, lens, *,
-                        scale, chunk_pages=None, interpret=None):
-    """``out [S, H, hd]`` in ``q``'s dtype: head ``h`` of slot ``s`` attends
+                        scale, window=0, sink=None, chunk_pages=None,
+                        interpret=None):
+    """``out [S, H, hdv]`` in ``q``'s dtype: head ``h`` of slot ``s`` attends
     kv head ``h // (H // kvh)`` at pool positions ``< lens[s]`` through
     ``page_table[s]`` and then the new row ``(k_new[s], v_new[s])``.
-    ``chunk_pages`` overrides K (the tests walk several chunks of a tiny
-    table with it)."""
+    ``window`` > 0: only the keys at ``lens[s] - window + 1`` and after, the
+    table row a ring (``kv_pool.ring_pages``), the call named
+    ``paged_window_attention``. ``sink [H]`` (float32): one more logit a
+    head in every softmax's denominator, with no value. ``chunk_pages``
+    overrides K (the tests walk several chunks of a tiny table with it)."""
     K = (pages_per_chunk(k_pool.shape[1], page_table.shape[1])
          if chunk_pages is None else chunk_pages)
     if interpret is None:
         interpret = interpret_mode()
-    return _program(float(scale), int(K), bool(interpret),
-                    call_name("paged_gqa_attention"))(
-        q, k_new, v_new, k_pool, v_pool, page_table, lens)
+    if window == 1:
+        raise ValueError("a window of 1 holds no key of the pool: attend the "
+                         "new row alone")
+    name = call_name("paged_window_attention" if window
+                     else "paged_gqa_attention")
+    return _program(float(scale), int(K), bool(interpret), name, int(window))(
+        q, k_new, v_new, k_pool, v_pool, page_table, lens,
+        *(() if sink is None else (sink,)))
 
 
 @functools.lru_cache(maxsize=None)
-def _program(scale: float, K: int, interpret: bool, name: str):
+def _program(scale: float, K: int, interpret: bool, name: str, window: int):
     """The jitted call for one static choice and call name. The SAME
     callable comes back for every layer, so a decode program traces and
     lowers the kernel once and calls it, not once a layer (seconds of every
     start-up otherwise, as for ``decode_engine._view_branches``)."""
     return jax.jit(functools.partial(_attend, scale=scale, K=K,
-                                     interpret=interpret, name=name))
+                                     interpret=interpret, name=name,
+                                     window=window))
 
 
-def _attend(q, k_new, v_new, k_pool, v_pool, page_table, lens, *, scale, K,
-            interpret, name):
+def _attend(q, k_new, v_new, k_pool, v_pool, page_table, lens, sink=None, *,
+            scale, K, interpret, name, window=0):
     S, H, hd = q.shape
-    pages, ps, kvh, _ = k_pool.shape
+    tokens = token_rows(k_pool)
+    if tokens:
+        (pages, ps, _), kvh = k_pool.shape, k_new.shape[1]
+    else:
+        pages, ps, kvh, _ = k_pool.shape
+    hv = v_new.shape[-1]
     rep, P = H // kvh, page_table.shape[1]
     dtype = k_pool.dtype
     rows = K * ps * kvh
@@ -239,28 +322,45 @@ def _attend(q, k_new, v_new, k_pool, v_pool, page_table, lens, *, scale, K,
     def per_slot(s, pt, lens):
         return (s, 0, 0)
 
+    def whole(s, pt, lens):
+        return (0, 0)
+
     def per_head(new):
         """``new [S, kvh, hd]`` with each kv head's row once a query head."""
         return jnp.repeat(new.astype(dtype), rep, axis=1)
 
+    extra_specs, extra = [], []
+    if sink is not None:
+        extra_specs.append(pl.BlockSpec((H, 1), whole))
+        extra.append(jnp.asarray(sink, jnp.float32).reshape(H, 1))
+    if tokens:
+        bufs = [pltpu.VMEM((2, K * ps, kvh * hd), dtype),       # K chunks
+                pltpu.VMEM((2, K * ps, kvh * hv), dtype)]       # V chunks
+        flat = ((pages, ps, kvh * hd), (pages, ps, kvh * hv))
+    else:
+        bufs = [pltpu.VMEM((2, rows, hd), dtype),      # K chunks
+                pltpu.VMEM((2, rows, hd), dtype)]      # V chunks
+        flat = ((pages, ps * kvh, hd), (pages, ps * kvh, hd))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[pl.BlockSpec((1, H, hd), per_slot),
                   pl.BlockSpec((1, H, hd), per_slot),
-                  pl.BlockSpec((1, H, hd), per_slot),
+                  pl.BlockSpec((1, H, hv), per_slot),
+                  *extra_specs,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, H, hd), per_slot),
-        scratch_shapes=[pltpu.VMEM((2, rows, hd), dtype),      # K chunks
-                        pltpu.VMEM((2, rows, hd), dtype),      # V chunks
+        out_specs=pl.BlockSpec((1, H, hv), per_slot),
+        scratch_shapes=[*bufs,
                         pltpu.SemaphoreType.DMA((2, 2)),       # [pool, buffer]
                         pltpu.SMEM((1,), jnp.int32),   # chunk 0's buffer
                         pltpu.VMEM((H, 1), jnp.float32),       # running max
                         pltpu.VMEM((H, 1), jnp.float32),       # denominator
-                        pltpu.VMEM((H, hd), jnp.float32)])     # accumulator
+                        pltpu.VMEM((H, hv), jnp.float32)])     # accumulator
     kernel = functools.partial(_kernel, K=K, ps=ps, kvh=kvh, rep=rep,
-                               scale=scale, slots=S, table_pages=P)
+                               scale=scale, slots=S, table_pages=P,
+                               window=window, sink=sink is not None,
+                               tokens=tokens)
     # Mosaic has no 64-bit types and the package turns x64 on at import:
     # trace the call (index maps and body) with it off. The slots run in
     # order: a slot starts the next one's first copies.
@@ -268,12 +368,11 @@ def _attend(q, k_new, v_new, k_pool, v_pool, page_table, lens, *, scale, K,
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((S, H, hv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name=name,
         )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lens, jnp.int32),
-          q.astype(dtype), per_head(k_new), per_head(v_new),
-          k_pool.reshape(pages, ps * kvh, hd),
-          v_pool.reshape(pages, ps * kvh, hd))
+          q.astype(dtype), per_head(k_new), per_head(v_new), *extra,
+          *(p.reshape(shape) for p, shape in zip((k_pool, v_pool), flat)))
